@@ -1,9 +1,9 @@
-"""Pretrain a tiny Llama with hybrid parallelism on a virtual 8-device
-mesh (dp=2 x mp=4) — the same SpmdTrainer the bench runs on real TPU.
+"""Pretrain a tiny Llama with hybrid parallelism on an 8-device mesh
+(dp=2 x mp=4) — the same SpmdTrainer chip_smoke.py drives on real TPU.
 
-Run: XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+Run: JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
      python examples/train_llama_hybrid.py
-(on a TPU pod slice, drop the XLA_FLAGS and size the mesh to the chips)
+(on a TPU pod slice, drop both variables and size the mesh to the chips)
 """
 import numpy as np
 
@@ -13,7 +13,10 @@ import paddle_tpu as paddle
 def main():
     import jax
     if jax.device_count() < 8:
-        jax.config.update("jax_platforms", "cpu")  # fall back to virtual
+        raise SystemExit(
+            f"this example lays a dp=2 x mp=4 mesh over 8 devices; JAX "
+            f"sees {jax.device_count()} x {jax.devices()[0].platform}. "
+            "See the docstring for the 8-virtual-CPU-device command.")
     from paddle_tpu import optimizer as opt
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     from paddle_tpu.parallel import SpmdTrainer, make_hybrid_mesh

@@ -251,7 +251,7 @@ def _own_body_walk(fn) -> Iterable[ast.AST]:
 
 
 # =============================================================================
-# TPU1xx — version-shim invariants (the PR-2 bug class)
+# TPU1xx — one spelling of the linted JAX entry points (utils/jax_compat.py)
 # =============================================================================
 _RAW_SHARD_MAP = {"jax.shard_map", "jax.experimental.shard_map.shard_map"}
 _RAW_AXIS_SIZE = {"jax.lax.axis_size", "lax.axis_size"}
@@ -267,10 +267,9 @@ def _is_compat(name: Optional[str]) -> bool:
     "TPU101", "raw-shard-map",
     "raw jax.shard_map / jax.experimental.shard_map call site outside "
     "utils/jax_compat.py",
-    "import shard_map from paddle_tpu.utils.jax_compat — the shim accepts "
-    "the current-JAX kwargs everywhere and translates on 0.4.x, where the "
-    "raw spelling does not exist (this exact bypass caused PR 2's 32 "
-    "tier-1 failures)",
+    "import shard_map from paddle_tpu.utils.jax_compat — it checks the "
+    "specs' mesh axes against the mesh first (shardcheck's runtime twin), "
+    "which a raw call site skips",
     exempt_suffixes=("utils/jax_compat.py",))
 def _check_raw_shard_map(ctx: FileContext):
     rule = RULES["TPU101"]
@@ -292,9 +291,8 @@ def _check_raw_shard_map(ctx: FileContext):
 @_register(
     "TPU102", "raw-axis-size",
     "raw jax.lax.axis_size call site outside utils/jax_compat.py",
-    "import axis_size from paddle_tpu.utils.jax_compat — on pre-promotion "
-    "JAX the symbol does not exist and the shim emulates it with a psum "
-    "of 1",
+    "import axis_size from paddle_tpu.utils.jax_compat — collectives "
+    "code keeps one import home for the JAX names it depends on",
     exempt_suffixes=("utils/jax_compat.py",))
 def _check_raw_axis_size(ctx: FileContext):
     rule = RULES["TPU102"]
@@ -316,8 +314,8 @@ def _check_raw_axis_size(ctx: FileContext):
     "Pallas CompilerParams/TPUCompilerParams constructed outside "
     "utils/jax_compat.py",
     "call paddle_tpu.utils.jax_compat.tpu_compiler_params(**kw) — the "
-    "class was renamed when Pallas-TPU stabilized, so the raw spelling "
-    "only exists on one side of the version boundary",
+    "class has been renamed once already; kernels keep one place to "
+    "follow it",
     exempt_suffixes=("utils/jax_compat.py",))
 def _check_raw_compiler_params(ctx: FileContext):
     rule = RULES["TPU103"]

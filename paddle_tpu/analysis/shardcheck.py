@@ -217,7 +217,7 @@ class _Prop:
         return out_spec, bytes_, note
 
     def run(self, jaxpr, env: Dict):
-        from jax.core import Literal
+        from jax.extend.core import Literal
 
         def read(v):
             if isinstance(v, Literal):

@@ -2,8 +2,8 @@
 
 A persistent compiled-program cache is only safe if a stale or
 foreign artifact can never be *silently* loaded: the stock persistent
-XLA compile cache is disabled in this sandbox for exactly that reason
-(STATUS.md), so this module errs hard on the side of "any mismatch is a
+XLA compile cache stays off for the CPU tests for exactly that reason
+(tests/conftest.py), so this module errs hard on the side of "any mismatch is a
 miss, never a wrong hit". One key commits to every input that can change
 the compiled program:
 

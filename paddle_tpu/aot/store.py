@@ -1,8 +1,8 @@
 """ArtifactStore: checkpoint-grade persistence for exported programs.
 
-The stock persistent XLA compile cache is disabled-unsafe in this
-sandbox (STATUS.md): concurrent generations sharing one directory can
-tear each other's entries. This store is the safe replacement, built on
+The stock persistent XLA compile cache is unsafe for the CPU tests in
+this sandbox (tests/conftest.py), and concurrent generations sharing one
+directory can tear each other's entries. This store is the safe replacement, built on
 the same integrity discipline as ``distributed/checkpoint.py``:
 
   * **atomic writes** — payload and meta land as ``.tmp-<pid>`` files,

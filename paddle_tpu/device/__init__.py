@@ -19,6 +19,21 @@ def get_device() -> str:
 
 
 def set_device(device: str):
+    """Select ``"<platform>"`` or ``"<platform>:<index>"``. Every op runs
+    on JAX's default device (other chips are reached through a mesh), so
+    that is the only device that can be selected: any other platform or
+    index is an error, not a silent run somewhere else."""
+    platform, _, index = str(device).partition(":")
+    default = _devices()[0]
+    if platform != default.platform:
+        raise ValueError(
+            f"set_device({device!r}): the default backend is "
+            f"{default.platform!r}; no {platform!r} device is available "
+            "to this process")
+    if index and int(index) != default.id:
+        raise ValueError(
+            f"set_device({device!r}): ops run on the default device "
+            f"{get_device()}; use a mesh to place work on other devices")
     return get_device()
 
 
@@ -91,12 +106,10 @@ class cuda:
 
 
 def synchronize(device=None):
-    """Block until all queued device work completes."""
-    for d in _devices():
-        try:
-            d.synchronize_all_activity()
-        except AttributeError:
-            pass
+    """Block until the device work behind every live array, and every
+    ordered side effect, has completed."""
+    jax.effects_barrier()
+    jax.block_until_ready(jax.live_arrays())
 
 
 # -- memory stats (reference phi/core/memory/stats.h; python

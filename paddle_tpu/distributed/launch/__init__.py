@@ -110,16 +110,20 @@ class Controller:
                 else args.nproc_per_node
             self.ps_servers = args.server_num
             args.nproc_per_node = self.ps_servers + trainers
-        if args.nproc_per_node > 1 and \
-                os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
-            # one process owns all local TPU chips; several would fight over
-            # the device (the reference's per-GPU model does not transfer)
-            raise SystemExit(
-                f"--nproc_per_node={args.nproc_per_node}: a TPU host runs "
-                "ONE worker process (jax owns every local chip). Scale with "
-                "--nnodes/--rank_offset, or set JAX_PLATFORMS=cpu if these "
-                "ranks are CPU-only (e.g. ps servers/trainers).")
         self.nranks_local = args.nnodes * args.nproc_per_node
+        if self.nranks_local > 1 and \
+                os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
+            # one process owns all local TPU chips; a second would fail or
+            # hang waiting for them (the reference's per-GPU model does not
+            # transfer). --nnodes N also means N processes on THIS machine.
+            raise SystemExit(
+                f"--nnodes={args.nnodes} x --nproc_per_node="
+                f"{args.nproc_per_node} starts {self.nranks_local} "
+                "processes here: a TPU host runs ONE worker process (jax "
+                "owns every local chip). Run one launcher per machine "
+                "with --nnodes 1 --rank_offset/--world_size, or set "
+                "JAX_PLATFORMS=cpu if these ranks are CPU-only (e.g. ps "
+                "servers/trainers, elastic drills).")
         self.world = args.world_size or self.nranks_local
         master = args.master or f"127.0.0.1:{_free_port()}"
         self.master_addr, self.master_port = master.rsplit(":", 1)

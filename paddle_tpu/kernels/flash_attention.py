@@ -7,18 +7,10 @@ reference path (nn/functional/attention.py) when shapes/platform don't fit.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
-
-@functools.lru_cache(maxsize=1)
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+from . import on_tpu
 
 
 def is_available(q, k=None, causal=False) -> bool:
@@ -26,7 +18,7 @@ def is_available(q, k=None, causal=False) -> bool:
     BOTH q and k/v (a non-divisible kv length would silently truncate), and
     q_len <= kv_len for causal (bottom-right alignment leaves leading rows
     keyless otherwise — the XLA fallback defines that case)."""
-    if not _on_tpu():
+    if not on_tpu():
         return False
     if q.ndim != 4:
         return False
@@ -42,9 +34,9 @@ def is_available(q, k=None, causal=False) -> bool:
 
 
 def _tune_signature(q_bshd, k_bshd, causal):
-    # MUST match flash_pallas._resolve_blocks and the bench probe's
-    # flash_tune record key: (sq, sk, head_dim, dtype, causal) — batch and
-    # head count don't change the per-tile geometry
+    # MUST match flash_pallas._resolve_blocks' lookup key: (sq, sk,
+    # head_dim, dtype, causal) — batch and head count don't change the
+    # per-tile geometry
     b, sq, h, d = q_bshd.shape
     return (sq, k_bshd.shape[1], d, str(q_bshd.dtype), bool(causal))
 
@@ -54,8 +46,7 @@ def tune_blocks(q_bshd, k_bshd, v_bshd, causal: bool = False, scale=None):
     cache the winner under the 'flash_fwd' key (kernels/autotune.py).
     Traced call sites need nothing special: flash_pallas._resolve_blocks
     consults the cache at trace time, and its fallback chain gives the
-    backward the forward's winner unless a bwd-specific entry exists
-    (the hardware probe's flash_tune step records both)."""
+    backward the forward's winner unless a bwd-specific entry exists."""
     from . import autotune
     sq, sk, d = q_bshd.shape[1], k_bshd.shape[1], q_bshd.shape[3]
     sig = _tune_signature(q_bshd, k_bshd, causal)
@@ -66,12 +57,48 @@ def tune_blocks(q_bshd, k_bshd, v_bshd, causal: bool = False, scale=None):
                                        block_k=c[1]))
 
 
+def over_mesh(fn, mesh, batch_axes, q_shape, k_shape):
+    """Run ``fn(q, k, v)`` ([batch, seq, heads, dim]) per shard of
+    ``mesh``: batch over ``batch_axes``, heads over ``mp``.
+
+    A ``pallas_call`` is opaque to GSPMD: bare inside a multi-device jit
+    it does not lower on a TPU at all ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map").
+    Attention is independent per (batch, head), so a manual region over
+    exactly those axes keeps each chip on its own slice with no
+    collective. Returns ``fn`` unchanged where nothing divides, or inside
+    an enclosing manual region (the pipeline's stage region), whose axes
+    a nested region may not rebind."""
+    if mesh is None or q_shape[2] != k_shape[2] or \
+            jax.sharding.get_abstract_mesh().manual_axes:
+        return fn
+    from jax.sharding import PartitionSpec as P
+    from ..utils.jax_compat import shard_map
+
+    def live(axis):
+        return axis in mesh.dim_names and mesh.get_dim_size(axis) > 1
+
+    b_axes = tuple(a for a in (batch_axes or ()) if live(a))
+    b_deg = 1
+    for a in b_axes:
+        b_deg *= mesh.get_dim_size(a)
+    if q_shape[0] % b_deg:
+        b_axes = ()
+    h_axis = "mp" if live("mp") and \
+        q_shape[2] % mesh.get_dim_size("mp") == 0 else None
+    if not b_axes and h_axis is None:
+        return fn
+    spec = P(b_axes or None, None, h_axis, None)
+    return shard_map(fn, mesh=mesh.to_jax(), in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)
+
+
 def flash_attention_bshd(q, k, v, causal: bool = False, scale=None,
                          block_q=None, block_k=None):
     """[batch, seq, heads, dim] layout wrapper around the Pallas kernel.
     Block sizes stay None unless the caller pins them: the kernel's own
     _resolve_blocks consults the autotune cache per direction (fwd AND
-    bwd keys; tuned by the hardware probe's flash_tune step)."""
+    bwd keys)."""
     from .flash_pallas import flash_attention as fa_bhsd
     # kernel uses [batch, heads, seq, dim]
     qh = jnp.swapaxes(q, 1, 2)

@@ -515,7 +515,7 @@ def _reference_bhsd(q, k, v, causal, scale):
 
 def _resolve_blocks(which: str, q, k, causal, block_q, block_k):
     """None block sizes resolve through the autotune cache (in-process or
-    the probe-written disk cache), else the static defaults — so a
+    its disk file), else the static defaults — so a
     hardware-tuned decision reaches every call site without threading
     config (reference switch_autotune cache role)."""
     if block_q is not None and block_k is not None:

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..framework import flags
+from . import on_tpu
 
 flags.define_flag("use_pallas_fused", False,
                   "Route fused_rope/rms_norm through the Pallas kernels on "
@@ -39,15 +40,8 @@ def _best_block(n: int, target: int) -> int:
     return b
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
-
-
 def enabled() -> bool:
-    return flags.flag("use_pallas_fused") and (_on_tpu() or _INTERPRET)
+    return flags.flag("use_pallas_fused") and (_INTERPRET or on_tpu())
 
 
 # -- fused rope ---------------------------------------------------------------
@@ -126,8 +120,7 @@ def _rmsnorm_res_kernel(x_ref, r_ref, w_ref, o_ref, *, eps):
                     r_ref=r_ref)
 
 
-def fused_rms_norm_pallas(x, weight, eps: float = 1e-6, residual=None,
-                          block_rows: int = 512):
+def fused_rms_norm_pallas(x, weight, eps: float = 1e-6, residual=None):
     """RMSNorm (optionally fused with a residual add) in one HBM pass
     (parity: fused_layernorm_kernel.cu / fused_rms_norm capability)."""
     orig_shape = x.shape
@@ -136,7 +129,11 @@ def fused_rms_norm_pallas(x, weight, eps: float = 1e-6, residual=None,
     for dd in orig_shape[:-1]:
         rows *= dd
     xr = x.reshape(rows, hidden)
-    br = _best_block(rows, block_rows)
+    # Row block sized so ONE float32 working copy of the tile is 1 MiB.
+    # The kernel holds a few of those beside the double-buffered input,
+    # residual and output tiles; 512 rows of hidden 2048 asked Mosaic for
+    # 16.2 MiB of its 16 MiB scoped VMEM on a v5e (PERF.md, PR 21).
+    br = _best_block(rows, max(16, (1 << 18) // hidden))
     nr = rows // br
     if residual is not None:
         rr = residual.reshape(rows, hidden)

@@ -35,7 +35,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import fused_pallas as _fp
+from . import default_platform, fused_pallas as _fp
+
+
+def _interpret() -> bool:
+    """The dropless MoE path has no jnp twin, so off-TPU it runs the same
+    kernels through the Pallas interpreter — chosen because the platform
+    IS cpu (or the tests' switch), never because a device probe failed."""
+    return _fp._INTERPRET or default_platform() == "cpu"
 
 
 def make_group_metadata(group_sizes, t: int, bt: int):
@@ -119,7 +126,7 @@ def _gmm_call(x, w, group_sizes, bt: int = 128, bn: int = 128):
                                    lambda j, i, tl, gr, *_: (tl[i], j)),
         ),
         out_shape=jax.ShapeDtypeStruct((t, n), jnp.float32),
-        interpret=_fp._INTERPRET or not _fp._on_tpu(),
+        interpret=_interpret(),
     )(tiles, groups, first, row_s, row_e, gfirst, x, w)
     return out.astype(x.dtype)
 
@@ -168,7 +175,7 @@ def _tgmm_call(x, dy, group_sizes, bt: int = 128, bk: int = 128,
                 (1, bk, bn), lambda kb, j, i, tl, gr, *_: (gr[i], kb, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((e, k, n), jnp.float32),
-        interpret=_fp._INTERPRET or not _fp._on_tpu(),
+        interpret=_interpret(),
     )(tiles, groups, first, row_s, row_e, gfirst, x, dy)
     # groups with zero rows are never visited: their windows are
     # uninitialized memory, not zeros

@@ -33,7 +33,7 @@ def _adamw_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
                   op_ref, om_ref, ov_ref):
     """One VMEM block of the flat group, viewed 2-D [rows, 1024] (Mosaic
     wants >=2-D refs with a 128-multiple lane dim; the 1-D original
-    crashed the TPU compiler, PROBE_r04 fused_adamw). sc_ref: [8] f32
+    crashed the TPU compiler in r04). sc_ref: [8] f32
     scalars in SMEM (lr, beta1, beta2, eps, wd, bc1, bc2, decoupled) —
     a VMEM scalar block would violate the (8,128) tile divisibility."""
     lr, b1, b2, eps = sc_ref[0], sc_ref[1], sc_ref[2], sc_ref[3]
@@ -57,8 +57,8 @@ def _adamw_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
 _LANES = 1024
 # flat buffers are padded to _PAD elements = 64 rows of _LANES, so every
 # [block_rows, _LANES] tile is divisible by both the f32 (8,128) and bf16
-# (16,128) Mosaic tiles regardless of group size (the probe's divisibility
-# error came from padding only to _LANES: tiny groups made thin blocks)
+# (16,128) Mosaic tiles regardless of group size (padding only to _LANES
+# made thin blocks for tiny groups, which Mosaic refused)
 _PAD = _LANES * 64
 
 

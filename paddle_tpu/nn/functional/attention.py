@@ -83,10 +83,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                 tq, tk, tv = _amp_cast(
                     "flash_attention", (qt._data, kt._data, vt._data))
                 fa.tune_blocks(tq, tk, tv, causal=is_causal)
-            return dispatch(
-                "flash_attention",
-                lambda q, k, v: fa.flash_attention_bshd(q, k, v, causal=is_causal),
-                qt, kt, vt)
+            flash = fa.over_mesh(
+                lambda q, k, v: fa.flash_attention_bshd(q, k, v,
+                                                        causal=is_causal),
+                pctx.current_mesh(), pctx.batch_axes(),
+                qt._data.shape, kt._data.shape)
+            return dispatch("flash_attention", flash, qt, kt, vt)
     p_drop = float(dropout_p) if training else 0.0
     key = next_key() if p_drop > 0.0 else None
     if attn_mask is not None:

@@ -53,8 +53,9 @@ def quantize_weight_arrays(arr, bits: int = 8):
     instead of a bf16-rounded one. bits=8 returns int8 [in, out]; bits=4
     returns nibble-packed int8 [ceil(in/2), out] (reference parity with
     weight_quantize's two-nibbles-per-int8 packing — native jnp.int4 jit
-    arguments hit a layout-conversion recursion on real TPU, see
-    PROBE_r04; the packed form keeps HBM reads at 4 bits/weight because
+    arguments hit a layout-conversion recursion on real TPU in r04
+    (`RecursionError: Recursively calling jit`, not re-run since); the
+    packed form keeps HBM reads at 4 bits/weight because
     XLA fuses the unpack into the dot operand)."""
     if bits == 8:
         qmax, lo, hi = 127.0, -128, 127
@@ -110,7 +111,7 @@ def quant_matmul_arrays(x, q, s):
                 f"quant_matmul: weight rows {q.shape[0]} match neither the "
                 f"contraction dim {k} (int8) nor its nibble-packed half")
         # two half-dots against the nibble halves: no interleaved unpack
-        # buffer ever materializes (the PROBE_r04 rerun showed the
+        # buffer ever materializes (an r04 rerun showed the
         # stack+reshape unpack costing ~3x on decode), and XLA fuses each
         # shift pair into its dot's operand read
         even = ((q << 4) >> 4).astype(x.dtype)          # rows 0,2,4,...

@@ -442,9 +442,10 @@ class ServingEngine:
     def _new_pool(self):
         """A zeroed device pool in the engine's placement — construction
         and the step-fault containment rebuild share one spelling."""
-        pool = jnp.zeros(self._pool_shape, self._pool_dtype)
-        ns = self._pool_sharding()
-        return pool if ns is None else jax.device_put(pool, ns)
+        # created in place: under a mesh each chip zeroes its own KV-head
+        # shard, and no chip ever holds the whole pool
+        return jnp.zeros(self._pool_shape, self._pool_dtype,
+                         device=self._pool_sharding())
 
     def _weight_sharding(self, name, ndim):
         """PartitionSpec entries for one weight leaf under the TP mesh:

@@ -1,73 +1,33 @@
-"""Version-guarded JAX API shims.
+"""The one spelling of the JAX entry points whose call sites are linted.
 
-The repo targets the current JAX API surface but must run on older
-point releases shipped in CI images. Each symbol resolves once at import
-time to whatever spelling the installed JAX provides; call sites import
-from this module instead of guessing.
-
-``shard_map``: promoted to ``jax.shard_map`` in newer JAX; on 0.4.x it
-lives at ``jax.experimental.shard_map.shard_map`` with the older kwarg
-spellings (``check_rep`` for ``check_vma``; manual axes are expressed as
-the ``auto`` complement instead of ``axis_names``). The wrapper below
-accepts the NEW spellings everywhere and translates when running on the
-old API, so call sites are written once against current JAX.
+``shard_map`` goes through here so every manual region gets its specs
+validated against the mesh first (shardcheck's runtime twin); the lint
+rules in ``analysis/rules.py`` keep call sites pointed at this module.
+``axis_size`` and ``tpu_compiler_params`` are the installed JAX's own
+names, re-exported so kernels and collectives import from one place.
 """
 from __future__ import annotations
 
 import jax
 
-
-def _validate_shard_specs(mesh, in_specs, out_specs):
-    """Shardcheck's runtime twin: reject typo'd/duplicated mesh axes in
-    shard_map specs HERE, with the SHD rule id in the message, instead
-    of letting jax fail deep inside spec resolution. Deferred import:
-    distributed.mesh must not load while this module initializes."""
-    if mesh is None:
-        return
-    from ..distributed.mesh import validate_specs
-    validate_specs(mesh, in_specs, out_specs)
+axis_size = jax.lax.axis_size
 
 
-try:
-    _new_shard_map = jax.shard_map  # promoted spelling (new JAX)
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None, **kw):
-        # mesh must go by keyword: the promoted signature is
-        # shard_map(f, /, *, mesh=None, ...)
-        _validate_shard_specs(mesh, in_specs, out_specs)
-        return _new_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=True,
-                  axis_names=None, **kw):
-        # `axis_names` (partial-manual) maps to `auto=<complement>` on
-        # 0.4.x, but that lowering is broken there on the CPU backend
-        # (XLA aborts on manual-subgroup collectives). Since our bodies
-        # only issue collectives over the named axes, full-manual is
-        # numerically equivalent: axes absent from the specs behave as
-        # replicated (callers pass check_vma=False), at worst paying an
-        # extra gather at the region boundary on this legacy path.
-        _validate_shard_specs(mesh, in_specs, out_specs)
-        return _old_shard_map(f, mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
-try:
-    axis_size = jax.lax.axis_size  # new JAX
-except AttributeError:
-    def axis_size(axis_name):
-        # psum of a Python-int constant folds to a static int under a
-        # manual (shard_map) trace — the pre-promotion idiom
-        return jax.lax.psum(1, axis_name)
+def shard_map(f, mesh=None, in_specs=None, out_specs=None, **kw):
+    """``jax.shard_map`` with the mesh axes of the specs checked HERE,
+    with the SHD rule id in the message, instead of failing deep inside
+    spec resolution. Deferred import: distributed.mesh must not load
+    while this module initializes."""
+    if mesh is not None:
+        from ..distributed.mesh import validate_specs
+        validate_specs(mesh, in_specs, out_specs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def tpu_compiler_params(**kw):
-    """pltpu.CompilerParams on new JAX, TPUCompilerParams on 0.4.x
-    (same fields — the class was renamed when Pallas-TPU stabilized)."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
+    return pltpu.CompilerParams(**kw)
 
 
 __all__ = ["shard_map", "axis_size", "tpu_compiler_params"]

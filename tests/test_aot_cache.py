@@ -1,8 +1,8 @@
 """AOT program-artifact cache: fingerprint, store, cached_jit, and the
 trainer / serving-engine / to_static integrations.
 
-The contract under test is the one the disabled stock XLA cache lacked
-(STATUS.md): any mismatch is a miss, never a wrong hit; a corrupted,
+The contract under test is the one the stock XLA cache lacked on the CPU
+(tests/conftest.py): any mismatch is a miss, never a wrong hit; a corrupted,
 truncated, killed-mid-write, or chaos-poisoned artifact NEVER enters (or
 survives in) the ``_GOOD.json`` ledger and always degrades to a fresh
 compile with bit-identical numerics — tagged and metered, never fatal.

@@ -1,0 +1,143 @@
+"""chip_smoke.py off the chip: it refuses a CPU, its tiny mode passes, and the
+two rules it shares with every chip script (platform check, compile-cache
+placement) hold. The real run is on the chip: `python chip_smoke.py`."""
+import json
+import os
+import subprocess
+import sys
+import types
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+from paddle_tpu.utils import chip  # noqa: E402
+
+
+def _run(*args):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)           # one device: the one-chip phases
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    return subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"), *args],
+        capture_output=True, text=True, env=env, cwd=REPO, timeout=300)
+
+
+def test_refuses_to_run_without_a_tpu():
+    r = _run()
+    assert r.returncode != 0
+    assert "default backend is 'cpu'" in r.stderr
+    lines = r.stdout.strip().splitlines()
+    assert not lines[-1].startswith("{"), "a result was printed"
+    assert not any("phase" in ln for ln in lines), "work ran on the CPU"
+
+
+def test_tiny_mode_passes_every_phase():
+    r = _run("--tiny-cpu")
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    lines = r.stdout.strip().splitlines()
+    device = {"platform": "cpu", "kind": "cpu", "count": 1}
+    # the result line carries exactly these keys; the detail is the line
+    # before it
+    assert json.loads(lines[-1]) == {"ok": True, "device": device}
+    tag = "[chip_smoke] summary "
+    assert lines[-2].startswith(tag)
+    summary = json.loads(lines[-2][len(tag):])
+    assert summary["ok"] is True and summary["tiny_cpu"] is True
+    assert summary["device"] == device
+    assert summary["phases_run"] == ["kernels", "train", "serve"]
+    assert all(p["ok"] for p in summary["phases"].values())
+    assert summary["compile_cache"] is None     # the CPU stays cold
+    assert list(summary)[-1] == "claim" and summary["claim"] is None
+    assert "tokens_per_s" not in r.stdout and "mfu" not in r.stdout
+
+
+def test_flash_partition_parser():
+    """The four-chip phase reads the flash call's per-chip operand out of
+    compiled HLO; pin the reader on the two shapes it must tell apart."""
+    line = ('  %custom-call.3 = (bf16[{n},2048,128]{{2,1,0}}, '
+            'f32[{n},2048,8]{{2,1,0}}) custom-call(%a, %b, %c), '
+            'custom_call_target="tpu_custom_call", operand_layout=...\n')
+    ok = chip_smoke.parse_flash_calls(line.format(n=16) * 3,
+                                      whole=64, shard=16)
+    assert ok["flash_partitioned"] is True
+    with pytest.raises(chip_smoke.SmokeFailure, match="whole problem is 64"):
+        chip_smoke.parse_flash_calls(line.format(n=64), whole=64, shard=16)
+    with pytest.raises(chip_smoke.SmokeFailure, match="no tpu_custom_call"):
+        chip_smoke.parse_flash_calls("ROOT %dot = f32[4]", 64, 16)
+
+
+@pytest.fixture
+def config_updates(monkeypatch):
+    """Record what the helper asks of jax.config without doing it: the
+    test process must stay on a cold cache."""
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda key, value: calls.append((key, value)))
+    return calls
+
+
+def test_compile_cache_yields_to_the_environment(monkeypatch, tmp_path,
+                                                 config_updates):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert chip.enable_compile_cache() == str(tmp_path)
+    assert config_updates == []
+
+
+def test_compile_cache_default_is_one_fixed_path(monkeypatch,
+                                                 config_updates):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert chip.enable_compile_cache() is None      # a CPU stays cold
+    assert config_updates == []
+    monkeypatch.setattr(jax, "devices", lambda: [
+        types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")])
+    fixed = os.path.join(REPO, ".jax_cache")
+    assert chip.enable_compile_cache() == fixed
+    assert chip.enable_compile_cache() == fixed
+    assert config_updates == [("jax_compilation_cache_dir", fixed)] * 2
+
+
+def test_kernel_gates_let_a_backend_error_out(monkeypatch):
+    """A chip held by another process must stop the program, not select
+    the XLA path or the interpreter."""
+    import jax.numpy as jnp
+    from paddle_tpu import kernels
+    from paddle_tpu.kernels import (flash_attention, fused_pallas,
+                                    gmm_pallas, ragged_pallas)
+    from paddle_tpu.framework import flags
+
+    def boom():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", boom)
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        kernels.on_tpu()
+    q = jnp.zeros((1, 128, 2, 64), jnp.bfloat16)
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        flash_attention.is_available(q, q, causal=True)
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        gmm_pallas._interpret()
+    monkeypatch.setitem(flags._FLAGS, "use_pallas_fused", True)
+    monkeypatch.setitem(flags._FLAGS, "use_ragged_pallas", True)
+    for mod in (fused_pallas, ragged_pallas):
+        with pytest.raises(RuntimeError, match="initialize backend"):
+            mod.enabled()
+
+
+def test_require_tpu_names_what_it_found():
+    with pytest.raises(RuntimeError, match=r"'cpu' \(8 x cpu\)"):
+        chip.require_tpu()
+
+
+def test_set_device_refuses_what_it_cannot_select():
+    import paddle_tpu as paddle
+    assert paddle.device.set_device("cpu") == "cpu:0"
+    assert paddle.device.set_device("cpu:0") == "cpu:0"
+    with pytest.raises(ValueError, match="no 'tpu' device"):
+        paddle.device.set_device("tpu")
+    with pytest.raises(ValueError, match="use a mesh"):
+        paddle.device.set_device("cpu:3")
+    paddle.device.synchronize()

@@ -22,14 +22,20 @@ def _model(tied=False, kv_heads=2, seed=3):
 
 
 def _greedy_oracle(model, ids, steps):
-    """Naive loop: full forward recompute each step, argmax."""
-    cur = np.asarray(ids)
+    """Naive loop: full forward recompute each step, argmax. The sequence
+    is right-padded to one width for every step of every test (causal
+    attention never sees the padding); a growing sequence would recompile
+    every eager op at every step."""
+    ids = np.asarray(ids)
+    b, n = ids.shape
+    cur = np.zeros((b, max(16, n + steps)), ids.dtype)
+    cur[:, :n] = ids
     out = []
-    for _ in range(steps):
+    for t in range(steps):
         logits = model(paddle.to_tensor(cur)).numpy()
-        tok = np.argmax(logits[:, -1], axis=-1).astype(np.int32)
+        tok = np.argmax(logits[:, n + t - 1], axis=-1).astype(np.int32)
         out.append(tok)
-        cur = np.concatenate([cur, tok[:, None]], axis=1)
+        cur[:, n + t] = tok
     return np.stack(out, axis=1)
 
 

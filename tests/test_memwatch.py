@@ -28,8 +28,17 @@ sys.path.insert(0, TOOLS)
 
 import mem_report  # noqa: E402
 
-LEDGER = os.path.join(REPO, "PERF_LEDGER.jsonl")
-CONFIG = os.path.join(REPO, "PERF_CONFIG.json")
+
+
+@pytest.fixture(scope="module")
+def repo_ledger(tmp_path_factory):
+    """An evidence ledger built from the artifacts the repo commits
+    (BENCH_SERVE_*, MEM_WATCH_*, AOT_STATS_*) — the repo commits no
+    ledger of its own."""
+    from paddle_tpu.profiler import evidence
+    path = str(tmp_path_factory.mktemp("ledger") / "ledger.jsonl")
+    evidence.build_ledger(REPO, path)
+    return path
 
 
 def _toy_llama(vocab=61, hidden=32, layers=2, heads=4, kv=2, seq=64):
@@ -498,7 +507,7 @@ class TestWhatFits:
         assert r.returncode == 0, r.stdout + r.stderr
         assert "match the planner exactly" in r.stdout
 
-    def test_plan_cli_and_report_cli(self):
+    def test_plan_cli_and_report_cli(self, repo_ledger):
         r = subprocess.run(
             [sys.executable, os.path.join(TOOLS, "mem_report.py"),
              "--plan", "--preset", "llama2-7b", "--dtype", "bf16",
@@ -507,7 +516,8 @@ class TestWhatFits:
         assert r.returncode == 0, r.stdout + r.stderr
         assert "FITS" in r.stdout
         r2 = subprocess.run(
-            [sys.executable, os.path.join(TOOLS, "mem_report.py")],
+            [sys.executable, os.path.join(TOOLS, "mem_report.py"),
+             "--ledger", repo_ledger],
             capture_output=True, text=True, cwd=REPO)
         assert r2.returncode == 0, r2.stdout + r2.stderr
         assert "mem_report" in r2.stdout
@@ -570,14 +580,15 @@ class TestEvidence:
         assert rows[0]["data"]["reason"] == "near_oom"
         assert rows[0]["data"]["detail"]["pool"] == "kv_pages"
 
-    def test_mem_rows_leave_resolver_decisions_byte_identical(self,
-                                                              tmp_path):
-        """Acceptance criterion: appending memory evidence rows to the
-        committed ledger leaves perf_resolve's decisions for the
-        pre-existing devices byte-identical."""
+    def test_mem_rows_leave_resolver_decisions_byte_identical(
+            self, tmp_path, repo_ledger):
+        """Acceptance criterion: appending memory evidence rows to a
+        ledger leaves perf_resolve's decisions for the pre-existing
+        devices byte-identical."""
         import perf_resolve
-        rows, quarantined = evidence.read_rows(LEDGER)
+        rows, quarantined = evidence.read_rows(repo_ledger)
         assert rows and not quarantined
+        assert perf_resolve.resolve(rows)["devices"]
         before = perf_resolve.resolve(rows)
         path = self._dump(tmp_path)
         mem_rows = evidence.ingest_mem(path)
@@ -586,20 +597,20 @@ class TestEvidence:
             json.dumps(after["devices"], sort_keys=True)
         assert after["ledger_rows"] == before["ledger_rows"] + 1
 
-    def test_committed_mem_artifact_in_ledger(self):
-        """The committed MEM_WATCH artifact ingests and its rows are in
-        the committed ledger (the --build round-trip happened)."""
+    def test_committed_mem_artifact_in_ledger(self, repo_ledger):
+        """The committed MEM_WATCH artifact ingests and its rows land in
+        a ledger built from the repo (the --build round-trip)."""
         paths = [p for p in evidence.scan_repo(REPO)
                  if os.path.basename(p).startswith("MEM_WATCH_")]
         assert paths, "no committed MEM_WATCH artifact"
-        rows, _ = evidence.read_rows(LEDGER)
+        rows, _ = evidence.read_rows(repo_ledger)
         ids = {r["id"] for r in rows}
         for p in paths:
             got = evidence.ingest_mem(p)
             assert got and got[0]["id"] in ids
 
-    def test_mem_report_joins_ledger(self):
-        rep = mem_report.report(LEDGER)
+    def test_mem_report_joins_ledger(self, repo_ledger):
+        rep = mem_report.report(repo_ledger)
         assert rep["mem_rows"] >= 1
         assert rep["latest"]["last"]["pools"]
         text = mem_report.render_report(rep)
@@ -671,7 +682,7 @@ class TestAotMem:
         prog(jnp.zeros(4))
         assert calls["n"] == 0, "paid program stats with no consumer"
 
-    def test_ingest_aot_stats_carries_mem(self, tmp_path):
+    def test_ingest_aot_stats_carries_mem(self, tmp_path, repo_ledger):
         stats = {"programs": {"train_step": {
             "hits": 0, "misses": 1, "fallbacks": 0,
             "cost": {"flops": 1e9, "bytes_accessed": 1e6},
@@ -687,7 +698,7 @@ class TestAotMem:
         fixture_rows = evidence.ingest_aot_stats(
             os.path.join(REPO, "AOT_STATS_cpu_fixture.json"))
         assert all("mem" not in r["data"] for r in fixture_rows)
-        ids = {r["id"] for r in evidence.read_rows(LEDGER)[0]}
+        ids = {r["id"] for r in evidence.read_rows(repo_ledger)[0]}
         assert all(r["id"] in ids for r in fixture_rows)
 
 

@@ -76,12 +76,6 @@ def test_ring_gradients_match():
                                    rtol=3e-4, atol=3e-5)
 
 
-@pytest.mark.xfail(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="XLA SPMD in jax 0.4.x miscompiles the backward of activations "
-           "2-D-sharded over dp x sep (grads drift from step 1; verified "
-           "against the serial oracle with ring attention disabled too)",
-    strict=False)
 def test_llama_context_parallel_matches_serial():
     """Llama trained with sep=4 sequence sharding == serial run."""
     from paddle_tpu import optimizer as opt

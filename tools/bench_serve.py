@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Poisson open-loop serving benchmark: continuous vs static batching.
 
-The serving twin of bench.py: a seeded open-loop load generator (arrivals
+A seeded open-loop load generator (arrivals
 are a Poisson process — exponential gaps at --rate requests/s — fixed by
 the seed BEFORE either run, so both policies face the identical
 schedule) drives the ServingEngine twice over the same request set:
@@ -1270,11 +1270,17 @@ def run_bench(fast: bool = True, seed: int = 0, tag: str = "fast",
               f"goodput {rows[policy]['goodput_tokens_per_s']:.1f} tok/s  "
               f"steps {rows[policy]['engine_steps']}", flush=True)
 
+    from paddle_tpu.utils import chip
+    device = chip.device_summary()
     result = {
         "bench": "serve",
         "schema_version": 2,
         "tag": tag,
         "seed": seed,
+        # every number below was taken on THIS backend; on "cpu" the
+        # rates compare scheduling policies and are not device metrics
+        "device": device,
+        "device_kind": device["kind"],
         "fast": bool(fast),
         "slo": {"ttft_deadline_s": slo[0], "tpot_deadline_s": slo[1]},
         "model": {"hidden": model.config.hidden_size,
@@ -1450,6 +1456,8 @@ def main(argv=None):
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
     tag = args.tag or ("fast" if args.fast else "run")
+    from paddle_tpu.utils import chip
+    chip.enable_compile_cache()
     res = run_bench(fast=args.fast, seed=args.seed, tag=tag,
                     n_requests=args.requests, rate=args.rate,
                     out_path=args.out, spec=args.spec,

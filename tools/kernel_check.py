@@ -1,0 +1,234 @@
+#!/usr/bin/env python
+"""Does each Pallas kernel compile on this chip, and does it match its oracle?
+
+One process on a TPU, no arguments. Every Pallas kernel in
+``paddle_tpu/kernels`` that ``chip_smoke.py`` does not already gate (it
+checks the default-path flash forward and backward) is run once, compiled
+(never interpreted), at one production shape, against the jnp
+implementation the tests use as its oracle. They sit behind flags that are
+off (``FLAGS_use_pallas_fused``, ``FLAGS_use_ragged_pallas``) or behind an
+explicit MoE/packing option, and this survey is the record of which of
+them the installed Mosaic accepts (ROADMAP S7, D2). It turns no flag on.
+
+Prints one JSON line per kernel — ``"verdict": "compiles and matches"``
+or the compiler's own words — and a final summary line; the same goes to
+``chiprun_out/kernel_check.json``. A refused kernel does not stop the
+survey (reporting the refusal is its job), but the exit code is 1 unless
+every kernel matched.
+"""
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+import traceback
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from paddle_tpu.utils import chip  # noqa: E402
+
+REL_L2 = 2e-2          # bf16 kernels against a float32 oracle
+REL_L2_F32 = 1e-5      # float32 kernels (the AdamW update)
+
+
+def rel_l2(got, want):
+    got = jnp.asarray(got, jnp.float32)
+    want = jnp.asarray(want, jnp.float32)
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+def rand(key, shape, dtype=jnp.bfloat16, scale=1.0):
+    return (jax.random.normal(jax.random.PRNGKey(key), shape) * scale) \
+        .astype(dtype)
+
+
+def out_and_grads(fn, args, cotangent):
+    """(fn(*args), *d fn/d args) under one jit, so Mosaic sees what a
+    model would hand it. The arrays are jit ARGUMENTS: closed over, they
+    would be baked into the executable and its cache entry."""
+    def run(args, cotangent):
+        out, vjp = jax.vjp(fn, *args)
+        return (out, *vjp(cotangent.astype(out.dtype)))
+    return jax.jit(run)(args, cotangent)
+
+
+# Each case returns its shape and {output name: (kernel result, oracle
+# result)}.
+def case_flashmask():
+    """Packed documents of 256 tokens: causal within a document."""
+    from paddle_tpu.kernels import flash_pallas as fp
+    from paddle_tpu.nn.functional.attention import (_flashmask_dense_visible,
+                                                    _sdpa_reference)
+    b, h, s, d, doc = 2, 16, 2048, 128, 256
+    q, k, v, g = (rand(i, (b, h, s, d)) for i in range(4))
+    j = jnp.arange(s)
+    lts = ((j // doc + 1) * doc).astype(jnp.int32)
+    bounds = jnp.broadcast_to(
+        jnp.stack([lts, jnp.full((s,), s, jnp.int32),
+                   jnp.zeros((s,), jnp.int32),
+                   jnp.zeros((s,), jnp.int32)], -1)[None, None],
+        (b, h, s, 4))
+
+    def oracle(q, k, v):
+        vis = _flashmask_dense_visible(bounds, s, s, True, None)
+        to_bshd = lambda t: jnp.swapaxes(t, 1, 2)
+        return to_bshd(_sdpa_reference(to_bshd(q), to_bshd(k), to_bshd(v),
+                                       mask=vis))
+
+    got = out_and_grads(
+        lambda q, k, v: fp.flashmask_attention(q, k, v, bounds, True),
+        (q, k, v), g)
+    want = out_and_grads(oracle, (q, k, v), g)
+    return (b, h, s, d), dict(zip(("out", "dq", "dk", "dv"),
+                                  zip(got, want)))
+
+
+def case_fused_rope():
+    from paddle_tpu.kernels.fused_pallas import fused_rope_pallas
+    from paddle_tpu.models.llama import apply_rope, build_rope_cache
+    b, s, h, d = 4, 2048, 16, 128
+    q, k = rand(0, (b, s, h, d)), rand(1, (b, s, h, d))
+    cos, sin = build_rope_cache(s, d)
+    got = jax.jit(lambda q, k: fused_rope_pallas(q, k, cos, sin))(q, k)
+    want = jax.jit(lambda q, k: apply_rope(q, k, cos, sin))(q, k)
+    return (b, s, h, d), {"q": (got[0], want[0]), "k": (got[1], want[1])}
+
+
+def case_fused_rms_norm():
+    from paddle_tpu.kernels.fused_pallas import fused_rms_norm_pallas
+    b, s, hid = 4, 2048, 2048
+    x, r = rand(0, (b, s, hid)), rand(1, (b, s, hid))
+    w = rand(2, (hid,))
+
+    def oracle(x, r):
+        xf = x.astype(jnp.float32) + r.astype(jnp.float32)
+        return (xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
+                                   + 1e-6) * w.astype(jnp.float32))
+
+    got = jax.jit(lambda x, r: fused_rms_norm_pallas(
+        x, w, eps=1e-6, residual=r))(x, r)
+    got_plain = jax.jit(lambda x: fused_rms_norm_pallas(x, w, eps=1e-6))(x)
+    return (b, s, hid), {
+        "norm_residual": (got, oracle(x, r)),
+        "norm": (got_plain, oracle(x, jnp.zeros_like(x)))}
+
+
+def case_fused_adamw():
+    from paddle_tpu.kernels import optimizer_pallas as op
+    from paddle_tpu.optimizer import _adam_update
+    shapes = [(2048, 5632), (2048, 2048), (2048,), (1000,)]
+    ps = [rand(i, s, jnp.float32) for i, s in enumerate(shapes)]
+    gs = [p * 0.01 for p in ps]
+    ms = [jnp.zeros_like(p) for p in ps]
+    vs = [jnp.zeros_like(p) for p in ps]
+    hp = dict(lr=1e-3, beta1=0.9, beta2=0.95, eps=1e-8, step=2.0)
+    got = jax.jit(lambda ps, gs, ms, vs: op.multi_tensor_adamw_pallas(
+        ps, gs, ms, vs, wds=[0.1] * len(ps), **hp))(ps, gs, ms, vs)
+    f = jnp.float32
+    want = [_adam_update(p, g, m, v, f(hp["lr"]), f(hp["beta1"]),
+                         f(hp["beta2"]), f(hp["eps"]), f(hp["step"]),
+                         f(0.1), True)
+            for p, g, m, v in zip(ps, gs, ms, vs)]
+    pairs = {}
+    for i in range(len(ps)):
+        for j, name in enumerate(("p", "m", "v")):
+            pairs[f"{name}{i}"] = (got[j][i], want[i][j])
+    return shapes, pairs
+
+
+def case_gmm():
+    """Dropless-MoE grouped matmul at OLMoE-like widths: forward, and the
+    backward's dx (gmm on w^T) and dw (tgmm)."""
+    from paddle_tpu.kernels import gmm_pallas as G
+    t, dm, dff, e = 4096, 2048, 1024, 8
+    x = rand(0, (t, dm), scale=0.1)
+    w = rand(1, (e, dm, dff), scale=0.1)
+    ct = rand(2, (t, dff))
+    sizes = jnp.asarray([700, 0, 1300, 512, 37, 1035, 256, 256], jnp.int32)
+
+    got = out_and_grads(lambda x, w: G.gmm(x, w, sizes), (x, w), ct)
+    want = out_and_grads(lambda x, w: G._gmm_reference(x, w, sizes),
+                         (x, w), ct)
+    return (t, dm, dff, e), dict(zip(("out", "dx", "dw"), zip(got, want)))
+
+
+def case_ragged_decode():
+    """The smoke engine's geometry: 64 packed decode tokens over 4
+    sequence slots, 16 KV heads of 128, pages of 16 slots."""
+    from paddle_tpu.kernels.ragged_pallas import ragged_decode_attention
+    from paddle_tpu.serving.ragged import ragged_paged_attention
+    t, h, d, pages, bs, slots, mp = 64, 16, 128, 256, 16, 4, 64
+    rng = np.random.default_rng(0)
+    q = rand(0, (t, h, d))
+    kp, vp = rand(1, (pages, h, bs, d)), rand(2, (pages, h, bs, d))
+    lens = [1000, 37, 512, 260]
+    tables = np.full((slots, mp), -1, np.int32)
+    perm = rng.permutation(pages)
+    at = 0
+    for s_, n in enumerate(lens):
+        need = -(-n // bs)
+        tables[s_, :need] = perm[at:at + need]
+        at += need
+    slot = rng.integers(0, slots, t).astype(np.int32)
+    pos = np.asarray([rng.integers(0, lens[s_]) for s_ in slot], np.int32)
+    valid = rng.random(t) > 0.1
+    args = (jnp.asarray(tables), jnp.asarray(slot), jnp.asarray(pos),
+            jnp.asarray(valid))
+    got = jax.jit(lambda q, kp, vp: ragged_decode_attention(
+        q, kp, vp, *args, rep=1))(q, kp, vp)
+    want = jax.jit(lambda q, kp, vp: ragged_paged_attention(
+        q, kp, vp, *args, rep=1))(q, kp, vp)
+    return (t, h, d, pages, bs), {"out": (got, want)}
+
+
+CASES = (
+    ("flashmask", "attn_startend_row_indices", case_flashmask, REL_L2),
+    ("fused_rope", "FLAGS_use_pallas_fused", case_fused_rope, REL_L2),
+    ("fused_rms_norm", "FLAGS_use_pallas_fused", case_fused_rms_norm,
+     REL_L2),
+    ("fused_adamw", "FLAGS_use_pallas_fused", case_fused_adamw, REL_L2_F32),
+    ("gmm", "MoE dropless=True", case_gmm, REL_L2),
+    ("ragged_decode", "FLAGS_use_ragged_pallas", case_ragged_decode, REL_L2),
+)
+
+
+def main() -> int:
+    device = chip.require_tpu()
+    chip.enable_compile_cache()
+    print(json.dumps({"device": device}), flush=True)
+    rows = []
+    for name, reached_by, fn, tol in CASES:
+        row = {"kernel": name, "reached_by": reached_by}
+        t0 = time.perf_counter()
+        try:
+            shape, pairs = fn()
+            errs = {k: round(rel_l2(a, b), 6) for k, (a, b) in pairs.items()}
+            row.update(shape=shape, rel_l2=errs, tolerance=tol)
+            bad = {k: e for k, e in errs.items() if not e <= tol}
+            row["verdict"] = ("compiles and matches" if not bad else
+                              f"compiles, WRONG: {bad}")
+        except Exception as e:  # noqa: BLE001 — the refusal IS the result
+            traceback.print_exc()
+            row["verdict"] = f"{type(e).__name__}: {e}"[:1500]
+        row["seconds"] = round(time.perf_counter() - t0, 1)
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    ok = all(r["verdict"] == "compiles and matches" for r in rows)
+    out_dir = os.path.join(REPO, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "kernel_check.json"), "w") as f:
+        json.dump({"device": device, "kernels": rows}, f, indent=1)
+    print(json.dumps({"ok": ok, "device": device,
+                      "kernels": {r["kernel"]: r["verdict"][:200]
+                                  for r in rows}}), flush=True)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -64,7 +64,6 @@ from _bootstrap import REPO, bootstrap_pkg  # noqa: E402
 
 bootstrap_pkg()
 
-LEDGER = os.path.join(REPO, "PERF_LEDGER.jsonl")
 FIXTURE = os.path.join(REPO, "tools", "mem_plan_baseline.json")
 
 #: storage bits per element by dtype spelling (int4 packs two per byte)
@@ -312,8 +311,7 @@ def _fmt_bytes(v) -> str:
         v /= 1024.0
 
 
-def report(ledger_path: str = LEDGER,
-           aot_stats_path: str = None) -> dict:
+def report(ledger_path: str, aot_stats_path: str = None) -> dict:
     """Join the measured memory evidence into one budget view: the
     newest mem_snapshot row (pool split + watermarks + pressure reason)
     and every per-program static footprint (aot_stats rows' ``mem``
@@ -442,7 +440,9 @@ def main(argv=None) -> int:
                     help="XLA temp bytes (from an AOT memory_analysis row)")
     ap.add_argument("--fits", type=float, default=None, metavar="GIB",
                     help="HBM budget to verdict against (e.g. 16)")
-    ap.add_argument("--ledger", default=LEDGER)
+    ap.add_argument("--ledger", default=None, metavar="FILE",
+                    help="evidence ledger JSONL to report on (built by "
+                         "tools/perf_resolve.py --build)")
     ap.add_argument("--aot-stats", default=None,
                     help="live PADDLE_AOT_STATS file to join per-program "
                          "memory_analysis from")
@@ -487,6 +487,8 @@ def main(argv=None) -> int:
               else render_plan(p), end="")
         return 0
 
+    if not args.ledger:
+        ap.error("the report mode needs --ledger FILE")
     rep = report(args.ledger, args.aot_stats)
     print(json.dumps(rep, indent=1, sort_keys=True) if args.as_json
           else render_report(rep), end="")

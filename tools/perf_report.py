@@ -1,20 +1,20 @@
 #!/usr/bin/env python
 """perf_report: offline "where did the step go" over the evidence ledger.
 
-The serve_top of the perf plane: renders the PerfEvidence ledger
-(PERF_LEDGER.jsonl) as a static report — step-time anatomy
+The serve_top of the perf plane: renders a PerfEvidence ledger (built
+by ``tools/perf_resolve.py --build``) as a static report — step-time anatomy
 (compute/collective/data/host fractions from runlog wall times joined
 with per-program XLA cost_analysis), top programs by modeled time with
 their roofline position (compute- vs memory-bound), the MFU delta
-against the committed hardware anchor (BENCH_SESSION_r04), the probe
+against the newest hardware training session in the ledger, the probe
 tier table, serving bench summaries, and the resolver decisions in
 effect per device. jax-free (lint.py-style bootstrap): reads files,
 renders text.
 
-    python tools/perf_report.py                    # committed ledger
+    python tools/perf_report.py --ledger runs/evidence.jsonl
     python tools/perf_report.py --runlog runs/r0/runlog_rank0.jsonl \\
-        --aot-stats runs/r0/aot_stats_0.json       # join a live run
-    python tools/perf_report.py --json             # machine-readable
+        --aot-stats runs/r0/aot_stats_0.json       # a live run alone
+    python tools/perf_report.py ... --json         # machine-readable
 """
 from __future__ import annotations
 
@@ -24,7 +24,7 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from _bootstrap import REPO, bootstrap_pkg  # noqa: E402
+from _bootstrap import bootstrap_pkg  # noqa: E402
 
 bootstrap_pkg()
 from paddle_tpu.profiler import evidence  # noqa: E402
@@ -202,7 +202,7 @@ def render(rep: dict) -> str:
 
     if rep["decisions"]:
         lines.append("")
-        lines.append("resolver decisions in effect (PERF_CONFIG.json)")
+        lines.append("resolver decisions in effect (--config)")
         for dk, entry in rep["decisions"].items():
             lines.append(f"  {dk}  [window: {entry['window']}]")
             for name, d in entry["flags"].items():
@@ -214,10 +214,11 @@ def render(rep: dict) -> str:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--ledger",
-                    default=os.path.join(REPO, "PERF_LEDGER.jsonl"))
-    ap.add_argument("--config",
-                    default=os.path.join(REPO, "PERF_CONFIG.json"))
+    ap.add_argument("--ledger", default=None, metavar="FILE",
+                    help="evidence ledger JSONL (none: only the joined "
+                         "--runlog/--aot-stats files are reported)")
+    ap.add_argument("--config", default=None, metavar="FILE",
+                    help="perf config whose decisions to list")
     ap.add_argument("--runlog", action="append", default=[],
                     metavar="FILE", help="join a runlog JSONL (repeatable)")
     ap.add_argument("--aot-stats", action="append", default=[],
@@ -226,7 +227,8 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true", dest="as_json")
     args = ap.parse_args(argv)
 
-    rows, quarantined = evidence.read_rows(args.ledger)
+    rows, quarantined = (evidence.read_rows(args.ledger)
+                         if args.ledger else ([], []))
     runlog_rows = []
     for path in args.runlog:
         runlog_rows.extend(evidence.ingest_runlog(path))
@@ -234,11 +236,9 @@ def main(argv=None) -> int:
     for path in args.aot_stats:
         aot_rows.extend(evidence.ingest_aot_stats(path))
     config = None
-    try:
+    if args.config:
         with open(args.config) as f:
             config = json.load(f)
-    except (OSError, ValueError):
-        config = None
 
     rep = build_report(rows, quarantined, config, runlog_rows, aot_rows)
     if args.as_json:
