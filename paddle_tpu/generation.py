@@ -167,6 +167,31 @@ def _attend_gqa(q, k, v, score_mask, rep):
     return out.reshape(b, s, h, d).astype(q.dtype)
 
 
+@jax.named_scope("kv_write")
+def _layer_pools(k_pools, v_pools, i):
+    """Layer ``i``'s pools out of the stacked [L, P, kvh, bs, D] ones."""
+    return k_pools[i], v_pools[i]
+
+
+@jax.named_scope("kv_write")
+def _kv_write(kp, vp, k, v, scatter):
+    """Scatter this step's K/V rows ([T, 1, kvh, D]) into one layer's paged
+    pools at ``scatter`` = (pages [T], offs [T]); page index P (one past the
+    pool) is a dropped row. Scope ``kv_write``: with ``_layer_pools`` and
+    ``_stack_pools`` the whole cost of keeping the pools, whatever the
+    attention reads."""
+    pages, offs = scatter
+    kp = kp.at[pages, :, offs, :].set(k[:, 0].astype(kp.dtype), mode="drop")
+    vp = vp.at[pages, :, offs, :].set(v[:, 0].astype(vp.dtype), mode="drop")
+    return kp, vp
+
+
+@jax.named_scope("kv_write")
+def _stack_pools(new_k, new_v):
+    """The per-layer pools back into the [L, P, kvh, bs, D] the step takes."""
+    return jnp.stack(new_k), jnp.stack(new_v)
+
+
 class _LlamaDecoder:
     """Pure functions over a LlamaForCausalLM state dict.
 
@@ -242,14 +267,16 @@ class _LlamaDecoder:
         """Residual + output projection + MLP, shared by both layer paths;
         att: [B, S, H*D]."""
         pre = f"model.layers.{i}."
-        h = h + _mm(att, w, pre + "self_attn.o_proj.weight")
-        x2 = _rms(h, self._lw(w, i, "post_attention_layernorm.weight"),
-                  self.eps)
-        gate = _mm(x2, w, pre + "mlp.gate_proj.weight")
-        up = _mm(x2, w, pre + "mlp.up_proj.weight")
-        swi = (jax.nn.silu(gate.astype(jnp.float32))
-               .astype(up.dtype) * up)
-        return h + _mm(swi, w, pre + "mlp.down_proj.weight")
+        with jax.named_scope("attn_proj"):
+            h = h + _mm(att, w, pre + "self_attn.o_proj.weight")
+        with jax.named_scope("mlp"):
+            x2 = _rms(h, self._lw(w, i, "post_attention_layernorm.weight"),
+                      self.eps)
+            gate = _mm(x2, w, pre + "mlp.gate_proj.weight")
+            up = _mm(x2, w, pre + "mlp.up_proj.weight")
+            swi = (jax.nn.silu(gate.astype(jnp.float32))
+                   .astype(up.dtype) * up)
+            return h + _mm(swi, w, pre + "mlp.down_proj.weight")
 
     def _layer(self, w, i, h, cos, sin, kc, vc, write_pos, score_mask):
         """One decoder layer with cache append; h: [B, S, H*D]."""
@@ -289,17 +316,14 @@ class _LlamaDecoder:
         and the attention output right before the row-parallel o_proj,
         the same two seams the training side shards."""
         t, s, _ = h.shape
-        x = _rms(h, self._lw(w, i, "input_layernorm.weight"), self.eps)
-        q, k, v = self._qkv_proj(w, i, x, t, s)
-        q = _rope_rows(q, cos, sin)
-        k = _rope_rows(k, cos, sin)
-        if shard is not None:
-            q, k, v = shard.qkv(q, k, v)
-        pages, offs = scatter
-        kp = kp.at[pages, :, offs, :].set(k[:, 0].astype(kp.dtype),
-                                          mode="drop")
-        vp = vp.at[pages, :, offs, :].set(v[:, 0].astype(vp.dtype),
-                                          mode="drop")
+        with jax.named_scope("attn_proj"):
+            x = _rms(h, self._lw(w, i, "input_layernorm.weight"), self.eps)
+            q, k, v = self._qkv_proj(w, i, x, t, s)
+            q = _rope_rows(q, cos, sin)
+            k = _rope_rows(k, cos, sin)
+            if shard is not None:
+                q, k, v = shard.qkv(q, k, v)
+        kp, vp = _kv_write(kp, vp, k, v, scatter)
         att = attend(q[:, 0], kp, vp).reshape(t, 1, -1)
         if shard is not None:
             att = shard.att(att)
@@ -312,18 +336,19 @@ class _LlamaDecoder:
         absolute position); k_pools/v_pools: [L, P, kvh, bs, D] shared
         block pools; scatter/attend/shard as in _layer_ragged. Returns
         (logits [T, V], k_pools', v_pools')."""
-        emb = w[self.embed_key]
-        h = emb[tokens][:, None]                     # [T, 1, H*D]
-        cos = w["__rope_cos"][positions][:, None]    # [T, 1, hd/2]
-        sin = w["__rope_sin"][positions][:, None]
+        with jax.named_scope("embed"):
+            emb = w[self.embed_key]
+            h = emb[tokens][:, None]                     # [T, 1, H*D]
+            cos = w["__rope_cos"][positions][:, None]    # [T, 1, hd/2]
+            sin = w["__rope_sin"][positions][:, None]
         new_k, new_v = [], []
         for i in range(self.n_layers):
-            h, kp, vp = self._layer_ragged(w, i, h, cos, sin, k_pools[i],
-                                           v_pools[i], scatter, attend,
-                                           shard=shard)
+            h, kp, vp = self._layer_ragged(
+                w, i, h, cos, sin, *_layer_pools(k_pools, v_pools, i),
+                scatter, attend, shard=shard)
             new_k.append(kp)
             new_v.append(vp)
-        return self._logits(w, h)[:, 0], jnp.stack(new_k), jnp.stack(new_v)
+        return self._logits(w, h)[:, 0], *_stack_pools(new_k, new_v)
 
     _TP_COL = ("self_attn.q_proj.weight", "self_attn.k_proj.weight",
                "self_attn.v_proj.weight", "mlp.gate_proj.weight",
@@ -347,6 +372,7 @@ class _LlamaDecoder:
                 specs[pre + n] = ("mp", None)
         return specs
 
+    @jax.named_scope("head")
     def _logits(self, w, h):
         h = _rms(h, w["model.norm.weight"], self.eps)
         return _head_logits(w, h, self.tied, self.embed_key)
@@ -484,16 +510,18 @@ class _GPTDecoder:
     def _post_attn(self, w, i, h, att):
         """Residual + out proj + (MoE-)MLP, shared by both layer paths."""
         p = f"transformer.h.{i}."
-        h = h + _mm(att, w, p + "attn.out_proj.weight") \
-            + w[p + "attn.out_proj.bias"]
-        x2 = _ln(h, w[p + "ln_2.weight"], w[p + "ln_2.bias"], self.eps)
-        if i in self.moe_layers:
-            return h + self._moe_mlp(w, i, x2)
-        m = jax.nn.gelu((_mm(x2, w, p + "mlp.fc_in.weight")
-                         + w[p + "mlp.fc_in.bias"]).astype(jnp.float32),
-                        approximate=False).astype(h.dtype)
-        return h + _mm(m, w, p + "mlp.fc_out.weight") \
-            + w[p + "mlp.fc_out.bias"]
+        with jax.named_scope("attn_proj"):
+            h = h + _mm(att, w, p + "attn.out_proj.weight") \
+                + w[p + "attn.out_proj.bias"]
+        with jax.named_scope("mlp"):
+            x2 = _ln(h, w[p + "ln_2.weight"], w[p + "ln_2.bias"], self.eps)
+            if i in self.moe_layers:
+                return h + self._moe_mlp(w, i, x2)
+            m = jax.nn.gelu((_mm(x2, w, p + "mlp.fc_in.weight")
+                             + w[p + "mlp.fc_in.bias"]).astype(jnp.float32),
+                            approximate=False).astype(h.dtype)
+            return h + _mm(m, w, p + "mlp.fc_out.weight") \
+                + w[p + "mlp.fc_out.bias"]
 
     def _layer(self, w, i, h, kc, vc, write_pos, score_mask):
         p = f"transformer.h.{i}."
@@ -512,15 +540,12 @@ class _GPTDecoder:
         GPT has no rope — positions enter through the wpe embedding."""
         p = f"transformer.h.{i}."
         t, s, _ = h.shape
-        x = _ln(h, w[p + "ln_1.weight"], w[p + "ln_1.bias"], self.eps)
-        q, k, v = self._qkv_proj(w, i, x, t, s)
-        if shard is not None:
-            q, k, v = shard.qkv(q, k, v)
-        pages, offs = scatter
-        kp = kp.at[pages, :, offs, :].set(k[:, 0].astype(kp.dtype),
-                                          mode="drop")
-        vp = vp.at[pages, :, offs, :].set(v[:, 0].astype(vp.dtype),
-                                          mode="drop")
+        with jax.named_scope("attn_proj"):
+            x = _ln(h, w[p + "ln_1.weight"], w[p + "ln_1.bias"], self.eps)
+            q, k, v = self._qkv_proj(w, i, x, t, s)
+            if shard is not None:
+                q, k, v = shard.qkv(q, k, v)
+        kp, vp = _kv_write(kp, vp, k, v, scatter)
         att = attend(q[:, 0], kp, vp).reshape(t, 1, -1)
         if shard is not None:
             att = shard.att(att)
@@ -529,18 +554,21 @@ class _GPTDecoder:
     def step_ragged(self, w, tokens, positions, k_pools, v_pools, scatter,
                     attend, shard=None):
         """Ragged-batch twin of step(); see _LlamaDecoder.step_ragged."""
-        h = (w["transformer.wte.weight"][tokens]
-             + w["transformer.wpe.weight"][positions])[:, None]
+        with jax.named_scope("embed"):
+            h = (w["transformer.wte.weight"][tokens]
+                 + w["transformer.wpe.weight"][positions])[:, None]
         new_k, new_v = [], []
         for i in range(self.n_layers):
-            h, kp, vp = self._layer_ragged(w, i, h, k_pools[i], v_pools[i],
-                                           scatter, attend, shard=shard)
+            h, kp, vp = self._layer_ragged(
+                w, i, h, *_layer_pools(k_pools, v_pools, i), scatter, attend,
+                shard=shard)
             new_k.append(kp)
             new_v.append(vp)
-        h = _ln(h, w["transformer.ln_f.weight"], w["transformer.ln_f.bias"],
-                self.eps)
-        logits = _head_logits(w, h, self.tied, self.embed_key)
-        return logits[:, 0], jnp.stack(new_k), jnp.stack(new_v)
+        with jax.named_scope("head"):
+            h = _ln(h, w["transformer.ln_f.weight"],
+                    w["transformer.ln_f.bias"], self.eps)
+            logits = _head_logits(w, h, self.tied, self.embed_key)
+        return logits[:, 0], *_stack_pools(new_k, new_v)
 
     def tp_specs(self):
         """See _LlamaDecoder.tp_specs. GPT's fused qkv projection packs

@@ -235,6 +235,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, bounds=None,
                                masked=masked, window=window)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -440,6 +441,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         functools.partial(_fa_dq_kernel, scale=s, causal=causal, block_q=bq,
                           block_k=bk, nk=nk, offset=sk - sq, masked=masked,
                           window=window),
+        name="flash_dq",
         grid=(bh, nq, nk),
         in_specs=dq_in_specs,
         out_specs=q_spec,
@@ -479,6 +481,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         functools.partial(_fa_dkv_kernel, scale=s, causal=causal, block_q=bq,
                           block_k=bk, nq=nq, offset=sk - sq, masked=masked,
                           window=window),
+        name="flash_dkv",
         grid=(bh, nk, nq),
         in_specs=dkv_in_specs,
         out_specs=[kv_spec, kv_spec],

@@ -15,6 +15,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from .. import nn
@@ -76,6 +77,7 @@ class GPTAttention(nn.Layer):
         self.qkv_proj = ColumnParallelLinear(h, 3 * h, has_bias=True)
         self.out_proj = RowParallelLinear(h, h, has_bias=True)
 
+    @jax.named_scope("attention")
     def forward(self, x, attention_mask=None):
         b, s, h = x.shape
         qkv = self.qkv_proj(x).reshape([b, s, 3, self.num_heads,
@@ -95,6 +97,7 @@ class GPTMLP(nn.Layer):
         self.fc_out = RowParallelLinear(config.ffn_size, config.hidden_size,
                                         has_bias=True)
 
+    @jax.named_scope("mlp")
     def forward(self, x):
         return self.fc_out(F.gelu(self.fc_in(x)))
 
@@ -144,8 +147,9 @@ class GPTModel(nn.Layer):
             raise ValueError(
                 f"sequence length {s} exceeds max_position_embeddings "
                 f"{self.config.max_position_embeddings}")
-        pos = Tensor(jnp.arange(s, dtype=jnp.int32))
-        x = self.wte(input_ids) + self.wpe(pos)
+        with jax.named_scope("embed"):
+            pos = Tensor(jnp.arange(s, dtype=jnp.int32))
+            x = self.wte(input_ids) + self.wpe(pos)
         for block in self.h:
             x = block(x, attention_mask)
         return self.ln_f(x)
@@ -165,10 +169,12 @@ class GPTForCausalLM(nn.Layer):
 
     def forward(self, input_ids, attention_mask=None):
         h = self.transformer(input_ids, attention_mask)
-        if self.lm_head is None:
-            from ..ops.linalg import matmul
-            return matmul(h, self.transformer.wte.weight, transpose_y=True)
-        return self.lm_head(h)
+        with jax.named_scope("head_loss"):
+            if self.lm_head is None:
+                from ..ops.linalg import matmul
+                return matmul(h, self.transformer.wte.weight,
+                              transpose_y=True)
+            return self.lm_head(h)
 
     def generate(self, input_ids, attention_mask=None, **kwargs):
         """KV-cached decoding (dense blocks only; see generation.py)."""
@@ -187,6 +193,7 @@ class GPTForCausalLM(nn.Layer):
             return None
         return total * self.config.aux_loss_weight
 
+    @jax.named_scope("head_loss")
     def compute_loss(self, logits, labels):
         from ..ops.manipulation import reshape
         b, s, v = logits.shape

@@ -144,6 +144,7 @@ class LlamaAttention(nn.Layer):
         self.o_proj = Row(self.num_heads * self.head_dim, self.hidden_size,
                           has_bias=False)
 
+    @jax.named_scope("attention")
     def forward(self, hidden_states, rope_cache, attention_mask=None,
                 startend_row_indices=None):
         b, s, _ = hidden_states.shape
@@ -193,6 +194,7 @@ class LlamaMLP(nn.Layer):
         self.down_proj = Row(config.intermediate_size, config.hidden_size,
                              has_bias=False)
 
+    @jax.named_scope("mlp")
     def forward(self, x):
         return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
 
@@ -237,7 +239,8 @@ class LlamaModel(nn.Layer):
 
     def forward(self, input_ids, attention_mask=None,
                 attn_startend_row_indices=None):
-        h = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            h = self.embed_tokens(input_ids)
         if self.config.sequence_parallel:
             # Megatron-SP: activations between blocks live seq-sharded over mp
             # (reference: split_inputs_sequence_dim + ScatterOp after embed)
@@ -285,6 +288,11 @@ class LlamaForCausalLM(nn.Layer):
         attention_mask."""
         h = self.model(input_ids, attention_mask,
                        attn_startend_row_indices)
+        return self._head(h)
+
+    @jax.named_scope("head_loss")
+    def _head(self, h):
+        """Logits of the (tied or own) head, under the loss's scope."""
         if self.lm_head is None:
             from ..ops.linalg import matmul
             return matmul(h, self.model.embed_tokens.weight, transpose_y=True)
@@ -297,6 +305,7 @@ class LlamaForCausalLM(nn.Layer):
         return generate(self, input_ids, attention_mask=attention_mask,
                         **kwargs)
 
+    @jax.named_scope("head_loss")
     def compute_loss(self, logits, labels):
         """Shifted next-token cross entropy."""
         from ..ops.manipulation import reshape
@@ -362,7 +371,8 @@ class LlamaForCausalLM(nn.Layer):
                 (hs, ys, valid))
             return tot / jnp.maximum(cnt, 1).astype(jnp.float32)
 
-        return dispatch("chunked_causal_ce", fwd, h, ensure_tensor(w), lt)
+        with jax.named_scope("head_loss"):
+            return dispatch("chunked_causal_ce", fwd, h, ensure_tensor(w), lt)
 
     # -- pipeline protocol (parallel.pipeline.PipelinedTrainer) ---------------
     def pp_block_layers(self):
@@ -379,14 +389,7 @@ class LlamaForCausalLM(nn.Layer):
         return h, (cos, sin)
 
     def pp_tail(self, h, labels):
-        h = self.model.norm(h)
-        if self.lm_head is None:
-            from ..ops.linalg import matmul
-            logits = matmul(h, self.model.embed_tokens.weight,
-                            transpose_y=True)
-        else:
-            logits = self.lm_head(h)
-        return self.compute_loss(logits, labels)
+        return self.compute_loss(self._head(self.model.norm(h)), labels)
 
     def pp_embed_param_names(self):
         return ["model.embed_tokens.weight"]

@@ -32,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..autograd.tape import no_grad
 from ..utils.jax_compat import shard_map
 from ..framework.random import key_context, next_key
+from ..profiler import RecordEvent
 from ..optimizer import (ClipGradByGlobalNorm, ClipGradByNorm, ClipGradByValue,
                          Optimizer)
 from ..tensor import Tensor
@@ -394,13 +395,17 @@ class SpmdTrainer:
     def _apply_update(self, params, grads, opt_state, lr, step_i):
         """Shared step epilogue: grad clip + per-param optimizer update."""
         opt = self.opt
-        grads = _clip_grads_functional(opt._grad_clip, params, grads)
+        with jax.named_scope("clip"):
+            grads = _clip_grads_functional(opt._grad_clip, params, grads)
         asp_masks = self._active_asp_masks()
-        if self._use_sharded_update(asp_masks):
-            return self._apply_update_sharded(params, grads, opt_state, lr,
-                                              step_i)
-        return self._update_loop(params, grads, opt_state, lr, step_i,
-                                 asp_masks)
+        # the scope the eager Optimizer.step carries too: the update's
+        # device time is found by name, whichever path ran it
+        with jax.named_scope("optimizer_step"):
+            if self._use_sharded_update(asp_masks):
+                return self._apply_update_sharded(params, grads, opt_state,
+                                                  lr, step_i)
+            return self._update_loop(params, grads, opt_state, lr, step_i,
+                                     asp_masks)
 
     @staticmethod
     def _active_asp_masks():
@@ -622,31 +627,32 @@ class SpmdTrainer:
         """One compiled fwd+bwd+update step. batch: Tensors or arrays."""
         batch_arrays = tuple(b._data if isinstance(b, Tensor) else jnp.asarray(b)
                              for b in batch)
-        # validated per call: jit retraces on new shapes, and a
-        # non-divisible batch must fail with THIS message, not a reshape
-        # error deep inside the trace
-        self._check_accumulate_batch(batch_arrays)
-        if self._opt_state is None:
-            self._place_params()
-            self._opt_state = self._init_opt_state()
-        if self._step_fn is None:
-            self._step_fn = self._build(batch_arrays)
-        self._step_count += 1
-        params = {n: self._params[n]._data for n in self._param_list}
-        lr = jnp.float32(self.opt.get_lr())
-        loss, new_params, new_state = self._step_fn(
-            params, self._opt_state, lr, jnp.float32(self._step_count),
-            next_key(), *batch_arrays)
-        for n in self._param_list:
-            self._params[n]._data = new_params[n]
-        self._opt_state = new_state
-        self.opt._global_step = self._step_count
-        self._last_loss = loss
-        if self.memwatch is not None:
-            if not self._mem_pools_tagged:
-                self._tag_mem_pools()
-            self.memwatch.snapshot(step=self._step_count)
-        return Tensor(loss)
+        with RecordEvent("train.step"):
+            # validated per call: jit retraces on new shapes, and a
+            # non-divisible batch must fail with THIS message, not a reshape
+            # error deep inside the trace
+            self._check_accumulate_batch(batch_arrays)
+            if self._opt_state is None:
+                self._place_params()
+                self._opt_state = self._init_opt_state()
+            if self._step_fn is None:
+                self._step_fn = self._build(batch_arrays)
+            self._step_count += 1
+            params = {n: self._params[n]._data for n in self._param_list}
+            lr = jnp.float32(self.opt.get_lr())
+            loss, new_params, new_state = self._step_fn(
+                params, self._opt_state, lr, jnp.float32(self._step_count),
+                next_key(), *batch_arrays)
+            for n in self._param_list:
+                self._params[n]._data = new_params[n]
+            self._opt_state = new_state
+            self.opt._global_step = self._step_count
+            self._last_loss = loss
+            if self.memwatch is not None:
+                if not self._mem_pools_tagged:
+                    self._tag_mem_pools()
+                self.memwatch.snapshot(step=self._step_count)
+            return Tensor(loss)
 
     def _tag_mem_pools(self):
         """Register the trainer's array families with the memory watcher
@@ -666,9 +672,10 @@ class SpmdTrainer:
         parameters, so the loss alone would leave the last update in
         flight)."""
         if self._last_loss is not None:
-            jax.block_until_ready(
-                (self._last_loss,
-                 [self._params[n]._data for n in self._param_list]))
+            with RecordEvent("train.block"):
+                jax.block_until_ready(
+                    (self._last_loss,
+                     [self._params[n]._data for n in self._param_list]))
 
     # checkpoint bridge: expose optimizer state in the eager optimizer format
     def sync_optimizer_state(self):

@@ -5,13 +5,16 @@ states CLOSED/READY/RECORD/RECORD_AND_RETURN, ProfilerTarget, RecordEvent
 utils.py:47, make_scheduler, chrome-trace export, summary tables) wrapping
 the C++ host tracer + CUPTI (fluid/platform/profiler/).
 
-TPU-native: host-side annotations are recorded in-process into ONE shared,
-lock-guarded buffer (spans may begin/end on any thread — dataloader worker
-spans are collected too); the framework emits spans per dispatched op, per
-train/eval phase (Forward/Backward/Optimization/Dataloader), and per
-collective entry point, all guarded by a single boolean so disabled runs
-pay one check. Device-side tracing delegates to jax.profiler (XLA's TPU
-trace), the platform's CUPTI equivalent.
+TPU-native: every ``RecordEvent`` is a ``jax.profiler.TraceAnnotation``, so
+its span lands in the host plane of whatever ``jax.profiler`` trace is
+running, beside the device operations and on their clock (XLA's TPU trace is
+the platform's CUPTI equivalent). While a ``Profiler`` records, spans are
+also kept in ONE shared, lock-guarded buffer (they may begin/end on any
+thread — dataloader worker spans are collected too) for ``summary()`` and
+the chrome export. The serving engine and the trainer carry fixed spans
+(``serve.*``, ``train.*``; README "Observability"); the spans per dispatched
+op and per collective entry point, thousands a step, stay behind a single
+boolean so disabled runs pay one check.
 
 Exports: chrome-trace JSON with rank-qualified pids, process/thread-name
 metadata and a wall-clock anchor (``tools/trace_merge.py`` merges N ranks
@@ -32,6 +35,8 @@ import threading
 import time
 from enum import Enum
 from typing import Callable, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from . import evidence, instrument, memwatch, metrics  # noqa: F401
 from . import runlog  # noqa: F401 (re-export)
@@ -107,19 +112,35 @@ def _trace_pid() -> int:
 
 
 class RecordEvent:
-    """Context manager / start-end span (parity: profiler/utils.py:47)."""
+    """Context manager / start-end span (parity: profiler/utils.py:47).
+
+    The span always enters a ``jax.profiler.TraceAnnotation``: whatever
+    ``jax.profiler`` trace is running (this module's ``Profiler`` or a bare
+    ``jax.profiler.start_trace``) gets it in its host plane, on the clock
+    the device operations are on, with ``counts`` as the event's arguments
+    (taken when the span is built). With no trace running that is an
+    inactive ``TraceMe``, under a microsecond. The private buffer is only
+    what ``Profiler.summary()`` and the chrome export read while a
+    ``Profiler`` records."""
+
+    __slots__ = ("name", "event_type", "counts", "_ann", "_begin")
 
     def __init__(self, name: str,
-                 event_type: TracerEventType = TracerEventType.UserDefined):
+                 event_type: TracerEventType = TracerEventType.UserDefined,
+                 **counts):
         self.name = name
         self.event_type = event_type
+        self.counts = counts
+        self._ann = TraceAnnotation(name, **counts)
         self._begin = None
 
     def begin(self):
-        # off path: one boolean check, no clock read
+        self._ann.__enter__()
+        # buffer off: one boolean check, no clock read
         self._begin = _now_us() if _tracer.enabled else None
 
     def end(self):
+        self._ann.__exit__(None, None, None)
         if self._begin is None or not _tracer.enabled:
             self._begin = None
             return
@@ -128,6 +149,8 @@ class RecordEvent:
             "ts": self._begin, "dur": _now_us() - self._begin,
             "pid": _trace_pid(), "tid": threading.get_ident() % 100000,
         }
+        if self.counts:
+            ev["args"] = dict(self.counts)
         with _tracer.lock:
             _tracer.events.append(ev)
         self._begin = None

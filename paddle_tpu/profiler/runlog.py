@@ -12,9 +12,8 @@ BENCH_* trajectory tooling parse it):
 ``mfu`` is a FLOPs-based model-flops-utilization estimate:
 ``flops_per_step / step_time_s / peak_flops`` — ``flops_per_step`` comes
 from :func:`model_flops_per_step` (a jaxpr walk via hapi.dynamic_flops,
-x3 for forward+backward) and ``peak_flops`` from the constructor or the
-``PADDLE_TPU_PEAK_FLOPS`` env var. Missing either leaves ``mfu: null``
-rather than inventing a number.
+x3 for forward+backward) and ``peak_flops`` from the constructor. Missing
+either leaves ``mfu: null`` rather than inventing a number.
 """
 from __future__ import annotations
 
@@ -60,9 +59,6 @@ class RunLog:
                 os.makedirs(parent, exist_ok=True)
         self.path = path
         self.flops_per_step = flops_per_step
-        if peak_flops is None:
-            env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
-            peak_flops = float(env) if env else None
         self.peak_flops = peak_flops
         self._f = open(path, "w")
         self._step = 0

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..profiler import RecordEvent
 from ..profiler import instrument as _instr
 from ..resilience import chaos
 from . import ragged as _ragged
@@ -589,34 +590,35 @@ class ServingEngine:
         ``reject``/``shed``, or a ``block`` timeout) with a structured
         retry-after estimate — overload becomes a clean, typed refusal
         instead of an unbounded queue."""
-        req = Request(prompt, max_new_tokens=max_new_tokens, eos_id=eos_id,
-                      on_token=on_token, stream=stream,
-                      ttft_deadline=ttft_deadline,
-                      tpot_deadline=tpot_deadline, tag=tag)
-        total = len(req.prompt) + req.max_new_tokens
-        if total > self.max_model_len:
-            raise ValueError(
-                f"prompt {len(req.prompt)} + max_new_tokens "
-                f"{req.max_new_tokens} exceeds max_model_len "
-                f"{self.max_model_len}")
-        # the last fed position is total-2 (the final sampled token is
-        # never fed), so the worst case is (total-2)//bs + 1 pages
-        if (total - 2) // self.pool.block_size + 1 > self.pool.num_blocks:
-            raise ValueError(
-                f"request needs more pages than the whole pool "
-                f"({self.pool.num_blocks} x {self.pool.block_size})")
-        if generated:
-            if len(generated) >= req.max_new_tokens:
+        with RecordEvent("serve.submit"):
+            req = Request(prompt, max_new_tokens=max_new_tokens, eos_id=eos_id,
+                          on_token=on_token, stream=stream,
+                          ttft_deadline=ttft_deadline,
+                          tpot_deadline=tpot_deadline, tag=tag)
+            total = len(req.prompt) + req.max_new_tokens
+            if total > self.max_model_len:
                 raise ValueError(
-                    f"replay carries {len(generated)} generated tokens "
-                    f"but max_new_tokens is {req.max_new_tokens} — "
-                    "nothing left to decode")
-            req.seq.extend(int(t) for t in generated)
-            req.output = [int(t) for t in generated]
-        self._admit(req, bypass=_bypass_admission)
-        self._work.set()
-        _instr.record_serve_queue_depth(self.sched.queue_depth())
-        return req
+                    f"prompt {len(req.prompt)} + max_new_tokens "
+                    f"{req.max_new_tokens} exceeds max_model_len "
+                    f"{self.max_model_len}")
+            # the last fed position is total-2 (the final sampled token is
+            # never fed), so the worst case is (total-2)//bs + 1 pages
+            if (total - 2) // self.pool.block_size + 1 > self.pool.num_blocks:
+                raise ValueError(
+                    f"request needs more pages than the whole pool "
+                    f"({self.pool.num_blocks} x {self.pool.block_size})")
+            if generated:
+                if len(generated) >= req.max_new_tokens:
+                    raise ValueError(
+                        f"replay carries {len(generated)} generated tokens "
+                        f"but max_new_tokens is {req.max_new_tokens} — "
+                        "nothing left to decode")
+                req.seq.extend(int(t) for t in generated)
+                req.output = [int(t) for t in generated]
+            self._admit(req, bypass=_bypass_admission)
+            self._work.set()
+            _instr.record_serve_queue_depth(self.sched.queue_depth())
+            return req
 
     def _admit(self, req: Request, bypass: bool = False) -> None:
         """Put one request on the waiting queue, applying the resilience
@@ -737,7 +739,16 @@ class ServingEngine:
         """Run one continuous-batching step: schedule, one device call,
         sample, evict — and on a prefill-role engine, export finished
         prefills' KV pages for hand-off to the decode pool. Returns
-        True while work remains."""
+        True while work remains.
+
+        Always under the ``serve.step`` span, with its phases inside
+        (``serve.schedule``, ``serve.run`` and its four children,
+        ``serve.post``): whatever ``jax.profiler`` trace runs sees where
+        the host was while the device stood idle."""
+        with RecordEvent("serve.step"):
+            return self._step()
+
+    def _step(self) -> bool:
         t0 = time.monotonic()
         obs = self.obs
         armed = obs is not None and obs.armed
@@ -745,7 +756,8 @@ class ServingEngine:
         with self._lock:
             q0 = self.pool.stats["prefix_queries"]
             h0 = self.pool.stats["prefix_hits"]
-            plan = self.sched.schedule()
+            with RecordEvent("serve.schedule"):
+                plan = self.sched.schedule()
             if not plan.entries:
                 # prefill-complete requests can exist even on an empty
                 # plan (everything schedulable was already swept):
@@ -780,7 +792,13 @@ class ServingEngine:
                 has_work = self.sched.has_work()
             else:
                 try:
-                    sampled = self._run_plan(plan, armed)
+                    with RecordEvent(
+                            "serve.run",
+                            prefill_tokens=plan.prefill_tokens,
+                            decode_tokens=plan.decode_tokens,
+                            first_scheduled=plan.first_scheduled,
+                            first_wait_s=plan.first_wait_s):
+                        sampled = self._run_plan(plan, armed)
                 except Exception as exc:  # noqa: BLE001 — containment seam
                     if self.resilience is None:
                         # disarmed: the pre-resilience contract — the
@@ -830,31 +848,33 @@ class ServingEngine:
         # -- outside the engine lock: hand-off dispatch, telemetry I/O,
         #    metrics (the sink takes the router lock, and lock order is
         #    always engine -> nothing while dispatching)
-        self._dispatch_handoffs(outbox)
-        if self.step_hook is not None:
-            self.step_hook()
-        if sampled is None:
+        with RecordEvent("serve.post"):
+            self._dispatch_handoffs(outbox)
+            if self.step_hook is not None:
+                self.step_hook()
+            if sampled is None:
+                return has_work
+            if armed and obs.telemetry_path and \
+                    self.steps % obs.config.telemetry_every == 0:
+                # telemetry file I/O happens OUTSIDE the engine lock —
+                # telemetry() takes it briefly for the snapshot, but the
+                # write must not stall concurrent submit() callers
+                obs.write_telemetry(self.telemetry())
+            dt = time.monotonic() - t0
+            _instr.record_serve_step(
+                plan.admitted, sampled["finished"], plan.preempted,
+                queue_depth, running, util)
+            _instr.record_serve_kv_pool_bytes(used_blocks * self.page_bytes)
+            _instr.record_serve_prefix(dq, dh)
+            for lat in sampled["ttfts"]:
+                _instr.record_serve_ttft(lat)
+            _instr.record_serve_tokens(sampled["tokens"], dt)
+            if plan.drafted:
+                _instr.record_serve_spec_tokens(plan.drafted,
+                                                sampled["accepted"])
+            _instr.record_serve_spec_rollback(sampled["rollback_pages"])
+            self._notify_admit()
             return has_work
-        if armed and obs.telemetry_path and \
-                self.steps % obs.config.telemetry_every == 0:
-            # telemetry file I/O happens OUTSIDE the engine lock —
-            # telemetry() takes it briefly for the snapshot, but the
-            # write must not stall concurrent submit() callers
-            obs.write_telemetry(self.telemetry())
-        dt = time.monotonic() - t0
-        _instr.record_serve_step(plan.admitted, sampled["finished"],
-                                 plan.preempted, queue_depth, running, util)
-        _instr.record_serve_kv_pool_bytes(used_blocks * self.page_bytes)
-        _instr.record_serve_prefix(dq, dh)
-        for lat in sampled["ttfts"]:
-            _instr.record_serve_ttft(lat)
-        _instr.record_serve_tokens(sampled["tokens"], dt)
-        if plan.drafted:
-            _instr.record_serve_spec_tokens(plan.drafted,
-                                            sampled["accepted"])
-        _instr.record_serve_spec_rollback(sampled["rollback_pages"])
-        self._notify_admit()
-        return has_work
 
     def _notify_admit(self) -> None:
         """Wake submitters blocked on queue room (policy ``block``)."""
@@ -1162,16 +1182,47 @@ class ServingEngine:
             self._work.set()
 
     def _run_plan(self, plan, armed: bool = False) -> dict:
+        """One planned step, in the four phases its ``serve.*`` spans
+        name: pack the host arrays, launch the step program, wait for the
+        device (``serve.sync``), hand the sampled tokens out."""
         # the step-fault drill seam: an injected error here is exactly a
         # device step blowing up with requests mid-flight (contained by
         # _contain_step_fault when the resilience plane is armed)
         chaos.site("serve.engine_step")
+        with RecordEvent("serve.pack"):
+            tokens, slots, positions, valid, sample_points = \
+                self._pack_plan(plan, armed)
+        with RecordEvent("serve.launch"):
+            logits, self._kp, self._vp = self._step_call(
+                self._w, jnp.asarray(tokens), jnp.asarray(slots),
+                jnp.asarray(positions), jnp.asarray(valid),
+                jnp.asarray(self._tables), self._kp, self._vp)
+        with RecordEvent("serve.sync"):
+            res = self.resilience
+            if res is not None and res.nan_guard and \
+                    not bool(_all_finite(logits)):
+                # garbage logits: fail the STEP before any token of it can
+                # reach a client (pools already swapped — consistent; the
+                # containment path requeues everything for recompute)
+                raise _res.StepFault(
+                    "nan_logits", f"step {self.steps + 1} produced non-finite "
+                    f"logits over {int(valid.sum())} packed tokens")
+            all_tok = np.asarray(_argmax_rows(logits)) \
+                if sample_points else None
+        with RecordEvent("serve.emit"):
+            return self._emit_sampled(plan, sample_points, all_tok, armed)
+
+    def _pack_plan(self, plan, armed: bool):
+        """The numpy fill of the step program's inputs (and of the page
+        table rows of the scheduled slots). Returns (tokens, slots,
+        positions, valid, sample_points): sample_points are (entry, row of
+        its LAST seq token)."""
         t_max = self.config.token_budget
         tokens = np.zeros(t_max, np.int32)
         slots = np.zeros(t_max, np.int32)
         positions = np.zeros(t_max, np.int32)
         valid = np.zeros(t_max, bool)
-        sample_points = []             # (entry, row of its LAST seq token)
+        sample_points = []
         idx = 0
         for e in plan.entries:
             n, k = e.n, len(e.draft)
@@ -1192,105 +1243,99 @@ class ServingEngine:
             if armed and e.start + e.n < len(e.req.seq):
                 self.obs.on_prefill(e.req, e.start, e.n)
             idx += n + k
-        logits, self._kp, self._vp = self._step_call(
-            self._w, jnp.asarray(tokens), jnp.asarray(slots),
-            jnp.asarray(positions), jnp.asarray(valid),
-            jnp.asarray(self._tables), self._kp, self._vp)
-        res = self.resilience
-        if res is not None and res.nan_guard and \
-                not bool(_all_finite(logits)):
-            # garbage logits: fail the STEP before any token of it can
-            # reach a client (pools already swapped — consistent; the
-            # containment path requeues everything for recompute)
-            raise _res.StepFault(
-                "nan_logits", f"step {self.steps + 1} produced non-finite "
-                f"logits over {int(valid.sum())} packed tokens")
+        return tokens, slots, positions, valid, sample_points
+
+    def _emit_sampled(self, plan, sample_points, all_tok, armed: bool) -> dict:
+        """Confirm the fed positions and hand out the sampled tokens
+        (``all_tok``: the step's argmax row per packed token, on the host):
+        verify drafts, emit, roll back rejected pages, evict the finished.
+        Returns the step's counts."""
         out = {"tokens": 0, "finished": 0, "finished_rids": [],
                "ttfts": [], "accepted": 0, "rollback_pages": 0}
         for e in plan.entries:
             e.req.pos = e.start + e.n    # draft positions confirmed below
-        if sample_points:
-            all_tok = np.asarray(_argmax_rows(logits))
-            now = time.monotonic()
-            finished = []
-            for e, i in sample_points:
-                req = e.req
-                k = len(e.draft)
-                targets = [int(t) for t in all_tok[i:i + k + 1]]
-                if k:
-                    try:
-                        chaos.site("serve.spec_verify")
-                        _, emitted = verify_greedy(e.draft, targets)
-                    except chaos.FaultInjected:
-                        # full-rejection drill: every draft is discarded,
-                        # but the bonus token still lands — the engine
-                        # never falls below one token per seq per step
-                        emitted = targets[:1]
-                        if armed:
-                            if plan.explain is not None:
-                                plan.explain["chaos"].append(
-                                    "serve.spec_verify")
-                            self.obs.note_anomaly(
-                                "chaos_fault",
-                                {"site": "serve.spec_verify",
-                                 "rid": req.rid})
-                else:
+        if not sample_points:
+            return out
+        now = time.monotonic()
+        finished = []
+        for e, i in sample_points:
+            req = e.req
+            k = len(e.draft)
+            targets = [int(t) for t in all_tok[i:i + k + 1]]
+            if k:
+                try:
+                    chaos.site("serve.spec_verify")
+                    _, emitted = verify_greedy(e.draft, targets)
+                except chaos.FaultInjected:
+                    # full-rejection drill: every draft is discarded,
+                    # but the bonus token still lands — the engine
+                    # never falls below one token per seq per step
                     emitted = targets[:1]
-                used = 0
-                for tok in emitted:
-                    if req.first_token_at is None:
-                        req.first_token_at = now
-                        out["ttfts"].append(now - req.arrival)
-                        if armed:
-                            self.obs.on_first_token(req, now - req.arrival)
-                    req.emit(tok)
-                    self.tokens_generated += 1
-                    out["tokens"] += 1
-                    used += 1
-                    if (len(req.output) >= req.max_new_tokens
-                            or (req.eos_id is not None
-                                and tok == req.eos_id)):
-                        req.finish_reason = (
-                            "eos" if req.eos_id is not None
-                            and tok == req.eos_id else "max_new_tokens")
-                        finished.append(req)
-                        break
-                # used-1 drafts were confirmed correct (eos may cut the
-                # emission short of the full accepted prefix)
-                consumed = used - 1
-                out["accepted"] += consumed
-                req.pos = e.start + e.n + consumed
-                if armed:
-                    self.obs.on_decode(req, used, k, consumed)
-                if consumed < k:
-                    # rejected drafts left garbage K/V past the accepted
-                    # frontier: roll the page list back; copy-on-write if
-                    # the kept boundary page is shared (rollback must
-                    # never mutate a page another holder can read)
-                    kept, released, cow = self.pool.truncate(req.pages,
-                                                             req.pos)
-                    req.pages = kept
-                    out["rollback_pages"] += released
-                    if cow is not None:
-                        self._kp, self._vp = _copy_page(
-                            self._kp, self._vp, cow[0], cow[1])
-            for req in finished:
-                self.sched.evict_finished(req)
-                if req.finished_at is not None:
-                    # service-time evidence the admission-control
-                    # estimates (retry-after, predicted queue wait)
-                    # read; a handed-off request clocks from its
-                    # hand-off, not the original submit — decode-pool
-                    # estimates must not be polluted by prefill time
-                    self._e2e_sum += req.finished_at - (
-                        req.handoff_at if req.handoff_at is not None
-                        else req.arrival)
-                    self._e2e_n += 1
-            out["finished"] = len(finished)
-            out["finished_rids"] = [r.rid for r in finished]
-            self.spec_proposed += plan.drafted
-            self.spec_accepted += out["accepted"]
-            self.spec_rollback_pages += out["rollback_pages"]
+                    if armed:
+                        if plan.explain is not None:
+                            plan.explain["chaos"].append(
+                                "serve.spec_verify")
+                        self.obs.note_anomaly(
+                            "chaos_fault",
+                            {"site": "serve.spec_verify",
+                             "rid": req.rid})
+            else:
+                emitted = targets[:1]
+            used = 0
+            for tok in emitted:
+                if req.first_token_at is None:
+                    req.first_token_at = now
+                    out["ttfts"].append(now - req.arrival)
+                    if armed:
+                        self.obs.on_first_token(req, now - req.arrival)
+                req.emit(tok)
+                self.tokens_generated += 1
+                out["tokens"] += 1
+                used += 1
+                if (len(req.output) >= req.max_new_tokens
+                        or (req.eos_id is not None
+                            and tok == req.eos_id)):
+                    req.finish_reason = (
+                        "eos" if req.eos_id is not None
+                        and tok == req.eos_id else "max_new_tokens")
+                    finished.append(req)
+                    break
+            # used-1 drafts were confirmed correct (eos may cut the
+            # emission short of the full accepted prefix)
+            consumed = used - 1
+            out["accepted"] += consumed
+            req.pos = e.start + e.n + consumed
+            if armed:
+                self.obs.on_decode(req, used, k, consumed)
+            if consumed < k:
+                # rejected drafts left garbage K/V past the accepted
+                # frontier: roll the page list back; copy-on-write if
+                # the kept boundary page is shared (rollback must
+                # never mutate a page another holder can read)
+                kept, released, cow = self.pool.truncate(req.pages,
+                                                         req.pos)
+                req.pages = kept
+                out["rollback_pages"] += released
+                if cow is not None:
+                    self._kp, self._vp = _copy_page(
+                        self._kp, self._vp, cow[0], cow[1])
+        for req in finished:
+            self.sched.evict_finished(req)
+            if req.finished_at is not None:
+                # service-time evidence the admission-control
+                # estimates (retry-after, predicted queue wait)
+                # read; a handed-off request clocks from its
+                # hand-off, not the original submit — decode-pool
+                # estimates must not be polluted by prefill time
+                self._e2e_sum += req.finished_at - (
+                    req.handoff_at if req.handoff_at is not None
+                    else req.arrival)
+                self._e2e_n += 1
+        out["finished"] = len(finished)
+        out["finished_rids"] = [r.rid for r in finished]
+        self.spec_proposed += plan.drafted
+        self.spec_accepted += out["accepted"]
+        self.spec_rollback_pages += out["rollback_pages"]
         return out
 
     def run_until_idle(self, max_steps: Optional[int] = None) -> int:

@@ -82,9 +82,13 @@ def make_attend(page_tables, slot_ids, positions, valid, rep):
     """Bind the ragged metadata into the ``attend(q, kp, vp)`` callable
     ``generation.step_ragged`` expects, routing through the Pallas kernel
     when it is flag-enabled (the kernel walks one query token per grid
-    cell, so prefill chunks are served but not blocked; ROADMAP S4)."""
+    cell, so prefill chunks are served but not blocked; ROADMAP S4).
+    Whatever implements it runs under the scope ``paged_attention``: the
+    device time of the gather over the page tables and of the attention
+    itself is found by that name."""
     from ..kernels import ragged_pallas as _rp
 
+    @jax.named_scope("paged_attention")
     def attend(q, kp, vp):
         if _rp.enabled():
             return _rp.ragged_decode_attention(

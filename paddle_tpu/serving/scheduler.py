@@ -138,6 +138,9 @@ class Request:
         # -time evidence clocks from here, not from the original submit,
         # so prefill time never pollutes the decode pool's estimates
         self.handoff_at: Optional[float] = None
+        # when the scheduler first gave this request an entry of a plan
+        # (kept across preemption: the queue wait is counted once)
+        self.first_planned_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
         self.finish_reason: Optional[str] = None
@@ -230,10 +233,13 @@ class StepEntry:
 
 
 class StepPlan:
-    __slots__ = ("entries", "admitted", "preempted", "drafted", "explain")
+    __slots__ = ("entries", "admitted", "preempted", "drafted", "explain",
+                 "decode_tokens", "prefill_tokens", "first_scheduled",
+                 "first_wait_s")
 
     def __init__(self, entries, admitted, preempted, drafted=0,
-                 explain=None):
+                 explain=None, decode_tokens=0, prefill_tokens=0,
+                 first_scheduled=0, first_wait_s=0.0):
         self.entries: List[StepEntry] = entries
         self.admitted: int = admitted
         self.preempted: int = preempted
@@ -242,6 +248,16 @@ class StepPlan:
         # budget split, who was admitted/preempted and WHY, exhaustion
         # events, spec outcome. None when the obs plane is disarmed.
         self.explain: Optional[dict] = explain
+        # the budget split, counted as the entries are made (the engine's
+        # ``serve.run`` span carries them; ``explain`` reads the same
+        # count): one token per sequence in its decode phase, and the
+        # chunks of sequences still inside their prompt
+        self.decode_tokens: int = decode_tokens
+        self.prefill_tokens: int = prefill_tokens
+        # requests given their first entry ever by this plan, and the sum
+        # over them of (now - arrival): the queue wait as the engine saw it
+        self.first_scheduled: int = first_scheduled
+        self.first_wait_s: float = first_wait_s
 
     @property
     def total_tokens(self) -> int:
@@ -466,10 +482,14 @@ class Scheduler:
         decode_entries: List[StepEntry] = []
         budget = self.token_budget
         admitted = preempted = drafted = 0
+        decode_tokens = prefill_tokens = first_scheduled = 0
+        first_wait_s = 0.0
+        now = time.monotonic()
         obs = self.obs
         armed = obs is not None and obs.armed
         explain = None
         if armed:
+            # the two token counts are filled in from the plan's at the end
             explain = {"budget_total": budget, "decode_tokens": 0,
                        "prefill_tokens": 0, "drafted_tokens": 0,
                        "admitted": [], "preempted": [], "exhaustion": [],
@@ -506,8 +526,7 @@ class Scheduler:
             entries.append(e)
             decode_entries.append(e)
             budget -= 1
-            if explain is not None:
-                explain["decode_tokens"] += 1
+            decode_tokens += 1
 
         # 2) prefill chunks for running requests still inside their prompt
         #    (chunked prefill: admitted earlier, prompt longer than the
@@ -523,8 +542,7 @@ class Scheduler:
                 continue
             entries.append(StepEntry(req, req.pos, chunk))
             budget -= chunk
-            if explain is not None:
-                explain["prefill_tokens"] += chunk
+            prefill_tokens += chunk
 
         # 3) admission, strictly FIFO. Static policy: gang admission into
         #    an empty batch only (the BatchingServer baseline).
@@ -599,8 +617,12 @@ class Scheduler:
             entries.append(StepEntry(req, req.pos, chunk))
             budget -= chunk
             admitted += 1
+            prefill_tokens += chunk
+            if req.first_planned_at is None:
+                req.first_planned_at = now
+                first_scheduled += 1
+                first_wait_s += now - req.arrival
             if explain is not None:
-                explain["prefill_tokens"] += chunk
                 explain["admitted"].append({"rid": req.rid, "chunk": chunk,
                                             "prefix_tokens": n_cached,
                                             "requeued": req.preemptions})
@@ -685,9 +707,14 @@ class Scheduler:
 
         if explain is not None:
             explain["budget_left"] = budget
+            explain["decode_tokens"] = decode_tokens
+            explain["prefill_tokens"] = prefill_tokens
         self._explain = None
         return StepPlan(entries, admitted, preempted, drafted,
-                        explain=explain)
+                        explain=explain, decode_tokens=decode_tokens,
+                        prefill_tokens=prefill_tokens,
+                        first_scheduled=first_scheduled,
+                        first_wait_s=first_wait_s)
 
     def _prefill_cap(self, req: Request) -> int:
         """How many tokens of ``req.seq`` prefill may still feed: the

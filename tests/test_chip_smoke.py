@@ -80,11 +80,16 @@ def config_updates(monkeypatch):
     return calls
 
 
+METADATA_IN_KEY = ("jax_compilation_cache_include_metadata_in_key", True)
+
+
 def test_compile_cache_yields_to_the_environment(monkeypatch, tmp_path,
                                                  config_updates):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert chip.enable_compile_cache() == str(tmp_path)
-    assert config_updates == []
+    # the place is the environment's; the key takes the metadata in, so a
+    # profile names this commit's scopes and lines, not a cached program's
+    assert config_updates == [METADATA_IN_KEY]
 
 
 def test_compile_cache_default_is_one_fixed_path(monkeypatch,
@@ -97,7 +102,8 @@ def test_compile_cache_default_is_one_fixed_path(monkeypatch,
     fixed = os.path.join(REPO, ".jax_cache")
     assert chip.enable_compile_cache() == fixed
     assert chip.enable_compile_cache() == fixed
-    assert config_updates == [("jax_compilation_cache_dir", fixed)] * 2
+    assert config_updates == [METADATA_IN_KEY,
+                              ("jax_compilation_cache_dir", fixed)] * 2
 
 
 def test_kernel_gates_let_a_backend_error_out(monkeypatch):

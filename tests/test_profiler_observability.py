@@ -367,13 +367,8 @@ class TestRunLog:
 
     def test_mfu_null_without_peak(self, tmp_path):
         path = str(tmp_path / "rl.jsonl")
-        old = os.environ.pop("PADDLE_TPU_PEAK_FLOPS", None)
-        try:
-            with prof.RunLog(path, rank=0, world=1) as rl:
-                rec = rl.log_step(step=0, step_time_ms=5.0)
-        finally:
-            if old is not None:
-                os.environ["PADDLE_TPU_PEAK_FLOPS"] = old
+        with prof.RunLog(path, rank=0, world=1, flops_per_step=1e9) as rl:
+            rec = rl.log_step(step=0, step_time_ms=5.0)
         assert rec["mfu"] is None
 
     def test_directory_path_gets_rank_name(self, tmp_path):
