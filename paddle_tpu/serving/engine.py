@@ -9,8 +9,8 @@ Composes the pieces PR 1-5 left on the table into a serving tier:
     hash-chain prefix reuse across requests;
   * ``scheduler.Scheduler`` — admits new requests and evicts finished
     ones at every decode step under a token budget;
-  * ``serving.ragged`` — the pure-JAX ragged attention reference, with
-    the flag-gated Pallas kernel underneath for the TPU window.
+  * ``serving.ragged`` — the step's attention: the Pallas paged kernel
+    on a single TPU chip, the pure-JAX reference on the CPU and on a mesh.
 
 Sampling runs host-side (greedy, or temperature with a seeded generator
 per engine) so the device program stays sampling-agnostic and requests
@@ -278,7 +278,7 @@ def _engine_step_impl(dec, shard, w, tokens, slot_ids, positions, valid,
     pages = jnp.where(bad, p_total, page)
     offs = positions % bs
     attend = _ragged.make_attend(tables, slot_ids, positions, valid,
-                                 dec.n_heads // dec.n_kv)
+                                 dec.n_heads // dec.n_kv, shard=shard)
     logits, kp, vp = dec.step_ragged(w, tokens, positions, k_pools,
                                      v_pools, (pages, offs), attend,
                                      shard=shard)
@@ -797,7 +797,10 @@ class ServingEngine:
                             prefill_tokens=plan.prefill_tokens,
                             decode_tokens=plan.decode_tokens,
                             first_scheduled=plan.first_scheduled,
-                            first_wait_s=plan.first_wait_s):
+                            first_wait_s=plan.first_wait_s,
+                            pages_walked=self._pages_walked(plan),
+                            pages_tabled=self.config.token_budget
+                            * self.max_pages_per_seq):
                         sampled = self._run_plan(plan, armed)
                 except Exception as exc:  # noqa: BLE001 — containment seam
                     if self.resilience is None:
@@ -1193,10 +1196,14 @@ class ServingEngine:
             tokens, slots, positions, valid, sample_points = \
                 self._pack_plan(plan, armed)
         with RecordEvent("serve.launch"):
+            # the page tables go as a COPY: the CPU backend's asarray
+            # aliases a numpy array, and the next _pack_plan rewrites
+            # these rows while a step that sampled nothing (a prefill
+            # chunk) may still be running
             logits, self._kp, self._vp = self._step_call(
                 self._w, jnp.asarray(tokens), jnp.asarray(slots),
                 jnp.asarray(positions), jnp.asarray(valid),
-                jnp.asarray(self._tables), self._kp, self._vp)
+                jnp.array(self._tables), self._kp, self._vp)
         with RecordEvent("serve.sync"):
             res = self.resilience
             if res is not None and res.nan_guard and \
@@ -1211,6 +1218,15 @@ class ServingEngine:
                 if sample_points else None
         with RecordEvent("serve.emit"):
             return self._emit_sampled(plan, sample_points, all_tok, armed)
+
+    def _pages_walked(self, plan) -> int:
+        """Pages the step's attention has to read: each scheduled
+        sequence's live context (its rows and drafts included) in pages.
+        ``serve.run`` carries it beside ``pages_tabled``, the whole page
+        table once a packed row, which is what the reference gathers."""
+        bs = self.config.block_size
+        return sum(-(-(e.start + e.n + len(e.draft)) // bs)
+                   for e in plan.entries)
 
     def _pack_plan(self, plan, armed: bool):
         """The numpy fill of the step program's inputs (and of the page
@@ -1502,7 +1518,7 @@ class ServingEngine:
         with self._lock:
             s = self.pool.stats
             base = {
-                "version": 1,
+                "version": 2,
                 "steps": self.steps,
                 "tokens_generated": self.tokens_generated,
                 "queue_depth": self.sched.queue_depth(),
@@ -1522,6 +1538,8 @@ class ServingEngine:
                                "hit_tokens": s["prefix_hit_tokens"]},
                 },
                 "spec": self.spec_stats(),
+                "attention": _ragged.attention_path(
+                    self._shard, self._pool_shape, self._pool_dtype),
             }
             if self.mesh is not None:
                 base["mesh"] = {"mp": int(self.mesh.shape["mp"]),

@@ -5,9 +5,13 @@ Reference capability: Ragged Paged Attention (PAPERS.md, arxiv 2604.15464)
 page tables, which is exactly the attention shape a continuous batcher
 emits. This module holds the pure-JAX reference implementation (the
 numerics oracle, pinned against the dense ``generation._attend`` /
-``_attend_gqa`` paths on CPU by tests/test_serve_engine.py) plus the
-dispatch that routes decode-only steps through the flag-gated Pallas
-kernel (``kernels/ragged_pallas.py``) on TPU.
+``_attend_gqa`` paths on CPU by tests/test_serve_engine.py) and the
+dispatch between it and the Pallas kernel (``kernels/ragged_pallas.py``).
+No flag chooses: ``attention_path`` looks at the backend, at the engine's
+tensor-parallel annotator and at the pool's geometry. A single TPU chip
+runs the kernel, whose reads follow each scheduled sequence's live pages;
+the CPU and a mesh (which cannot partition a bare ``pallas_call``) run
+the reference, whose gather follows ``token_budget x max_model_len``.
 
 Layout contract (shared with ``incubate...block_multihead_attention`` and
 the serving engine):
@@ -78,25 +82,40 @@ def ragged_paged_attention(q, k_pool, v_pool, page_tables, slot_ids,
     return out.astype(q.dtype)
 
 
-def make_attend(page_tables, slot_ids, positions, valid, rep):
+def attention_path(shard, pool_shape, dtype) -> str:
+    """Which implementation serves the step's attention, from what the
+    code can observe: ``"paged_kernel"`` on a single TPU chip (``shard``
+    is None) whose pool Mosaic can tile, else ``"reference"``.
+    ``telemetry()["attention"]`` reports it."""
+    from ..kernels import on_tpu, ragged_pallas as _rp
+    if shard is None and (_rp._INTERPRET or (
+            on_tpu() and _rp.tiles(pool_shape, dtype))):
+        return "paged_kernel"
+    return "reference"
+
+
+def make_attend(page_tables, slot_ids, positions, valid, rep, shard=None):
     """Bind the ragged metadata into the ``attend(q, kp, vp)`` callable
-    ``generation.step_ragged`` expects, routing through the Pallas kernel
-    when it is flag-enabled (the kernel walks one query token per grid
-    cell, so prefill chunks are served but not blocked; ROADMAP S4).
-    Whatever implements it runs under the scope ``paged_attention``: the
-    device time of the gather over the page tables and of the attention
-    itself is found by that name."""
+    ``generation.step_ragged`` expects. ``attention_path`` selects what
+    implements it (``shard`` is the engine's tensor-parallel annotator,
+    None on a single chip); the kernel takes the rows of each page-table
+    slot as one query block, which is how ``_pack_plan`` packs them.
+    Either way it runs under the scope ``paged_attention``: the device
+    time of the step's attention is found by that name."""
     from ..kernels import ragged_pallas as _rp
+    meta = []
 
     @jax.named_scope("paged_attention")
     def attend(q, kp, vp):
-        if _rp.enabled():
-            return _rp.ragged_decode_attention(
-                q, kp, vp, page_tables, slot_ids, positions, valid, rep)
-        return ragged_paged_attention(q, kp, vp, page_tables, slot_ids,
-                                      positions, valid, rep)
+        if attention_path(shard, kp.shape, kp.dtype) == "reference":
+            return ragged_paged_attention(q, kp, vp, page_tables, slot_ids,
+                                          positions, valid, rep)
+        if not meta:                    # once a step, not once a layer
+            meta.extend(_rp.seq_meta(slot_ids, positions, valid,
+                                     page_tables.shape[0]))
+        return _rp.paged_attention(q, kp, vp, page_tables, *meta, rep=rep)
 
     return attend
 
 
-__all__ = ["ragged_paged_attention", "make_attend"]
+__all__ = ["ragged_paged_attention", "attention_path", "make_attend"]

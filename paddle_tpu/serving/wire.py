@@ -337,7 +337,7 @@ WIRE_SCHEMAS = {
     },
     "telemetry_line": {
         "family": "telemetry_line",
-        "version": 1,
+        "version": 2,                  # 2: "attention" (PR 27)
         "version_key": "version",
         "required": {
             "version": "int",
@@ -359,11 +359,12 @@ WIRE_SCHEMAS = {
             "handoff": "dict",
             "mem": "dict",
             "resilience": "dict",
+            "attention": "str",
         },
         "item_key": None,
         "item_required": {},
         "item_optional": {},
-        "key_hashes": {1: "f2b55577"},
+        "key_hashes": {1: "f2b55577", 2: "a5410fb5"},
         "byte_stable": False,
         "builders": ("serving/engine.py::telemetry",),
         "consumers": (),

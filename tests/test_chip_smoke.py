@@ -111,9 +111,9 @@ def test_kernel_gates_let_a_backend_error_out(monkeypatch):
     the XLA path or the interpreter."""
     import jax.numpy as jnp
     from paddle_tpu import kernels
-    from paddle_tpu.kernels import (flash_attention, fused_pallas,
-                                    gmm_pallas, ragged_pallas)
+    from paddle_tpu.kernels import flash_attention, fused_pallas, gmm_pallas
     from paddle_tpu.framework import flags
+    from paddle_tpu.serving import ragged
 
     def boom():
         raise RuntimeError("Unable to initialize backend 'tpu'")
@@ -127,10 +127,12 @@ def test_kernel_gates_let_a_backend_error_out(monkeypatch):
     with pytest.raises(RuntimeError, match="initialize backend"):
         gmm_pallas._interpret()
     monkeypatch.setitem(flags._FLAGS, "use_pallas_fused", True)
-    monkeypatch.setitem(flags._FLAGS, "use_ragged_pallas", True)
-    for mod in (fused_pallas, ragged_pallas):
-        with pytest.raises(RuntimeError, match="initialize backend"):
-            mod.enabled()
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        fused_pallas.enabled()
+    # no flag stands before the serving kernel: the backend alone is asked
+    assert "use_ragged_pallas" not in flags._FLAGS
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        ragged.attention_path(None, (256, 32, 16, 128), jnp.bfloat16)
 
 
 def test_require_tpu_names_what_it_found():
@@ -147,3 +149,50 @@ def test_set_device_refuses_what_it_cannot_select():
     with pytest.raises(ValueError, match="use a mesh"):
         paddle.device.set_device("cpu:3")
     paddle.device.synchronize()
+
+
+# -- the serving kernel, compiled for the chip that is described, not attached --
+@pytest.fixture(scope="module")
+def one_chip():
+    """One v5e chip as a sharding: the TPU's compiler is installed here and
+    compiles for a chip it is told about (on-chip-measurement guide, section
+    2). Only inside a fixture: the library is loaded by the worker that runs
+    this file, after collection."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("pages,kvh,rep,rows,slots,table", [
+    (256, 32, 1, 64, 16, 128),       # cgpt67-serve-decode
+    (1280, 8, 4, 128, 32, 64),       # mistral7b-serve-chat
+], ids=["decode", "chat"])
+def test_paged_attention_compiles_at_the_serving_cells_geometries(
+        one_chip, pages, kvh, rep, rows, slots, table):
+    """Mosaic accepts the kernel at both cells' pools, budgets and tables
+    (what interpret mode cannot show); ``tools/kernel_check.py`` runs the
+    same two against the reference on the chip."""
+    import jax.numpy as jnp
+    from paddle_tpu.kernels import ragged_pallas as rp
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    q = shape((rows, kvh * rep, 128), jnp.bfloat16)
+    pool = shape((pages, kvh, 16, 128), jnp.bfloat16)
+    assert rp.tiles(pool.shape, pool.dtype)
+    per_slot = shape((slots,), jnp.int32)
+    compiled = jax.jit(
+        lambda q, kp, vp, tables, starts, counts, ctx: rp.paged_attention(
+            q, kp, vp, tables, starts, counts, ctx, rep=rep)).lower(
+        q, pool, pool, shape((slots, table), jnp.int32),
+        per_slot, per_slot, per_slot).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention" in text
+    assert compiled.memory_analysis().output_size_in_bytes == rows * kvh * rep * 256

@@ -763,8 +763,7 @@ class TestLintPerfConfig:
         from paddle_tpu.analysis import load_flag_registry
         reg = load_flag_registry()
         for name in ("use_autotune", "use_pallas_fused",
-                     "use_ragged_pallas", "sp_overlap_linear",
-                     "check_nan_inf"):
+                     "sp_overlap_linear", "check_nan_inf"):
             assert name in reg
 
 

@@ -150,44 +150,124 @@ def test_ragged_attention_mixed_phase_chunk():
                                atol=2e-5, rtol=2e-5)
 
 
-def test_pallas_kernel_matches_reference(monkeypatch):
-    from paddle_tpu.kernels import ragged_pallas as rp
-    monkeypatch.setattr(rp, "_INTERPRET", True)
-    rng = np.random.default_rng(2)
-    t, kvh, d, p, bs, mp, s = 10, 2, 8, 12, 4, 5, 3
-    kp = jnp.asarray(rng.standard_normal((p, kvh, bs, d)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((p, kvh, bs, d)), jnp.float32)
+def _mixed_step(rng, rep, dtype, t, kvh=2, d=8, bs=4):
+    """One packed step as ``_pack_plan`` lays it out: slot 0 a decode row,
+    slot 1 a prefill chunk that starts mid-page, slot 2 a verify chunk (the
+    fed token and two drafts), slot 3 idle, slot 4 a chunk over a table with
+    a -1 page inside that shares its first page with slot 0; the rest of the
+    budget is padding rows."""
+    p, s, mp = 20, 5, 6
+    kp = jnp.asarray(rng.standard_normal((p, kvh, bs, d)), dtype)
+    vp = jnp.asarray(rng.standard_normal((p, kvh, bs, d)), dtype)
     tables = np.full((s, mp), -1, np.int32)
     tables[0, :3] = [2, 5, 7]
-    tables[1, :2] = [1, 9]
+    tables[1, :4] = [1, 9, 11, 12]
     tables[2, :5] = [0, 3, 4, 6, 8]
-    tables = jnp.asarray(tables)
-    slot = jnp.asarray(rng.integers(0, s, (t,)), jnp.int32)
-    cap = np.asarray([3, 2, 5])[np.asarray(slot)] * bs - 1
-    pos = jnp.asarray(rng.integers(0, cap + 1), jnp.int32)
-    valid = jnp.asarray(rng.random(t) > 0.2)
-    for rep in (1, 2):
-        q = jnp.asarray(rng.standard_normal((t, kvh * rep, d)), jnp.float32)
-        ref = ragged_paged_attention(q, kp, vp, tables, slot, pos, valid,
-                                     rep=rep)
-        ref = np.where(np.asarray(valid)[:, None, None],
-                       np.asarray(ref), 0.0)
-        got = rp.ragged_decode_attention(q, kp, vp, tables, slot, pos,
-                                         valid, rep=rep)
-        np.testing.assert_allclose(np.asarray(got), ref, atol=2e-5,
-                                   rtol=2e-5)
+    tables[4, :4] = [2, -1, 13, 14]
+    plan = [(0, 9, 1), (1, 6, 7), (2, 17, 3), (4, 9, 6)]   # slot, pos, rows
+    slot = np.zeros(t, np.int32)
+    pos = np.zeros(t, np.int32)
+    valid = np.zeros(t, bool)
+    i = 0
+    for sl, first, n in plan:
+        slot[i:i + n] = sl
+        pos[i:i + n] = np.arange(first, first + n)
+        valid[i:i + n] = True
+        i += n
+    q = jnp.asarray(rng.standard_normal((t, kvh * rep, d)), dtype)
+    return q, kp, vp, (jnp.asarray(tables), jnp.asarray(slot),
+                       jnp.asarray(pos), jnp.asarray(valid))
 
 
-def test_pallas_kernel_flag_gated(monkeypatch):
-    from paddle_tpu.framework import flags
+def _decode_step(rng, rep, dtype, t, kvh=2, d=8, bs=4):
+    """Decode only: every row its own slot at a random depth, one idle
+    slot between them, one padding row at the end."""
+    s, mp = t, 5
+    p = s * mp
+    kp = jnp.asarray(rng.standard_normal((p, kvh, bs, d)), dtype)
+    vp = jnp.asarray(rng.standard_normal((p, kvh, bs, d)), dtype)
+    tables = rng.permutation(p).reshape(s, mp).astype(np.int32)
+    slot = np.asarray([i for i in range(s) if i != 2] + [0], np.int32)
+    pos = rng.integers(0, mp * bs, t).astype(np.int32)
+    valid = np.ones(t, bool)
+    valid[-1] = False
+    q = jnp.asarray(rng.standard_normal((t, kvh * rep, d)), dtype)
+    return q, kp, vp, (jnp.asarray(tables), jnp.asarray(slot),
+                       jnp.asarray(pos), jnp.asarray(valid))
+
+
+@pytest.mark.parametrize("step,rep,dtype,t,tol", [
+    (_mixed_step, 1, jnp.float32, 24, 2e-5),      # MHA
+    (_mixed_step, 4, jnp.float32, 24, 2e-5),      # GQA, Mistral's ratio
+    (_mixed_step, 2, jnp.float32, 17, 2e-5),      # the last tile clamps
+    (_mixed_step, 1, jnp.bfloat16, 40, 2e-2),
+    (_mixed_step, 4, jnp.bfloat16, 40, 2e-2),
+    (_decode_step, 1, jnp.float32, 10, 2e-5),
+    (_decode_step, 2, jnp.float32, 10, 2e-5),
+], ids=["mixed-mha", "mixed-gqa4", "mixed-gqa2-clamped", "mixed-mha-bf16",
+        "mixed-gqa4-bf16", "decode-mha", "decode-gqa2"])
+def test_paged_kernel_matches_reference(monkeypatch, step, rep, dtype, t,
+                                        tol):
     from paddle_tpu.kernels import ragged_pallas as rp
-    assert not rp.enabled()          # OFF by default (pending hardware)
     monkeypatch.setattr(rp, "_INTERPRET", True)
-    flags.set_flags({"use_ragged_pallas": True})
-    try:
-        assert rp.enabled()
-    finally:
-        flags.set_flags({"use_ragged_pallas": False})
+    q, kp, vp, (tables, slot, pos, valid) = step(
+        np.random.default_rng(2), rep, dtype, t)
+    want = ragged_paged_attention(q, kp, vp, tables, slot, pos, valid,
+                                  rep=rep)
+    meta = rp.seq_meta(slot, pos, valid, tables.shape[0])
+    got = rp.paged_attention(q, kp, vp, tables, *meta, rep=rep)
+    assert got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+    # padding rows and idle slots read zero
+    assert not np.asarray(got, np.float32)[~np.asarray(valid)].any()
+
+
+def test_seq_meta_is_the_plan():
+    from paddle_tpu.kernels import ragged_pallas as rp
+    _, _, _, (tables, slot, pos, valid) = _mixed_step(
+        np.random.default_rng(0), 1, jnp.float32, 24)
+    starts, counts, ctx = rp.seq_meta(slot, pos, valid, tables.shape[0])
+    assert counts.tolist() == [1, 7, 3, 0, 6]
+    assert [s for s, n in zip(starts.tolist(), counts.tolist()) if n] \
+        == [0, 1, 8, 11]
+    assert ctx.tolist() == [10, 13, 20, 0, 15]
+
+
+def test_attention_path_is_chosen_by_backend_mesh_and_geometry(monkeypatch):
+    from paddle_tpu import kernels
+    from paddle_tpu.kernels import ragged_pallas as rp
+    from paddle_tpu.serving import ragged
+    pool = ((256, 32, 16, 128), jnp.bfloat16)
+    assert not hasattr(rp, "enabled")            # no flag, no gate
+    assert ragged.attention_path(None, *pool) == "reference"      # a CPU
+    monkeypatch.setattr(kernels, "on_tpu", lambda: True)
+    assert ragged.attention_path(None, *pool) == "paged_kernel"
+    assert ragged.attention_path(object(), *pool) == "reference"  # a mesh
+    # pages Mosaic cannot tile: half a bfloat16 sublane tile, a 64-wide head
+    assert ragged.attention_path(
+        None, (256, 32, 8, 128), jnp.bfloat16) == "reference"
+    assert ragged.attention_path(
+        None, (256, 32, 8, 128), jnp.float32) == "paged_kernel"
+    assert ragged.attention_path(
+        None, (256, 32, 16, 64), jnp.bfloat16) == "reference"
+
+
+@pytest.mark.parametrize("kv_heads", [4, 2])     # MHA and GQA
+def test_engine_on_the_paged_kernel_matches_generate(monkeypatch, kv_heads):
+    """The whole engine on the kernel (interpreted): chunked prefill,
+    decode and eviction give generate()'s tokens, as the reference path
+    does in test_engine_matches_generate."""
+    from paddle_tpu.kernels import ragged_pallas as rp
+    monkeypatch.setattr(rp, "_INTERPRET", True)
+    model = _model(kv_heads=kv_heads)
+    prompts = _prompts(3)
+    want = _oracle(model, prompts, max_new=4)
+    eng = ServingEngine(model, EngineConfig(max_seqs=2, token_budget=8,
+                                            block_size=8))
+    assert eng.telemetry()["attention"] == "paged_kernel"
+    assert eng.generate_batch(prompts, max_new_tokens=4) == want
 
 
 # -- engine vs generate() parity ----------------------------------------------

@@ -1,14 +1,17 @@
 #!/usr/bin/env python
 """Does each Pallas kernel compile on this chip, and does it match its oracle?
 
-One process on a TPU, no arguments. Every Pallas kernel in
+One process on a TPU; arguments, if any, are prefixes of the kernels' names
+to keep (``paged_attention``). Every Pallas kernel in
 ``paddle_tpu/kernels`` that ``chip_smoke.py`` does not already gate (it
 checks the default-path flash forward and backward) is run once, compiled
 (never interpreted), at one production shape, against the jnp
-implementation the tests use as its oracle. They sit behind flags that are
-off (``FLAGS_use_pallas_fused``, ``FLAGS_use_ragged_pallas``) or behind an
-explicit MoE/packing option, and this survey is the record of which of
-them the installed Mosaic accepts (ROADMAP S7, D2). It turns no flag on.
+implementation the tests use as its oracle. They sit behind a flag that is
+off (``FLAGS_use_pallas_fused``) or behind an explicit MoE/packing option,
+and this survey is the record of which of them the installed Mosaic accepts
+(ROADMAP S7, D2). It turns no flag on. The serving step's paged attention
+kernel is on the engine's path on one chip; it is here at the two serving
+cells' geometries against the gather-based reference.
 
 Prints one JSON line per kernel — ``"verdict": "compiles and matches"``
 or the compiler's own words — and a final summary line; the same goes to
@@ -18,6 +21,7 @@ every kernel matched.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -158,33 +162,61 @@ def case_gmm():
     return (t, dm, dff, e), dict(zip(("out", "dx", "dw"), zip(got, want)))
 
 
-def case_ragged_decode():
-    """The smoke engine's geometry: 64 packed decode tokens over 4
-    sequence slots, 16 KV heads of 128, pages of 16 slots."""
-    from paddle_tpu.kernels.ragged_pallas import ragged_decode_attention
-    from paddle_tpu.serving.ragged import ragged_paged_attention
-    t, h, d, pages, bs, slots, mp = 64, 16, 128, 256, 16, 4, 64
-    rng = np.random.default_rng(0)
-    q = rand(0, (t, h, d))
-    kp, vp = rand(1, (pages, h, bs, d)), rand(2, (pages, h, bs, d))
-    lens = [1000, 37, 512, 260]
-    tables = np.full((slots, mp), -1, np.int32)
-    perm = rng.permutation(pages)
-    at = 0
-    for s_, n in enumerate(lens):
-        need = -(-n // bs)
+def paged_step(pages, kvh, rep, rows, slots, table, plan, seed=0):
+    """One packed serving step at a cell's geometry, as ``_pack_plan``
+    lays it out: ``plan`` is (rows, context) per scheduled sequence, one
+    page-table slot each from slot 1 on (slot 0 stays idle), live pages
+    drawn from a shuffled pool; the rest of the budget is padding rows.
+    Returns (q, k_pool, v_pool, (tables, slot_ids, positions, valid))."""
+    bs, d = 16, 128
+    rng = np.random.default_rng(seed)
+    tables = np.full((slots, table), -1, np.int32)
+    slot = np.zeros(rows, np.int32)
+    pos = np.zeros(rows, np.int32)
+    valid = np.zeros(rows, bool)
+    perm, at, row = rng.permutation(pages), 0, 0
+    for s_, (n, ctx) in enumerate(plan, start=1):
+        need = -(-ctx // bs)
         tables[s_, :need] = perm[at:at + need]
         at += need
-    slot = rng.integers(0, slots, t).astype(np.int32)
-    pos = np.asarray([rng.integers(0, lens[s_]) for s_ in slot], np.int32)
-    valid = rng.random(t) > 0.1
-    args = (jnp.asarray(tables), jnp.asarray(slot), jnp.asarray(pos),
-            jnp.asarray(valid))
-    got = jax.jit(lambda q, kp, vp: ragged_decode_attention(
-        q, kp, vp, *args, rep=1))(q, kp, vp)
-    want = jax.jit(lambda q, kp, vp: ragged_paged_attention(
-        q, kp, vp, *args, rep=1))(q, kp, vp)
-    return (t, h, d, pages, bs), {"out": (got, want)}
+        slot[row:row + n] = s_
+        pos[row:row + n] = np.arange(ctx - n, ctx)
+        valid[row:row + n] = True
+        row += n
+    q = rand(seed, (rows, kvh * rep, d))
+    kp, vp = (rand(seed + k, (pages, kvh, bs, d)) for k in (1, 2))
+    return q, kp, vp, tuple(jnp.asarray(a) for a in
+                            (tables, slot, pos, valid))
+
+
+# (pool pages, KV heads, query heads a KV head, token budget, slots, table
+# width, plan) of the two serving cells, `bench/traffic/`'s engines
+PAGED_GEOMETRIES = {
+    # cgpt67-serve-decode: MHA; 13 decodes, a 40-row prompt chunk, a
+    # verify chunk of 1 + 3 drafts
+    "decode": (256, 32, 1, 64, 16, 128,
+               [(1, c) for c in range(70, 250, 14)] + [(40, 40), (4, 130)]),
+    # mistral7b-serve-chat: GQA 32/8; 20 decodes, a chunk that continues a
+    # 700-token prompt from mid-page, a short first chunk
+    "chat": (1280, 8, 4, 128, 32, 64,
+             [(1, c) for c in range(60, 1000, 47)] + [(90, 700), (18, 18)]),
+}
+
+
+def case_paged_attention(cell):
+    """The step's attention kernel against the gather-based reference."""
+    from paddle_tpu.kernels import ragged_pallas as rp
+    from paddle_tpu.serving.ragged import ragged_paged_attention
+    pages, kvh, rep, rows, slots, table, plan = PAGED_GEOMETRIES[cell]
+    q, kp, vp, args = paged_step(pages, kvh, rep, rows, slots, table, plan)
+    got = jax.jit(lambda q, kp, vp, tables, slot, pos, valid:
+                  rp.paged_attention(
+                      q, kp, vp, tables,
+                      *rp.seq_meta(slot, pos, valid, tables.shape[0]),
+                      rep=rep))(q, kp, vp, *args)
+    want = jax.jit(functools.partial(ragged_paged_attention, rep=rep))(
+        q, kp, vp, *args)
+    return (rows, kvh * rep, 128, pages, 16), {"out": (got, want)}
 
 
 CASES = (
@@ -194,7 +226,10 @@ CASES = (
      REL_L2),
     ("fused_adamw", "FLAGS_use_pallas_fused", case_fused_adamw, REL_L2_F32),
     ("gmm", "MoE dropless=True", case_gmm, REL_L2),
-    ("ragged_decode", "FLAGS_use_ragged_pallas", case_ragged_decode, REL_L2),
+    ("paged_attention/decode", "ServingEngine on one chip",
+     functools.partial(case_paged_attention, "decode"), REL_L2),
+    ("paged_attention/chat", "ServingEngine on one chip",
+     functools.partial(case_paged_attention, "chat"), REL_L2),
 )
 
 
@@ -203,7 +238,10 @@ def main() -> int:
     chip.enable_compile_cache()
     print(json.dumps({"device": device}), flush=True)
     rows = []
+    only = tuple(sys.argv[1:])
     for name, reached_by, fn, tol in CASES:
+        if only and not name.startswith(only):
+            continue
         row = {"kernel": name, "reached_by": reached_by}
         t0 = time.perf_counter()
         try:
