@@ -1,7 +1,8 @@
 """Architecture adapter: Llama-shaped decoders (Mistral-7B).
 
-See ``gpt2.py``: the mapping of a published ``config.json`` onto the
-system's ``LlamaForCausalLM``, the leaves, and the cost of the work.
+The mapping of a published ``config.json`` onto the system's
+``LlamaForCausalLM``, the leaves, the walk and the cost of the work;
+``gpt2.py``'s docstring has the interface.
 """
 from __future__ import annotations
 
@@ -29,7 +30,8 @@ def top_specs(cfg):
             ("lm_head.weight", (h, cfg["vocab_size"]), ("normal", std))]
 
 
-def layer_specs(cfg):
+def layer_specs(cfg, i):
+    """Every layer alike."""
     h, kvw, inter = _dims(cfg)
     n, o = ("normal", cfg["initializer_range"]), ("near_one", 0.05)
     return [("self_attn.q_proj.weight", (h, h), n),
@@ -41,6 +43,11 @@ def layer_specs(cfg):
             ("mlp.down_proj.weight", (inter, h), n),
             ("input_layernorm.weight", (h,), o),
             ("post_attention_layernorm.weight", (h,), o)]
+
+
+def walk(cfg):
+    """Every block once, in order."""
+    return [("block", i) for i in range(cfg["num_hidden_layers"])]
 
 
 def build(cfg):

@@ -142,30 +142,37 @@ def _bucket(n, step):
 
 def reference_logits(arch, cfg, seed, sequences, q=None):
     """The reference's logits for every served token: one full causal
-    forward over each ``(prompt, served)``, layer by layer with one block's
-    weights alive at a time. Returns one [len(served), vocab] float32 array
-    per sequence (row i predicts served[i])."""
+    forward over each ``(prompt, served)``, ``embed``, then the stops of the
+    adapter's ``walk`` in order, then ``head``. A stop ``(name, i)`` calls the
+    reference module's function ``name`` on ``(top, layer i's leaves, x)``;
+    the layer's leaves are made from the seed again at every visit, so one
+    layer's weights are alive at a time however often the walk comes back to
+    it. Returns one [len(served), vocab] float32 array per sequence (row i
+    predicts served[i])."""
     ref = importlib.import_module(arch.REFERENCE)
     items = tuple(sorted((k, v) for k, v in cfg.items()
                          if isinstance(v, (int, float, str, bool))))
+    walk = list(arch.walk(cfg))
+
+    def f32(tree):
+        return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
 
     @jax.jit
     def embed(top, ids):
-        return ref.embed(jax.tree_util.tree_map(
-            lambda a: a.astype(jnp.float32), top), ids, dict(items))
+        return ref.embed(f32(top), ids, dict(items))
 
-    @jax.jit
-    def block(lw, x):
-        return ref.block(jax.tree_util.tree_map(
-            lambda a: a.astype(jnp.float32), lw), x, dict(items), q)
+    def stop(fn):
+        return jax.jit(lambda top, lw, x: fn(f32(top), f32(lw), x,
+                                             dict(items), q))
+
+    steps = {name: stop(getattr(ref, name)) for name in {n for n, _ in walk}}
 
     @jax.jit
     def head(top, x):
-        return ref.head(jax.tree_util.tree_map(
-            lambda a: a.astype(jnp.float32), top), x, dict(items), q)
+        return ref.head(f32(top), x, dict(items), q)
 
     top = W.top_weights(arch, cfg, seed)
-    # one padded length for all of them: one program per block, few shapes
+    # one padded length for all of them: one program per step, few shapes
     width = _bucket(max(len(p) + len(s) - 1 for p, s in sequences), 256)
     rows_width = _bucket(max(len(s) for _, s in sequences), 64)
     xs = []
@@ -174,9 +181,9 @@ def reference_logits(arch, cfg, seed, sequences, q=None):
         ids = np.zeros(width, np.int32)
         ids[:len(fed)] = fed
         xs.append(embed(top, jnp.asarray(ids)))
-    for i in range(arch.n_layers(cfg)):
-        lw = W.layer_weights(arch, cfg, seed, i)
-        xs = [block(lw, x) for x in xs]
+    for name, i in walk:
+        lw = None if i is None else W.layer_weights(arch, cfg, seed, i)
+        xs = [steps[name](top, lw, x) for x in xs]
     out = []
     for (prompt, served), x in zip(sequences, xs):
         rows = x[len(prompt) - 1: len(prompt) - 1 + len(served)]

@@ -5,8 +5,14 @@ mix (``bench/traffic/<traffic>.json``); a per-layer metric is
 ``bench/metrics/<name>.json`` naming a reader ``bench/readers/<reader>.py``;
 a traffic file names the ``kind`` whose driver is ``bench/kinds/<kind>.py``
 and a configuration the ``architecture`` whose adapter is
-``bench/archs/<architecture>.py``. Nothing here lists names: a later PR adds
-files and one ``workloads`` entry.
+``bench/archs/<architecture>.py``, which names its plain reference
+(``REFERENCE``, a module under ``bench/reference/``), each layer's leaves and
+the walk a forward pass makes over the layers (``bench/archs/gpt2.py`` has
+the adapter's interface). Nothing here, in the kinds or in the comparison
+lists names or assumes that layers are alike or visited once: a later PR adds
+files and one ``workloads`` entry, for a new architecture too (PERF.md
+section 4 lists the files). What is not files yet: the training reference
+follows a walk that visits every layer once (``reference/train_steps.py``).
 """
 from __future__ import annotations
 

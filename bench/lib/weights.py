@@ -1,11 +1,12 @@
 """Seeded weights, made on the device in the type they are served in.
 
 An architecture lists its leaves as ``(name, shape, init)``: the leaves outside
-the blocks once (``top_specs``) and one block's (``layer_specs``). One jitted
-program per list makes them from the seed; the layer index is a traced
-argument, so every layer shares one program. The plain references call the
-same two programs layer by layer, so they compute on the very values the
-system was given and hold one layer at a time.
+the layers once (``top_specs(cfg)``) and each layer's (``layer_specs(cfg, i)``).
+One jitted program per DISTINCT list makes them from the seed; the layer index
+is a traced argument, so layers whose lists are equal share one program,
+however many they are, and a model whose layers are of two kinds compiles two.
+The plain references call the same programs layer by layer, so they compute on
+the very values the system was given and hold one layer at a time.
 
 ``init`` is ``("normal", std)`` or ``("near_one", std)`` (1 + std * normal,
 for norm scales, so that the scale path is exercised).
@@ -56,8 +57,8 @@ def top_weights(arch, cfg, seed, dtype=jnp.bfloat16):
 
 
 def layer_weights(arch, cfg, seed, layer, dtype=jnp.bfloat16):
-    """One block's leaves, by their names inside the block."""
-    return _make(_hashable(arch.layer_specs(cfg)), seed_key(seed),
+    """Layer ``layer``'s leaves, by their names inside the layer."""
+    return _make(_hashable(arch.layer_specs(cfg, layer)), seed_key(seed),
                  jnp.int32(layer), jnp.dtype(dtype))
 
 

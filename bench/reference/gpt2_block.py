@@ -28,8 +28,9 @@ def embed(top, ids, cfg):
         + top["transformer.wpe.weight"].astype(jnp.float32)[pos]
 
 
-def block(lw, x, cfg, q=None):
-    """x: [S, n_embd] of one sequence."""
+def block(top, lw, x, cfg, q=None):
+    """The walk's one step. x: [S, n_embd] of one sequence; ``top`` is not
+    read."""
     s, h = x.shape
     heads = cfg["n_head"]
     eps = cfg["layer_norm_epsilon"]

@@ -36,8 +36,9 @@ def embed(top, ids, cfg):
     return top["model.embed_tokens.weight"].astype(jnp.float32)[ids]
 
 
-def block(lw, x, cfg, q=None):
-    """x: [S, hidden] of one sequence."""
+def block(top, lw, x, cfg, q=None):
+    """The walk's one step. x: [S, hidden] of one sequence; ``top`` is not
+    read."""
     s, h = x.shape
     heads, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     d = h // heads

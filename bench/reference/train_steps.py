@@ -1,9 +1,18 @@
 """Plain reference of the first training steps: loss, gradients and AdamW.
 
 float32 ``jax.numpy`` at the highest matmul precision over a block file's
-``embed`` / ``block`` / ``head``: one sequence at a time, layer by layer, the
-backward through ``jax.vjp`` of the same functions. It imports nothing of the
-system under test and makes its own weights from the seed.
+``embed`` / ``block`` / ``head``: one sequence at a time, layer by layer in
+the order of the adapter's walk, the backward through ``jax.vjp`` of the same
+functions. It imports nothing of the system under test and makes its own
+weights from the seed.
+
+It takes ONE gradient a layer from ONE visit, and the gradient of the leaves
+outside the layers through ``embed`` and ``head`` alone. So it follows a walk
+of ``"block"`` stops that visits every layer once and refuses any other
+(``one_visit_order``): a layer's gradient summed over its visits, and a stop
+between layers, come with the model that needs them. A block is handed no
+leaves outside it (``top`` is ``None`` here), so one that reads them fails at
+once and loses no term in silence.
 
 What it holds to is what the training configurations state: parameters are
 STORED in bfloat16 (the update is computed in float32 from the stored value
@@ -34,7 +43,7 @@ def _f32(tree):
 def _fwd_layer(ref, cfg_items, lw, xs, q):
     cfg = dict(cfg_items)
     lw = _f32(lw)
-    return jax.lax.map(lambda x: ref.block(lw, x, cfg, q), xs)
+    return jax.lax.map(lambda x: ref.block(None, lw, x, cfg, q), xs)
 
 
 @partial(jax.jit, static_argnums=(0, 1, 5))
@@ -45,7 +54,7 @@ def _bwd_layer(ref, cfg_items, lw, xs, dys, q):
 
     def body(acc, xy):
         x, dy = xy
-        _, vjp = jax.vjp(lambda w, a: ref.block(w, a, cfg, q), lw, x)
+        _, vjp = jax.vjp(lambda w, a: ref.block(None, w, a, cfg, q), lw, x)
         dw, dx = vjp(dy)
         return jax.tree_util.tree_map(jnp.add, acc, dw), dx
 
@@ -103,6 +112,26 @@ def _adamw(p, g, m, v, scale, step, lr, wd, b1, b2, eps):
             {k: o[2] for k, o in out.items()})
 
 
+def one_visit_order(arch, cfg):
+    """The layers in the order the walk visits them, where the walk is one
+    this reference can follow; ValueError, with the reason, where it is not."""
+    stops = list(arch.walk(cfg))
+    other = sorted({name for name, i in stops if name != "block" or i is None})
+    if other:
+        raise ValueError(
+            f"the training reference follows a walk of blocks only, and this "
+            f"one also stops at {other}: the backward through a stop between "
+            f"layers is not written")
+    order = [i for _, i in stops]
+    if sorted(order) != list(range(arch.n_layers(cfg))):
+        raise ValueError(
+            f"the training reference takes one gradient a layer from one "
+            f"visit, and this walk visits the {arch.n_layers(cfg)} layers in "
+            f"{len(order)} stops ({order[:8]}...): summing a layer's gradient "
+            f"over its visits is not written")
+    return order
+
+
 def follow(arch, cfg, seed, batches, hyper, q=None, fault=None,
            dtype=jnp.bfloat16):
     """Follow ``len(batches)`` steps from the seed's weights.
@@ -116,6 +145,7 @@ def follow(arch, cfg, seed, batches, hyper, q=None, fault=None,
     items = tuple(sorted((k, v) for k, v in cfg.items()
                          if isinstance(v, (int, float, str, bool))))
     n_layers = arch.n_layers(cfg)
+    order = one_visit_order(arch, cfg)
     fused = W.fused_of(arch)
     top = dict(W.top_weights(arch, cfg, seed, dtype))
     layers = [dict(W.layer_weights(arch, cfg, seed, i, dtype))
@@ -131,12 +161,12 @@ def follow(arch, cfg, seed, batches, hyper, q=None, fault=None,
         if fault == "half_batch":
             ids = ids[: ids.shape[0] // 2]
         xs = [_embed(ref, items, top, ids)]
-        for lw in layers:
-            xs.append(_fwd_layer(ref, items, lw, xs[-1], q))
+        for i in order:
+            xs.append(_fwd_layer(ref, items, layers[i], xs[-1], q))
         loss, dtop, dx = _head_loss(ref, items, top, xs.pop(), ids, q)
         losses.append(float(loss))
         grads = [None] * (n_layers + 1)
-        for i in reversed(range(n_layers)):
+        for i in reversed(order):
             grads[i + 1], dx = _bwd_layer(ref, items, layers[i], xs.pop(), dx, q)
         demb = _embed_grad(ref, items, top, ids, dx)
         grads[0] = jax.tree_util.tree_map(jnp.add, dtop, demb)
