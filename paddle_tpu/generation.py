@@ -192,6 +192,49 @@ def _stack_pools(new_k, new_v):
     return jnp.stack(new_k), jnp.stack(new_v)
 
 
+@jax.named_scope("kv_write")
+def _join_entries(pools):
+    """Stacked pools [E, P, kvh, bs, D] as one run of pages [E * P, kvh, bs,
+    D], entry ``e`` at pages ``e * P ..`` (no element moves): the form a
+    looping step carries, writes in place and attends from."""
+    return pools.reshape((-1,) + pools.shape[2:])
+
+
+@jax.named_scope("kv_write")
+def _page_plan(scatter, bs):
+    """For a step that writes whole pages: which of the step's rows lands in
+    each slot of each row's page. scatter = (pages [T], offs [T]). Returns
+    (hit [T, bs] bool, src [T, bs] int32): slot ``o`` of row ``r``'s page
+    takes row ``src[r, o]``'s K/V where ``hit[r, o]``. Rows of one page get
+    equal plans, so writing the page once a row writes one content."""
+    pages, offs = scatter
+    lands = (pages[:, None, None] == pages[None, :, None]) \
+        & (offs[None, :, None] == jnp.arange(bs)[None, None, :])   # [T, T', bs]
+    return lands.any(1), jnp.argmax(lands, axis=1).astype(jnp.int32)
+
+
+@jax.named_scope("kv_write")
+def _kv_write_pages(kf, vf, k, v, scatter):
+    """``_kv_write`` for joined pools [N, kvh, bs, D] in a loop's carry:
+    each row's page is read, this step's rows are put into its slots and
+    the page is written back whole, 64 KB a row and nothing else of the
+    pools moved. A row write (``_kv_write``) makes the compiler keep the
+    carry in the layout its scatter likes, slots before heads, and copy the
+    whole of it into the attention kernel's layout at every call; a page is
+    the unit both agree on. scatter = (pages [T] in the joined pools, ``N``
+    = a dropped row; ``_page_plan``'s hit and src)."""
+    pages, hit, src = scatter
+    at = jnp.minimum(pages, kf.shape[0] - 1)
+
+    def write(pool, new):
+        rows = new[:, 0][src].transpose(0, 2, 1, 3)      # [T, kvh, bs, D]
+        page = jnp.where(hit[:, None, :, None], rows.astype(pool.dtype),
+                         pool[at])
+        return pool.at[pages].set(page, mode="drop")
+
+    return write(kf, k), write(vf, v)
+
+
 class _LlamaDecoder:
     """Pure functions over a LlamaForCausalLM state dict.
 
@@ -208,7 +251,11 @@ class _LlamaDecoder:
         self.n_kv = cfg.num_key_value_heads or self.n_heads
         self.hd = cfg.hidden_size // self.n_heads
         self.eps = cfg.rms_norm_eps
+        # two numbers: layers that hold weights, and K/V cache entries a
+        # token keeps (what the caches' and the pools' first axis counts).
+        # Equal where every layer runs once a token.
         self.n_layers = cfg.num_hidden_layers
+        self.cache_entries = self.n_layers
         self.tied = model.lm_head is None
         self.embed_key = "model.embed_tokens.weight"
 
@@ -263,12 +310,20 @@ class _LlamaDecoder:
             .reshape(b, s, self.n_kv, self.hd)
         return q, k, v
 
+    def _branch_out(self, w, i, norm, x):
+        """What a branch's output goes through before it joins the
+        residual; ``norm`` names the norm that opened the branch. Nothing
+        here."""
+        return x
+
     def _post_attn(self, w, i, h, att):
         """Residual + output projection + MLP, shared by both layer paths;
         att: [B, S, H*D]."""
         pre = f"model.layers.{i}."
         with jax.named_scope("attn_proj"):
-            h = h + _mm(att, w, pre + "self_attn.o_proj.weight")
+            h = h + self._branch_out(
+                w, i, "input_layernorm",
+                _mm(att, w, pre + "self_attn.o_proj.weight"))
         with jax.named_scope("mlp"):
             x2 = _rms(h, self._lw(w, i, "post_attention_layernorm.weight"),
                       self.eps)
@@ -276,7 +331,9 @@ class _LlamaDecoder:
             up = _mm(x2, w, pre + "mlp.up_proj.weight")
             swi = (jax.nn.silu(gate.astype(jnp.float32))
                    .astype(up.dtype) * up)
-            return h + _mm(swi, w, pre + "mlp.down_proj.weight")
+            return h + self._branch_out(
+                w, i, "post_attention_layernorm",
+                _mm(swi, w, pre + "mlp.down_proj.weight"))
 
     def _layer(self, w, i, h, cos, sin, kc, vc, write_pos, score_mask):
         """One decoder layer with cache append; h: [B, S, H*D]."""
@@ -304,7 +361,7 @@ class _LlamaDecoder:
         return self._post_attn(w, i, h, att), kc, vc
 
     def _layer_ragged(self, w, i, h, cos, sin, kp, vp, scatter, attend,
-                      shard=None):
+                      shard=None, write=_kv_write):
         """One layer over a PACKED ragged batch (mixed prefill chunks and
         decode tokens from different sequences as a [T, 1, ...] batch).
         kp/vp: [P, kvh, bs, D] paged pools; scatter: (pages [T], offs [T])
@@ -314,7 +371,8 @@ class _LlamaDecoder:
         serving engine's tensor-parallel annotator (None = single chip) —
         it pins q/k/v to the per-head layout right after the projection
         and the attention output right before the row-parallel o_proj,
-        the same two seams the training side shards."""
+        the same two seams the training side shards; write:
+        callable(kp, vp, k, v, scatter) -> (kp, vp) that puts the rows in."""
         t, s, _ = h.shape
         with jax.named_scope("attn_proj"):
             x = _rms(h, self._lw(w, i, "input_layernorm.weight"), self.eps)
@@ -323,24 +381,31 @@ class _LlamaDecoder:
             k = _rope_rows(k, cos, sin)
             if shard is not None:
                 q, k, v = shard.qkv(q, k, v)
-        kp, vp = _kv_write(kp, vp, k, v, scatter)
+        kp, vp = write(kp, vp, k, v, scatter)
         att = attend(q[:, 0], kp, vp).reshape(t, 1, -1)
         if shard is not None:
             att = shard.att(att)
         return self._post_attn(w, i, h, att), kp, vp
 
+    @jax.named_scope("embed")
+    def _embed_ragged(self, w, tokens, positions):
+        """The packed rows' embeddings [T, 1, H*D] and rope rows [T, 1,
+        hd/2] at their positions."""
+        return (w[self.embed_key][tokens][:, None],
+                w["__rope_cos"][positions][:, None],
+                w["__rope_sin"][positions][:, None])
+
     def step_ragged(self, w, tokens, positions, k_pools, v_pools, scatter,
                     attend, shard=None):
         """Ragged-batch twin of step(): tokens/positions: [T] packed
         mixed-phase batch (each entry one token of some sequence at its
-        absolute position); k_pools/v_pools: [L, P, kvh, bs, D] shared
-        block pools; scatter/attend/shard as in _layer_ragged. Returns
-        (logits [T, V], k_pools', v_pools')."""
-        with jax.named_scope("embed"):
-            emb = w[self.embed_key]
-            h = emb[tokens][:, None]                     # [T, 1, H*D]
-            cos = w["__rope_cos"][positions][:, None]    # [T, 1, hd/2]
-            sin = w["__rope_sin"][positions][:, None]
+        absolute position); k_pools/v_pools: [E, P, kvh, bs, D] shared
+        block pools, one per cache entry (here one a layer);
+        scatter/attend/shard as in _layer_ragged. Returns (logits [T, V],
+        exits, k_pools', v_pools'): ``exits`` is the pass each row's
+        logits were taken after, [T] int32, where the decoder runs its
+        layers several times a token, and None here."""
+        h, cos, sin = self._embed_ragged(w, tokens, positions)
         new_k, new_v = [], []
         for i in range(self.n_layers):
             h, kp, vp = self._layer_ragged(
@@ -348,7 +413,7 @@ class _LlamaDecoder:
                 scatter, attend, shard=shard)
             new_k.append(kp)
             new_v.append(vp)
-        return self._logits(w, h)[:, 0], *_stack_pools(new_k, new_v)
+        return self._logits(w, h)[:, 0], None, *_stack_pools(new_k, new_v)
 
     _TP_COL = ("self_attn.q_proj.weight", "self_attn.k_proj.weight",
                "self_attn.v_proj.weight", "mlp.gate_proj.weight",
@@ -379,7 +444,8 @@ class _LlamaDecoder:
 
     def step(self, w, tokens, positions, kcs, vcs, write_pos, score_mask):
         """tokens: [B, S] int; positions: [B, S] int (rope positions);
-        kcs/vcs: [L, B, M, kvh, hd]; score_mask: [B, 1, S, M].
+        kcs/vcs: [E, B, M, kvh, hd], one cache per cache entry (here one a
+        layer); score_mask: [B, 1, S, M].
         Returns (logits [B, S, V], kcs', vcs')."""
         emb = w[self.embed_key]
         h = emb[tokens]
@@ -393,6 +459,115 @@ class _LlamaDecoder:
             new_v.append(vc)
         return self._logits(w, h), jnp.stack(new_k), jnp.stack(new_v)
 
+
+class _OuroDecoder(_LlamaDecoder):
+    """Pure functions over an OuroForCausalLM state dict: the Llama block
+    with a norm after each branch as well as before it, the SAME layers run
+    ``n_passes`` times a token (the model's one norm after every pass), and
+    a gate that picks which pass's state feeds the head.
+
+    Pass ``t`` of layer ``l`` attends to the pass-``t``, layer-``l`` keys of
+    earlier tokens, so a token keeps ``n_passes * n_layers`` cache entries
+    over ``n_layers`` layers of weights; entry ``(t, l)`` is ``t * n_layers
+    + l``. Both step programs loop over the passes (one traced body of
+    ``n_layers`` layers, whatever ``n_passes`` is) with the caches in the
+    loop's carry. The ragged step carries the stacked pools as one run of
+    pages and writes and attends at ``entry * P``: no entry is copied out
+    and nothing is stacked again, so no second array of the pools' size
+    exists. All passes always run; the exit only selects."""
+
+    def __init__(self, model):
+        super().__init__(model)
+        self.n_passes = int(self.cfg.total_ut_steps)
+        self.cache_entries = self.n_passes * self.n_layers
+        self.exit_threshold = float(self.cfg.early_exit_threshold)
+
+    def _static_key(self):
+        return super()._static_key() + (self.n_passes, self.exit_threshold)
+
+    def _branch_out(self, w, i, norm, x):
+        """Each branch is normed again before it joins the residual:
+        ``input_layernorm_2``, ``post_attention_layernorm_2``."""
+        return _rms(x, self._lw(w, i, norm + "_2.weight"), self.eps)
+
+    @jax.named_scope("loop_exit")
+    def _pass_end(self, w, h):
+        """``model.norm`` on a pass's output: what the next pass starts
+        from, and what the gate and the head read."""
+        return _rms(h, w["model.norm.weight"], self.eps)
+
+    @jax.named_scope("loop_exit")
+    def _exit(self, w, states):
+        """states: [n_passes, ..., H] normed pass outputs. Returns (the
+        state each row leaves with, the pass it leaves after)."""
+        from .models.ouro import exit_select
+        return exit_select(states, w["model.early_exit_gate.weight"],
+                           w["model.early_exit_gate.bias"],
+                           self.exit_threshold)
+
+    @jax.named_scope("head")
+    def _logits(self, w, h):
+        """The head on an exit state: normed at its pass's end already."""
+        return _head_logits(w, h, self.tied, self.embed_key)
+
+    def step_ragged(self, w, tokens, positions, k_pools, v_pools, scatter,
+                    attend, shard=None):
+        """See _LlamaDecoder.step_ragged; k_pools/v_pools: [n_passes *
+        n_layers, P, kvh, bs, D]. ``exits``: [T] int32 in 1..n_passes."""
+        h, cos, sin = self._embed_ragged(w, tokens, positions)
+        shape = k_pools.shape
+        entry_pages, all_pages = shape[1], shape[0] * shape[1]
+        pages, _ = scatter
+        plan = _page_plan(scatter, shape[3])
+
+        def one_pass(carry, t):
+            h, kf, vf = carry
+            for i in range(self.n_layers):
+                first_page = (t * self.n_layers + i) * entry_pages
+                with jax.named_scope("kv_write"):
+                    # a dropped row (one past the entry) stays dropped
+                    at = jnp.where(pages >= entry_pages, all_pages,
+                                   pages + first_page)
+                h, kf, vf = self._layer_ragged(
+                    w, i, h, cos, sin, kf, vf, (at, *plan),
+                    partial(attend, first_page=first_page), shard=shard,
+                    write=_kv_write_pages)
+            h = self._pass_end(w, h)
+            return (h, kf, vf), h
+
+        (_, kf, vf), states = jax.lax.scan(
+            one_pass, (h, _join_entries(k_pools), _join_entries(v_pools)),
+            jnp.arange(self.n_passes, dtype=jnp.int32))
+        with jax.named_scope("kv_write"):
+            kf, vf = kf.reshape(shape), vf.reshape(shape)
+        h_exit, exit_pass = self._exit(w, states)
+        return self._logits(w, h_exit)[:, 0], exit_pass[:, 0], kf, vf
+
+    def step(self, w, tokens, positions, kcs, vcs, write_pos, score_mask):
+        """See _LlamaDecoder.step; kcs/vcs: [n_passes * n_layers, B, M, kvh,
+        hd]."""
+        h = w[self.embed_key][tokens]
+        cos = w["__rope_cos"][positions]      # [B, S, hd/2]
+        sin = w["__rope_sin"][positions]
+
+        def one_pass(carry, t):
+            h, kcs, vcs = carry
+            for i in range(self.n_layers):
+                e = t * self.n_layers + i
+                h, kc, vc = self._layer(
+                    w, i, h, cos, sin,
+                    jax.lax.dynamic_index_in_dim(kcs, e, keepdims=False),
+                    jax.lax.dynamic_index_in_dim(vcs, e, keepdims=False),
+                    write_pos, score_mask)
+                kcs = jax.lax.dynamic_update_index_in_dim(kcs, kc, e, 0)
+                vcs = jax.lax.dynamic_update_index_in_dim(vcs, vc, e, 0)
+            h = self._pass_end(w, h)
+            return (h, kcs, vcs), h
+
+        (_, kcs, vcs), states = jax.lax.scan(
+            one_pass, (h, kcs, vcs),
+            jnp.arange(self.n_passes, dtype=jnp.int32))
+        return self._logits(w, self._exit(w, states)[0]), kcs, vcs
 
 
 
@@ -462,6 +637,7 @@ class _GPTDecoder:
         self.hd = cfg.hidden_size // self.n_heads
         self.eps = cfg.layer_norm_epsilon
         self.n_layers = cfg.num_hidden_layers
+        self.cache_entries = self.n_layers    # see _LlamaDecoder
         self.tied = model.lm_head is None
         self.embed_key = "transformer.wte.weight"
 
@@ -568,7 +744,7 @@ class _GPTDecoder:
             h = _ln(h, w["transformer.ln_f.weight"],
                     w["transformer.ln_f.bias"], self.eps)
             logits = _head_logits(w, h, self.tied, self.embed_key)
-        return logits[:, 0], *_stack_pools(new_k, new_v)
+        return logits[:, 0], None, *_stack_pools(new_k, new_v)
 
     def tp_specs(self):
         """See _LlamaDecoder.tp_specs. GPT's fused qkv projection packs
@@ -666,13 +842,14 @@ def _sample(logits, key, do_sample, temperature, top_k, top_p):
 # -- public API ----------------------------------------------------------------
 
 def _prefill(dec, w, ids, mask, max_new):
-    """Shared prefill: cache alloc, left-padded positions, key/pre masks,
-    and the prompt step. Returns (kcs, vcs, key_mask, last_logits)."""
+    """Shared prefill: cache alloc (one cache per cache entry of the
+    decoder), left-padded positions, key/pre masks, and the prompt step.
+    Returns (kcs, vcs, key_mask, last_logits)."""
     b, s = ids.shape
     m_total = s + max_new
     positions = jnp.maximum(
         jnp.cumsum(mask, axis=1).astype(jnp.int32) - 1, 0)   # [B, S]
-    kcs = jnp.zeros((dec.n_layers, b, m_total, dec.n_kv, dec.hd),
+    kcs = jnp.zeros((dec.cache_entries, b, m_total, dec.n_kv, dec.hd),
                     w[dec.embed_key].dtype)
     vcs = jnp.zeros_like(kcs)
     t_idx = jnp.arange(m_total)[None, None, None, :]         # key slots
@@ -772,7 +949,7 @@ def _beam_impl(dec, w, ids, mask, max_new, num_beams, eos_id, has_eos,
         def reorder(a):
             # a: [..., B*K, ...] with beam-major rows; gather along beams
             shp = a.shape
-            ax = 1 if a.ndim > 3 else 0   # kcs/vcs: [L, BK, ...]; 2-d: BK
+            ax = 1 if a.ndim > 3 else 0   # kcs/vcs: [E, BK, ...]; 2-d: BK
             aa = jnp.moveaxis(a, ax, 0).reshape((b, k) + shp[:ax]
                                                 + shp[ax + 1:])
             ga = jnp.take_along_axis(
@@ -1005,7 +1182,9 @@ def _decoder_for(model):
     configs hash equal, so the module jits share executables across
     instances)."""
     from .models.gpt import GPTForCausalLM
+    from .models.ouro import OuroForCausalLM
     cls = _GPTDecoder if isinstance(model, GPTForCausalLM) \
+        else _OuroDecoder if isinstance(model, OuroForCausalLM) \
         else _LlamaDecoder
     struct = (cls, model.lm_head is None,    # head tying is baked into the
               _live_moe_struct(model))       # traced logits branch

@@ -209,7 +209,7 @@ class _MeshShard:
         return self._c(att, None, None, "mp")
 
     def pools(self, pools):
-        """[L, P, kvh, bs, D] stacked pools: per-KV-head shards."""
+        """[E, P, kvh, bs, D] stacked pools: per-KV-head shards."""
         return self._c(pools, None, None, "mp", None, None)
 
 
@@ -223,6 +223,14 @@ def _argmax_rows(logits):
 
 
 @jax.jit
+def _beside_exits(tokens, exits):
+    """The sampled tokens with the pass each row's logits were taken after
+    (a decoder that runs its layers several times a token reports it) as a
+    second row: one array, so one transfer brings both back."""
+    return jnp.stack([tokens, exits])
+
+
+@jax.jit
 def _all_finite(logits):
     """The StepGuard-style sample guard (serving/resilience.py): one
     fused reduce over the step's logits — NaN/inf anywhere means the
@@ -233,10 +241,11 @@ def _all_finite(logits):
 
 @jax.jit
 def _read_page(k_pools, v_pools, src):
-    """Gather one physical page's K/V across every layer — the device
-    half of a KV-page handoff EXPORT. ``src`` is a traced scalar, so one
-    compiled program serves every page index (a per-export stacked
-    gather would recompile on each distinct page count)."""
+    """Gather one physical page's K/V across every cache entry (the
+    pools' first axis) — the device half of a KV-page handoff EXPORT.
+    ``src`` is a traced scalar, so one compiled program serves every
+    page index (a per-export stacked gather would recompile on each
+    distinct page count)."""
     return k_pools[:, src], v_pools[:, src]
 
 
@@ -251,7 +260,7 @@ def _install_page(k_pools, v_pools, k_page, v_page, dst):
 
 @partial(jax.jit, donate_argnums=(0, 1))
 def _copy_page(k_pools, v_pools, src, dst):
-    """Copy one physical page across every layer of the shared pools —
+    """Copy one physical page across every cache entry of the shared pools —
     the device half of a copy-on-write rollback: the sequence's new
     private boundary page starts as a byte copy of the shared one."""
     return (k_pools.at[:, dst].set(k_pools[:, src]),
@@ -279,14 +288,14 @@ def _engine_step_impl(dec, shard, w, tokens, slot_ids, positions, valid,
     offs = positions % bs
     attend = _ragged.make_attend(tables, slot_ids, positions, valid,
                                  dec.n_heads // dec.n_kv, shard=shard)
-    logits, kp, vp = dec.step_ragged(w, tokens, positions, k_pools,
-                                     v_pools, (pages, offs), attend,
-                                     shard=shard)
+    logits, exits, kp, vp = dec.step_ragged(w, tokens, positions, k_pools,
+                                            v_pools, (pages, offs), attend,
+                                            shard=shard)
     if shard is not None:
         # pin the donated outputs to the per-KV-head layout the next
         # step's inputs commit to (no silent gather between steps)
         kp, vp = shard.pools(kp), shard.pools(vp)
-    return logits, kp, vp
+    return logits, exits, kp, vp
 
 
 _engine_step = partial(jax.jit, static_argnums=(0, 1),
@@ -336,13 +345,15 @@ class ServingEngine:
         if num_blocks is None:
             num_blocks = cfg.max_seqs * self.max_pages_per_seq
         dtype = self._w[self.dec.embed_key].dtype
-        shape = (self.dec.n_layers, num_blocks, self.dec.n_kv, bs,
+        # first axis: the K/V cache entries a token keeps, which is the
+        # layers of weights only where each runs once a token
+        shape = (self.dec.cache_entries, num_blocks, self.dec.n_kv, bs,
                  self.dec.hd)
         self._pool_shape, self._pool_dtype = shape, dtype
         self._kp = self._new_pool()
         self._vp = self._new_pool()
-        # device bytes of one page across K+V and every layer — the unit
-        # the telemetry/memwatch byte accounting is denominated in
+        # device bytes of one page across K+V and every cache entry — the
+        # unit the telemetry/memwatch byte accounting is denominated in
         self.page_bytes = (self._kp.nbytes + self._vp.nbytes) // num_blocks
         self.pool = KVBlockPool(num_blocks, bs,
                                 enable_prefix_cache=cfg.enable_prefix_cache)
@@ -432,7 +443,7 @@ class ServingEngine:
 
     # -- tensor-parallel placement (EngineConfig.mesh) ------------------------
     def _pool_sharding(self):
-        """NamedSharding of one stacked pool ([L, P, kvh, bs, D]
+        """NamedSharding of one stacked pool ([E, P, kvh, bs, D]
         per-KV-head over mp), or None on a single chip."""
         if self.mesh is None:
             return None
@@ -800,7 +811,8 @@ class ServingEngine:
                             first_wait_s=plan.first_wait_s,
                             pages_walked=self._pages_walked(plan),
                             pages_tabled=self.config.token_budget
-                            * self.max_pages_per_seq):
+                            * self.max_pages_per_seq,
+                            layer_visits=self.dec.cache_entries):
                         sampled = self._run_plan(plan, armed)
                 except Exception as exc:  # noqa: BLE001 — containment seam
                     if self.resilience is None:
@@ -1200,7 +1212,7 @@ class ServingEngine:
             # aliases a numpy array, and the next _pack_plan rewrites
             # these rows while a step that sampled nothing (a prefill
             # chunk) may still be running
-            logits, self._kp, self._vp = self._step_call(
+            logits, exits, self._kp, self._vp = self._step_call(
                 self._w, jnp.asarray(tokens), jnp.asarray(slots),
                 jnp.asarray(positions), jnp.asarray(valid),
                 jnp.array(self._tables), self._kp, self._vp)
@@ -1214,9 +1226,17 @@ class ServingEngine:
                 raise _res.StepFault(
                     "nan_logits", f"step {self.steps + 1} produced non-finite "
                     f"logits over {int(valid.sum())} packed tokens")
-            all_tok = np.asarray(_argmax_rows(logits)) \
-                if sample_points else None
-        with RecordEvent("serve.emit"):
+            all_tok, counts = None, {}
+            if sample_points:
+                if exits is None:
+                    all_tok = np.asarray(_argmax_rows(logits))
+                else:
+                    all_tok, passes = np.asarray(
+                        _beside_exits(_argmax_rows(logits), exits))
+                    rows = [i for _, i in sample_points]
+                    counts = {"exit_pass_sum": int(passes[rows].sum()),
+                              "exit_rows": len(rows)}
+        with RecordEvent("serve.emit", **counts):
             return self._emit_sampled(plan, sample_points, all_tok, armed)
 
     def _pages_walked(self, plan) -> int:
@@ -1518,7 +1538,7 @@ class ServingEngine:
         with self._lock:
             s = self.pool.stats
             base = {
-                "version": 2,
+                "version": 3,
                 "steps": self.steps,
                 "tokens_generated": self.tokens_generated,
                 "queue_depth": self.sched.queue_depth(),
@@ -1540,6 +1560,10 @@ class ServingEngine:
                 "spec": self.spec_stats(),
                 "attention": _ragged.attention_path(
                     self._shard, self._pool_shape, self._pool_dtype),
+                # two numbers, equal unless the model runs its layers
+                # several times a token: the pools are cache_entries deep
+                "model": {"weight_layers": self.dec.n_layers,
+                          "cache_entries": self.dec.cache_entries},
             }
             if self.mesh is not None:
                 base["mesh"] = {"mp": int(self.mesh.shape["mp"]),

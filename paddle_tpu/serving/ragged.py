@@ -101,19 +101,27 @@ def make_attend(page_tables, slot_ids, positions, valid, rep, shard=None):
     None on a single chip); the kernel takes the rows of each page-table
     slot as one query block, which is how ``_pack_plan`` packs them.
     Either way it runs under the scope ``paged_attention``: the device
-    time of the step's attention is found by that name."""
+    time of the step's attention is found by that name.
+
+    ``attend(q, kp, vp, first_page)`` reads one cache entry out of pools
+    that hold several entries' pages one after another (``[E * P, kvh, bs,
+    D]``, entry ``e`` at pages ``e * P ..``: a decoder whose step loops
+    keeps its stacked pools whole in the loop's carry): the page tables
+    are shifted by ``first_page``, so neither path copies the entry out."""
     from ..kernels import ragged_pallas as _rp
-    meta = []
+    with jax.named_scope("paged_attention"):
+        # once a step, not once a layer, and outside any loop the step
+        # makes; the reference does not read them and the compiler drops them
+        meta = _rp.seq_meta(slot_ids, positions, valid, page_tables.shape[0])
 
     @jax.named_scope("paged_attention")
-    def attend(q, kp, vp):
+    def attend(q, kp, vp, first_page=None):
+        tables = page_tables if first_page is None else jnp.where(
+            page_tables >= 0, page_tables + first_page, -1)
         if attention_path(shard, kp.shape, kp.dtype) == "reference":
-            return ragged_paged_attention(q, kp, vp, page_tables, slot_ids,
+            return ragged_paged_attention(q, kp, vp, tables, slot_ids,
                                           positions, valid, rep)
-        if not meta:                    # once a step, not once a layer
-            meta.extend(_rp.seq_meta(slot_ids, positions, valid,
-                                     page_tables.shape[0]))
-        return _rp.paged_attention(q, kp, vp, page_tables, *meta, rep=rep)
+        return _rp.paged_attention(q, kp, vp, tables, *meta, rep=rep)
 
     return attend
 

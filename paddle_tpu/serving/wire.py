@@ -337,7 +337,7 @@ WIRE_SCHEMAS = {
     },
     "telemetry_line": {
         "family": "telemetry_line",
-        "version": 2,                  # 2: "attention" (PR 27)
+        "version": 3,                  # 2: "attention" (PR 27); 3: "model"
         "version_key": "version",
         "required": {
             "version": "int",
@@ -360,11 +360,12 @@ WIRE_SCHEMAS = {
             "mem": "dict",
             "resilience": "dict",
             "attention": "str",
+            "model": "dict",
         },
         "item_key": None,
         "item_required": {},
         "item_optional": {},
-        "key_hashes": {1: "f2b55577", 2: "a5410fb5"},
+        "key_hashes": {1: "f2b55577", 2: "a5410fb5", 3: "3a9c8544"},
         "byte_stable": False,
         "builders": ("serving/engine.py::telemetry",),
         "consumers": (),

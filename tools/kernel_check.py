@@ -10,7 +10,7 @@ implementation the tests use as its oracle. They sit behind a flag that is
 off (``FLAGS_use_pallas_fused``) or behind an explicit MoE/packing option,
 and this survey is the record of which of them the installed Mosaic accepts
 (ROADMAP S7, D2). It turns no flag on. The serving step's paged attention
-kernel is on the engine's path on one chip; it is here at the two serving
+kernel is on the engine's path on one chip; it is here at the serving
 cells' geometries against the gather-based reference.
 
 Prints one JSON line per kernel — ``"verdict": "compiles and matches"``
@@ -184,7 +184,9 @@ def paged_step(pages, kvh, rep, rows, slots, table, plan, seed=0):
         valid[row:row + n] = True
         row += n
     q = rand(seed, (rows, kvh * rep, d))
-    kp, vp = (rand(seed + k, (pages, kvh, bs, d)) for k in (1, 2))
+    # under jit: a pool of gigabytes is drawn with no float32 copy beside it
+    kp, vp = (jax.jit(rand, static_argnums=(0, 1))(
+        seed + k, (pages, kvh, bs, d)) for k in (1, 2))
     return q, kp, vp, tuple(jnp.asarray(a) for a in
                             (tables, slot, pos, valid))
 
@@ -200,6 +202,12 @@ PAGED_GEOMETRIES = {
     # 700-token prompt from mid-page, a short first chunk
     "chat": (1280, 8, 4, 128, 32, 64,
              [(1, c) for c in range(60, 1000, 47)] + [(90, 700), (18, 18)]),
+    # ouro26-serve-decode: MHA 16 x 128; the kernel reads one cache entry
+    # out of the 192 entries' pages joined into one pool (3.2 GB: page
+    # offsets pass 2**31 bytes), so the live pages lie anywhere in it; the
+    # decode cell's plan
+    "looped": (192 * 256, 16, 1, 64, 16, 128,
+               [(1, c) for c in range(70, 250, 14)] + [(40, 40), (4, 130)]),
 }
 
 
@@ -230,6 +238,8 @@ CASES = (
      functools.partial(case_paged_attention, "decode"), REL_L2),
     ("paged_attention/chat", "ServingEngine on one chip",
      functools.partial(case_paged_attention, "chat"), REL_L2),
+    ("paged_attention/looped", "ServingEngine on one chip",
+     functools.partial(case_paged_attention, "looped"), REL_L2),
 )
 
 
