@@ -168,36 +168,13 @@ def _attend_gqa(q, k, v, score_mask, rep):
 
 
 @jax.named_scope("kv_write")
-def _layer_pools(k_pools, v_pools, i):
-    """Layer ``i``'s pools out of the stacked [L, P, kvh, bs, D] ones."""
-    return k_pools[i], v_pools[i]
-
-
-@jax.named_scope("kv_write")
-def _kv_write(kp, vp, k, v, scatter):
-    """Scatter this step's K/V rows ([T, 1, kvh, D]) into one layer's paged
-    pools at ``scatter`` = (pages [T], offs [T]); page index P (one past the
-    pool) is a dropped row. Scope ``kv_write``: with ``_layer_pools`` and
-    ``_stack_pools`` the whole cost of keeping the pools, whatever the
-    attention reads."""
-    pages, offs = scatter
-    kp = kp.at[pages, :, offs, :].set(k[:, 0].astype(kp.dtype), mode="drop")
-    vp = vp.at[pages, :, offs, :].set(v[:, 0].astype(vp.dtype), mode="drop")
-    return kp, vp
-
-
-@jax.named_scope("kv_write")
-def _stack_pools(new_k, new_v):
-    """The per-layer pools back into the [L, P, kvh, bs, D] the step takes."""
-    return jnp.stack(new_k), jnp.stack(new_v)
-
-
-@jax.named_scope("kv_write")
-def _join_entries(pools):
+def _join_entries(k_pools, v_pools):
     """Stacked pools [E, P, kvh, bs, D] as one run of pages [E * P, kvh, bs,
-    D], entry ``e`` at pages ``e * P ..`` (no element moves): the form a
-    looping step carries, writes in place and attends from."""
-    return pools.reshape((-1,) + pools.shape[2:])
+    D] each, entry ``e`` at pages ``e * P ..`` (no element moves): the form
+    every ragged step threads through its layers, writes in place and
+    attends from."""
+    joined = (-1,) + k_pools.shape[2:]
+    return k_pools.reshape(joined), v_pools.reshape(joined)
 
 
 @jax.named_scope("kv_write")
@@ -213,16 +190,43 @@ def _page_plan(scatter, bs):
     return lands.any(1), jnp.argmax(lands, axis=1).astype(jnp.int32)
 
 
+def _entry_seams(pool_shape, scatter, attend):
+    """What a layer needs to work on ONE cache entry of the joined pools.
+    pool_shape: the stacked pools' [E, P, kvh, bs, D]; scatter: the step's
+    (pages [T], offs [T]) within an entry, page ``P`` a dropped row; attend:
+    ``ragged.make_attend``'s callable. Returns ``seam(e)`` -> (the scatter
+    ``_kv_write_pages`` takes for entry ``e``, the ``attend(q, kf, vf)``
+    that reads entry ``e``); ``e`` may be traced. The page plan is computed
+    here, once a step."""
+    entry_pages, all_pages = pool_shape[1], pool_shape[0] * pool_shape[1]
+    pages, _ = scatter
+    plan = _page_plan(scatter, pool_shape[3])
+
+    def seam(e):
+        first_page = e * entry_pages
+        with jax.named_scope("kv_write"):
+            # a dropped row (one past the entry) stays dropped
+            at = jnp.where(pages >= entry_pages, all_pages,
+                           pages + first_page)
+        return (at, *plan), partial(attend, first_page=first_page)
+
+    return seam
+
+
 @jax.named_scope("kv_write")
 def _kv_write_pages(kf, vf, k, v, scatter):
-    """``_kv_write`` for joined pools [N, kvh, bs, D] in a loop's carry:
-    each row's page is read, this step's rows are put into its slots and
-    the page is written back whole, 64 KB a row and nothing else of the
-    pools moved. A row write (``_kv_write``) makes the compiler keep the
-    carry in the layout its scatter likes, slots before heads, and copy the
-    whole of it into the attention kernel's layout at every call; a page is
-    the unit both agree on. scatter = (pages [T] in the joined pools, ``N``
-    = a dropped row; ``_page_plan``'s hit and src)."""
+    """Put this step's K/V rows ([T, 1, kvh, D]) into the joined pools [N,
+    kvh, bs, D], the one write of every decoder: each row's page is read,
+    the step's rows are put into its slots and the page is written back
+    whole, a page a row and nothing else of the pools moved, so the
+    scatter updates the step's donated argument (or a loop's carry) in
+    place. A scatter of single rows makes the compiler keep the pools in
+    the layout that scatter likes, slots before heads, and copy the whole
+    of them into the attention kernel's layout at every call; a page is the
+    unit both agree on. scatter = (pages [T] in the joined pools, ``N`` = a
+    dropped row; ``_page_plan``'s hit and src). Scope ``kv_write``: with
+    ``_join_entries``, ``_page_plan`` and ``_entry_seams``' targets the
+    whole cost of keeping the pools, whatever the attention reads."""
     pages, hit, src = scatter
     at = jnp.minimum(pages, kf.shape[0] - 1)
 
@@ -233,6 +237,13 @@ def _kv_write_pages(kf, vf, k, v, scatter):
         return pool.at[pages].set(page, mode="drop")
 
     return write(kf, k), write(vf, v)
+
+
+@jax.named_scope("kv_write")
+def _split_entries(kf, vf, pool_shape):
+    """The joined pools back as the [E, P, kvh, bs, D] the engine keeps
+    between steps (no element moves)."""
+    return kf.reshape(pool_shape), vf.reshape(pool_shape)
 
 
 class _LlamaDecoder:
@@ -360,19 +371,19 @@ class _LlamaDecoder:
             att = _attend(q, kc, vc, score_mask).reshape(b, s, -1)
         return self._post_attn(w, i, h, att), kc, vc
 
-    def _layer_ragged(self, w, i, h, cos, sin, kp, vp, scatter, attend,
-                      shard=None, write=_kv_write):
+    def _layer_ragged(self, w, i, h, cos, sin, kf, vf, scatter, attend,
+                      shard=None):
         """One layer over a PACKED ragged batch (mixed prefill chunks and
-        decode tokens from different sequences as a [T, 1, ...] batch).
-        kp/vp: [P, kvh, bs, D] paged pools; scatter: (pages [T], offs [T])
-        per-token write targets (page index P == dropped row); attend:
-        callable(q [T, H, D], kp, vp) -> [T, H, D] — the ragged paged
-        attention (paddle_tpu.serving.ragged supplies it); shard: the
+        decode tokens from different sequences as a [T, 1, ...] batch),
+        working on one cache entry of the joined pools. kf/vf: [E * P, kvh,
+        bs, D]; scatter, attend: that entry's pair from ``_entry_seams``:
+        the write targets ``_kv_write_pages`` takes, and callable(q [T, H,
+        D], kf, vf) -> [T, H, D], the ragged paged attention over the
+        entry's pages (paddle_tpu.serving.ragged supplies it); shard: the
         serving engine's tensor-parallel annotator (None = single chip) —
         it pins q/k/v to the per-head layout right after the projection
         and the attention output right before the row-parallel o_proj,
-        the same two seams the training side shards; write:
-        callable(kp, vp, k, v, scatter) -> (kp, vp) that puts the rows in."""
+        the same two seams the training side shards."""
         t, s, _ = h.shape
         with jax.named_scope("attn_proj"):
             x = _rms(h, self._lw(w, i, "input_layernorm.weight"), self.eps)
@@ -381,11 +392,11 @@ class _LlamaDecoder:
             k = _rope_rows(k, cos, sin)
             if shard is not None:
                 q, k, v = shard.qkv(q, k, v)
-        kp, vp = write(kp, vp, k, v, scatter)
-        att = attend(q[:, 0], kp, vp).reshape(t, 1, -1)
+        kf, vf = _kv_write_pages(kf, vf, k, v, scatter)
+        att = attend(q[:, 0], kf, vf).reshape(t, 1, -1)
         if shard is not None:
             att = shard.att(att)
-        return self._post_attn(w, i, h, att), kp, vp
+        return self._post_attn(w, i, h, att), kf, vf
 
     @jax.named_scope("embed")
     def _embed_ragged(self, w, tokens, positions):
@@ -400,20 +411,23 @@ class _LlamaDecoder:
         """Ragged-batch twin of step(): tokens/positions: [T] packed
         mixed-phase batch (each entry one token of some sequence at its
         absolute position); k_pools/v_pools: [E, P, kvh, bs, D] shared
-        block pools, one per cache entry (here one a layer);
-        scatter/attend/shard as in _layer_ragged. Returns (logits [T, V],
-        exits, k_pools', v_pools'): ``exits`` is the pass each row's
-        logits were taken after, [T] int32, where the decoder runs its
-        layers several times a token, and None here."""
+        block pools, one per cache entry (here one a layer); scatter:
+        (pages [T], offs [T]) per-token write targets within an entry
+        (page index P == dropped row); attend: ``ragged.make_attend``'s
+        callable; shard as in _layer_ragged. The pools are joined into one
+        run of pages, threaded through the layers and written in place a
+        page a row: no entry is copied out and nothing is stacked again.
+        Returns (logits [T, V], exits, k_pools', v_pools'): ``exits`` is
+        the pass each row's logits were taken after, [T] int32, where the
+        decoder runs its layers several times a token, and None here."""
         h, cos, sin = self._embed_ragged(w, tokens, positions)
-        new_k, new_v = [], []
+        seam = _entry_seams(k_pools.shape, scatter, attend)
+        kf, vf = _join_entries(k_pools, v_pools)
         for i in range(self.n_layers):
-            h, kp, vp = self._layer_ragged(
-                w, i, h, cos, sin, *_layer_pools(k_pools, v_pools, i),
-                scatter, attend, shard=shard)
-            new_k.append(kp)
-            new_v.append(vp)
-        return self._logits(w, h)[:, 0], None, *_stack_pools(new_k, new_v)
+            h, kf, vf = self._layer_ragged(w, i, h, cos, sin, kf, vf,
+                                           *seam(i), shard=shard)
+        return (self._logits(w, h)[:, 0], None,
+                *_split_entries(kf, vf, k_pools.shape))
 
     _TP_COL = ("self_attn.q_proj.weight", "self_attn.k_proj.weight",
                "self_attn.v_proj.weight", "mlp.gate_proj.weight",
@@ -515,31 +529,21 @@ class _OuroDecoder(_LlamaDecoder):
         """See _LlamaDecoder.step_ragged; k_pools/v_pools: [n_passes *
         n_layers, P, kvh, bs, D]. ``exits``: [T] int32 in 1..n_passes."""
         h, cos, sin = self._embed_ragged(w, tokens, positions)
-        shape = k_pools.shape
-        entry_pages, all_pages = shape[1], shape[0] * shape[1]
-        pages, _ = scatter
-        plan = _page_plan(scatter, shape[3])
+        seam = _entry_seams(k_pools.shape, scatter, attend)
 
         def one_pass(carry, t):
             h, kf, vf = carry
             for i in range(self.n_layers):
-                first_page = (t * self.n_layers + i) * entry_pages
-                with jax.named_scope("kv_write"):
-                    # a dropped row (one past the entry) stays dropped
-                    at = jnp.where(pages >= entry_pages, all_pages,
-                                   pages + first_page)
                 h, kf, vf = self._layer_ragged(
-                    w, i, h, cos, sin, kf, vf, (at, *plan),
-                    partial(attend, first_page=first_page), shard=shard,
-                    write=_kv_write_pages)
+                    w, i, h, cos, sin, kf, vf, *seam(t * self.n_layers + i),
+                    shard=shard)
             h = self._pass_end(w, h)
             return (h, kf, vf), h
 
         (_, kf, vf), states = jax.lax.scan(
-            one_pass, (h, _join_entries(k_pools), _join_entries(v_pools)),
+            one_pass, (h, *_join_entries(k_pools, v_pools)),
             jnp.arange(self.n_passes, dtype=jnp.int32))
-        with jax.named_scope("kv_write"):
-            kf, vf = kf.reshape(shape), vf.reshape(shape)
+        kf, vf = _split_entries(kf, vf, k_pools.shape)
         h_exit, exit_pass = self._exit(w, states)
         return self._logits(w, h_exit)[:, 0], exit_pass[:, 0], kf, vf
 
@@ -711,7 +715,7 @@ class _GPTDecoder:
         att = _attend(q, kc, vc, score_mask).reshape(b, s, -1)
         return self._post_attn(w, i, h, att), kc, vc
 
-    def _layer_ragged(self, w, i, h, kp, vp, scatter, attend, shard=None):
+    def _layer_ragged(self, w, i, h, kf, vf, scatter, attend, shard=None):
         """Packed ragged-batch layer (see _LlamaDecoder._layer_ragged);
         GPT has no rope — positions enter through the wpe embedding."""
         p = f"transformer.h.{i}."
@@ -721,11 +725,11 @@ class _GPTDecoder:
             q, k, v = self._qkv_proj(w, i, x, t, s)
             if shard is not None:
                 q, k, v = shard.qkv(q, k, v)
-        kp, vp = _kv_write(kp, vp, k, v, scatter)
-        att = attend(q[:, 0], kp, vp).reshape(t, 1, -1)
+        kf, vf = _kv_write_pages(kf, vf, k, v, scatter)
+        att = attend(q[:, 0], kf, vf).reshape(t, 1, -1)
         if shard is not None:
             att = shard.att(att)
-        return self._post_attn(w, i, h, att), kp, vp
+        return self._post_attn(w, i, h, att), kf, vf
 
     def step_ragged(self, w, tokens, positions, k_pools, v_pools, scatter,
                     attend, shard=None):
@@ -733,18 +737,16 @@ class _GPTDecoder:
         with jax.named_scope("embed"):
             h = (w["transformer.wte.weight"][tokens]
                  + w["transformer.wpe.weight"][positions])[:, None]
-        new_k, new_v = [], []
+        seam = _entry_seams(k_pools.shape, scatter, attend)
+        kf, vf = _join_entries(k_pools, v_pools)
         for i in range(self.n_layers):
-            h, kp, vp = self._layer_ragged(
-                w, i, h, *_layer_pools(k_pools, v_pools, i), scatter, attend,
-                shard=shard)
-            new_k.append(kp)
-            new_v.append(vp)
+            h, kf, vf = self._layer_ragged(w, i, h, kf, vf, *seam(i),
+                                           shard=shard)
         with jax.named_scope("head"):
             h = _ln(h, w["transformer.ln_f.weight"],
                     w["transformer.ln_f.bias"], self.eps)
             logits = _head_logits(w, h, self.tied, self.embed_key)
-        return logits[:, 0], None, *_stack_pools(new_k, new_v)
+        return logits[:, 0], None, *_split_entries(kf, vf, k_pools.shape)
 
     def tp_specs(self):
         """See _LlamaDecoder.tp_specs. GPT's fused qkv projection packs

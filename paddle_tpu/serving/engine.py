@@ -271,7 +271,9 @@ def _engine_step_impl(dec, shard, w, tokens, slot_ids, positions, valid,
                       tables, k_pools, v_pools):
     """The one compiled serving program: scatter targets from the page
     tables, ragged attention over the pools, logits for every packed
-    token. Pools are donated — each step reuses the previous buffers.
+    token. Pools are donated — each step reuses the previous buffers,
+    and ``step_ragged`` writes this step's rows into them in place (a page
+    a row; nothing of a pool's size is copied).
     ``shard`` (static, None on a single chip) is the tensor-parallel
     annotator pinning the TP layout through the ragged path. (The
     un-jitted body, so the AOT cache path can close over ``dec`` and

@@ -59,8 +59,10 @@ class KVBlockPool:
 
     # -- core accounting ------------------------------------------------------
     def used_blocks(self) -> int:
-        """Pages held by live sequences (refcount > 0)."""
-        return sum(1 for r in self._ref if r > 0)
+        """Pages held by live sequences (refcount > 0): every page is on
+        the free list, parked in the prefix cache or held, so nothing is
+        walked (the engine and its telemetry ask several times a step)."""
+        return self.num_blocks - len(self._free) - len(self._cached)
 
     def cached_blocks(self) -> int:
         return len(self._cached)

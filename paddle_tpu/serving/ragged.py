@@ -103,11 +103,14 @@ def make_attend(page_tables, slot_ids, positions, valid, rep, shard=None):
     Either way it runs under the scope ``paged_attention``: the device
     time of the step's attention is found by that name.
 
-    ``attend(q, kp, vp, first_page)`` reads one cache entry out of pools
-    that hold several entries' pages one after another (``[E * P, kvh, bs,
-    D]``, entry ``e`` at pages ``e * P ..``: a decoder whose step loops
-    keeps its stacked pools whole in the loop's carry): the page tables
-    are shifted by ``first_page``, so neither path copies the entry out."""
+    ``attend(q, kf, vf, first_page)`` reads one cache entry out of pools
+    that hold every entry's pages one after another (``[E * P, kvh, bs,
+    D]``, entry ``e`` at pages ``e * P ..``): every decoder's step threads
+    its stacked pools through the layers in that form
+    (``generation._entry_seams`` binds ``first_page`` a layer). The page
+    tables are shifted by ``first_page``, so neither path copies the entry
+    out. Without ``first_page`` the pools are one entry's, read as they
+    are."""
     from ..kernels import ragged_pallas as _rp
     with jax.named_scope("paged_attention"):
         # once a step, not once a layer, and outside any loop the step

@@ -170,9 +170,10 @@ def one_chip():
 
 
 @pytest.mark.parametrize("pages,kvh,rep,rows,slots,table", [
-    (256, 32, 1, 64, 16, 128),       # cgpt67-serve-decode
-    (1280, 8, 4, 128, 32, 64),       # mistral7b-serve-chat
-    (192 * 256, 16, 1, 64, 16, 128),  # ouro26-serve-decode: 192 entries joined
+    # every cell's cache entries joined into one pool, as the step threads them
+    (16 * 256, 32, 1, 64, 16, 128),     # cgpt67-serve-decode
+    (20 * 1280, 8, 4, 128, 32, 64),     # mistral7b-serve-chat
+    (192 * 256, 16, 1, 64, 16, 128),    # ouro26-serve-decode
 ], ids=["decode", "chat", "looped"])
 def test_paged_attention_compiles_at_the_serving_cells_geometries(
         one_chip, pages, kvh, rep, rows, slots, table):

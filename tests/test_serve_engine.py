@@ -680,6 +680,35 @@ def test_spec_accept_rate_floor_on_repetitive_text():
     assert eng1.steps < eng0.steps     # speculation saved device calls
 
 
+def test_used_blocks_is_the_count_of_held_pages():
+    """Free, parked in the prefix cache or held: ``used_blocks`` subtracts
+    and never walks the refcounts; through every way a page changes hands."""
+    pool = KVBlockPool(6, 4)
+
+    def held():
+        assert pool.used_blocks() == sum(r > 0 for r in pool._ref)
+        return pool.used_blocks()
+
+    toks = list(range(9))
+    pages = pool.allocate(3)
+    assert held() == 3
+    pool.register_prefix(toks, pages)
+    pool.incref(pages[:1])
+    pool.release(pages)                     # two park in the cache, one held
+    assert held() == 1 and pool.cached_blocks() == 1
+    pool.release(pages[:1])
+    assert held() == 0 and pool.cached_blocks() == 2
+    hit, n = pool.match_prefix(toks)        # both come back out of the cache
+    assert n == 8 and held() == 2
+    more = pool.allocate(4)                 # the unregistered page and three
+    assert held() == 6 and pool.free_blocks() == 0
+    pool.release(hit + more)
+    assert held() == 0
+    pool.allocate(5)                        # evicts a parked page
+    assert held() == 5 and pool.stats["evicted"] == 1
+    assert pool.drop_cache() == 1 and held() == 5
+
+
 # -- KV rollback (truncate) ----------------------------------------------------
 
 def test_truncate_releases_tail_and_drains_to_zero():

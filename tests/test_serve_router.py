@@ -158,6 +158,11 @@ class TestTensorParallelEngine:
         assert q.sharding.shard_shape(q.shape)[1] == q.shape[1] // 2
         assert o.sharding.shard_shape(o.shape)[0] == o.shape[0] // 2
         assert emb.sharding.shard_shape(emb.shape) == emb.shape
+        # the step joins the pools' first two axes and splits them again:
+        # the head axis comes back where ``shard.pools`` pins it
+        eng.generate_batch(_prompts(2), max_new_tokens=3)
+        for pool in (eng._kp, eng._vp):
+            assert pool.sharding.shard_shape(pool.shape) == shard
 
     def test_pool_shard_bytes_match_mem_report_plan(self):
         """tools/mem_report.py plan()'s kv_cache term already models

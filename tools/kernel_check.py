@@ -162,12 +162,16 @@ def case_gmm():
     return (t, dm, dff, e), dict(zip(("out", "dx", "dw"), zip(got, want)))
 
 
-def paged_step(pages, kvh, rep, rows, slots, table, plan, seed=0):
+def paged_step(entries, pages, kvh, rep, rows, slots, table, plan, seed=0):
     """One packed serving step at a cell's geometry, as ``_pack_plan``
     lays it out: ``plan`` is (rows, context) per scheduled sequence, one
     page-table slot each from slot 1 on (slot 0 stays idle), live pages
-    drawn from a shuffled pool; the rest of the budget is padding rows.
-    Returns (q, k_pool, v_pool, (tables, slot_ids, positions, valid))."""
+    drawn from a shuffled entry of ``pages`` pages; the rest of the budget
+    is padding rows. The pools are the ``entries`` cache entries' pages
+    joined into one run, as the step program threads them, and the tables
+    point into the LAST entry, the farthest page offset ``make_attend``
+    applies. Returns (q, k_pool, v_pool, (tables, slot_ids, positions,
+    valid))."""
     bs, d = 16, 128
     rng = np.random.default_rng(seed)
     tables = np.full((slots, table), -1, np.int32)
@@ -177,7 +181,7 @@ def paged_step(pages, kvh, rep, rows, slots, table, plan, seed=0):
     perm, at, row = rng.permutation(pages), 0, 0
     for s_, (n, ctx) in enumerate(plan, start=1):
         need = -(-ctx // bs)
-        tables[s_, :need] = perm[at:at + need]
+        tables[s_, :need] = (entries - 1) * pages + perm[at:at + need]
         at += need
         slot[row:row + n] = s_
         pos[row:row + n] = np.arange(ctx - n, ctx)
@@ -186,27 +190,27 @@ def paged_step(pages, kvh, rep, rows, slots, table, plan, seed=0):
     q = rand(seed, (rows, kvh * rep, d))
     # under jit: a pool of gigabytes is drawn with no float32 copy beside it
     kp, vp = (jax.jit(rand, static_argnums=(0, 1))(
-        seed + k, (pages, kvh, bs, d)) for k in (1, 2))
+        seed + k, (entries * pages, kvh, bs, d)) for k in (1, 2))
     return q, kp, vp, tuple(jnp.asarray(a) for a in
                             (tables, slot, pos, valid))
 
 
-# (pool pages, KV heads, query heads a KV head, token budget, slots, table
-# width, plan) of the two serving cells, `bench/traffic/`'s engines
+# (cache entries, pages an entry, KV heads, query heads a KV head, token
+# budget, slots, table width, plan) of the three serving cells,
+# `bench/traffic/`'s engines. Every decoder's step joins its entries' pages
+# into one pool and the kernel reads one entry at a page offset
 PAGED_GEOMETRIES = {
     # cgpt67-serve-decode: MHA; 13 decodes, a 40-row prompt chunk, a
     # verify chunk of 1 + 3 drafts
-    "decode": (256, 32, 1, 64, 16, 128,
+    "decode": (16, 256, 32, 1, 64, 16, 128,
                [(1, c) for c in range(70, 250, 14)] + [(40, 40), (4, 130)]),
     # mistral7b-serve-chat: GQA 32/8; 20 decodes, a chunk that continues a
     # 700-token prompt from mid-page, a short first chunk
-    "chat": (1280, 8, 4, 128, 32, 64,
+    "chat": (20, 1280, 8, 4, 128, 32, 64,
              [(1, c) for c in range(60, 1000, 47)] + [(90, 700), (18, 18)]),
-    # ouro26-serve-decode: MHA 16 x 128; the kernel reads one cache entry
-    # out of the 192 entries' pages joined into one pool (3.2 GB: page
-    # offsets pass 2**31 bytes), so the live pages lie anywhere in it; the
-    # decode cell's plan
-    "looped": (192 * 256, 16, 1, 64, 16, 128,
+    # ouro26-serve-decode: MHA 16 x 128; 192 entries (3.2 GB a pool: page
+    # offsets pass 2**31 bytes); the decode cell's plan
+    "looped": (192, 256, 16, 1, 64, 16, 128,
                [(1, c) for c in range(70, 250, 14)] + [(40, 40), (4, 130)]),
 }
 
@@ -215,8 +219,10 @@ def case_paged_attention(cell):
     """The step's attention kernel against the gather-based reference."""
     from paddle_tpu.kernels import ragged_pallas as rp
     from paddle_tpu.serving.ragged import ragged_paged_attention
-    pages, kvh, rep, rows, slots, table, plan = PAGED_GEOMETRIES[cell]
-    q, kp, vp, args = paged_step(pages, kvh, rep, rows, slots, table, plan)
+    entries, pages, kvh, rep, rows, slots, table, plan = \
+        PAGED_GEOMETRIES[cell]
+    q, kp, vp, args = paged_step(entries, pages, kvh, rep, rows, slots,
+                                 table, plan)
     got = jax.jit(lambda q, kp, vp, tables, slot, pos, valid:
                   rp.paged_attention(
                       q, kp, vp, tables,
@@ -224,7 +230,7 @@ def case_paged_attention(cell):
                       rep=rep))(q, kp, vp, *args)
     want = jax.jit(functools.partial(ragged_paged_attention, rep=rep))(
         q, kp, vp, *args)
-    return (rows, kvh * rep, 128, pages, 16), {"out": (got, want)}
+    return (rows, kvh * rep, 128, entries * pages, 16), {"out": (got, want)}
 
 
 CASES = (
