@@ -204,18 +204,3 @@ class GPTForCausalLM(nn.Layer):
 
     def num_params(self):
         return sum(p.numel() for p in self.parameters())
-
-    def flops_per_token(self, seq_len: int) -> float:
-        """Training FLOPs/token. MoE experts only count activated ones."""
-        c = self.config
-        n_dense = 0
-        for name, p in self.named_parameters():
-            if ".mlp.w" in name or ".mlp.b" in name:
-                continue  # batched expert bank counted separately
-            n_dense += p.numel()
-        moe_blocks = sum(1 for blk in self.transformer.h
-                         if getattr(blk, "is_moe", False))
-        active_expert = (2 * c.hidden_size * c.ffn_size) * c.moe_top_k
-        # causal attention matmuls: 12*L*h*s fwd+bwd, halved by causality
-        attn = 6.0 * c.num_hidden_layers * c.hidden_size * seq_len
-        return 6.0 * (n_dense + moe_blocks * active_expert) + attn

@@ -417,14 +417,3 @@ class LlamaForCausalLM(nn.Layer):
 
     def num_params(self):
         return sum(p.numel() for p in self.parameters())
-
-    def flops_per_token(self, seq_len: int) -> float:
-        """Approximate training FLOPs/token (6N + attention term).
-
-        Attention matmuls (QK^T, AV): 4*s*h per layer forward, x3 for
-        fwd+bwd, halved by causal masking -> 6*L*h*s per token.
-        """
-        c = self.config
-        n = self.num_params()
-        attn = 6.0 * c.num_hidden_layers * c.hidden_size * seq_len
-        return 6.0 * n + attn

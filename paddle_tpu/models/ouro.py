@@ -163,12 +163,3 @@ class OuroForCausalLM(LlamaForCausalLM):
         raise NotImplementedError(
             "OuroForCausalLM runs its layers total_ut_steps times a token; "
             "the pipeline region runs a block list once")
-
-    def flops_per_token(self, seq_len: int) -> float:
-        """As ``LlamaForCausalLM``'s, every layer counted once a pass."""
-        c = self.config
-        t = c.total_ut_steps
-        layers = sum(p.numel() for p in self.model.layers.parameters())
-        n = self.num_params() + (t - 1) * layers
-        return 6.0 * n + 6.0 * t * c.num_hidden_layers * c.hidden_size \
-            * seq_len

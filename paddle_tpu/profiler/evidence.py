@@ -32,9 +32,11 @@ Design rules:
     hardware window died rather than silently trusting an older one
     forever.
 
-The probe, bench, bench-session and mfu_lab formats have no writer in
-the tree since ``bench.py`` was removed (ROADMAP D2/D4 decide what the
-resolver still reads); their ingestors keep recorded artifacts readable.
+The probe, bench, bench-session, bench-serve and mfu_lab formats have no
+writer in the tree since ``bench.py`` and the CPU serving bench were
+removed (ROADMAP D2 decides what the resolver still reads); their
+ingestors keep recorded artifacts readable, and the repo root holds
+none: the three the tests ingest are under ``tests/data/evidence/``.
 
 Row shape (schema 1)::
 

@@ -24,9 +24,8 @@ back past the accepted frontier (copy-on-write on shared pages):
     eng = ServingEngine(model, EngineConfig(spec_method="ngram",
                                             num_draft_tokens=4))
 
-Benchmark with ``python tools/bench_serve.py --fast`` (Poisson open-loop
-load, continuous vs static policy, BENCH_SERVE_*.json artifact; add
-``--spec`` for the speculative vs non-speculative rows).
+Speed is measured on the chip by ``python bench/run.py`` (cells in
+``BENCHMARK.json``, the driver's results in ``PERF_LEDGER.jsonl``).
 
 Observability (``serving.obs``): per-request lifecycle tracing
 (chrome-trace exportable, trace_merge-alignable with training traces),
@@ -74,9 +73,8 @@ the affinity key) replays onto affinity-matched survivors:
     while router.step_all():
         pass
 
-Benchmark with ``python tools/bench_serve.py --router``; drill replica
-death with ``python tools/chaos_drill.py --router``; watch the fleet
-with ``python tools/serve_top.py --demo --replicas 4``.
+Drill replica death with ``python tools/chaos_drill.py --router``;
+watch the fleet with ``python tools/serve_top.py --demo --replicas 4``.
 
 Disaggregated serving (``EngineConfig(role=)`` + the router's pool
 classes): ``role="prefill"`` engines give the whole token budget to
@@ -93,9 +91,9 @@ decode survivor; nothing parks:
                                                token_budget=16))]
     router = ReplicaRouter(fleet, policy="affinity")
 
-Benchmark with ``python tools/bench_serve.py --disagg``; drill prefill
-death with ``python tools/chaos_drill.py --disagg``; watch the pools
-with ``python tools/serve_top.py --demo --disagg --replicas 4``.
+Drill prefill death with ``python tools/chaos_drill.py --disagg``;
+watch the pools with ``python tools/serve_top.py --demo --disagg
+--replicas 4``.
 
 Fleet observability (``serving.fleet_obs``): the third observability
 plane (training → engine → fleet). ``ReplicaRouter(fleet_obs=True |
@@ -141,9 +139,8 @@ on the fleet-obs signal ring:
     while router.step_all():
         scaler.control()                # at most one action per pass
 
-Benchmark the 10x traffic swing with ``python tools/bench_serve.py
---elastic``; drill faulted spawns/mid-burst retires with ``python
-tools/chaos_drill.py --elastic``.
+Drill faulted spawns and mid-burst retires over a 10x traffic swing
+with ``python tools/chaos_drill.py --elastic``.
 
 Fault-domain fabric (``serving.transport`` + ``serving.membership``):
 the router's three cross-replica channels — KV-page hand-off,
@@ -163,7 +160,7 @@ garbage and every request finishes exactly once:
 Disarmed (the default) the synchronous in-process paths are untouched,
 bit-identically. Drill with ``python tools/chaos_drill.py --partition``
 (partition-then-heal vs lease expiry) and ``--lossy`` (5% drop + dup +
-delay); benchmark with ``python tools/bench_serve.py --lossy``.
+delay).
 
 Lock discipline (``serving.locking``): every serving-plane lock is an
 ``OrderedLock`` ranked by the declared ``LOCK_ORDER`` (fleet_obs →

@@ -43,7 +43,7 @@ from .speculative import make_drafter, verify_greedy
 
 
 class EngineConfig:
-    """Static shapes and policy for one engine (one compiled program).
+    """Static shapes and options for one engine (one compiled program).
 
     Speculative decoding: ``spec_method`` = None (off), "ngram"
     (model-free self-drafting), or "draft_model" (requires
@@ -57,7 +57,7 @@ class EngineConfig:
                  block_size: int = 16, num_blocks: Optional[int] = None,
                  max_model_len: Optional[int] = None,
                  enable_prefix_cache: bool = True,
-                 policy: str = "continuous", quant: Optional[str] = None,
+                 quant: Optional[str] = None,
                  spec_method: Optional[str] = None,
                  num_draft_tokens: int = 4, draft_model=None,
                  spec_options: Optional[dict] = None,
@@ -69,7 +69,6 @@ class EngineConfig:
         self.num_blocks = num_blocks
         self.max_model_len = max_model_len
         self.enable_prefix_cache = bool(enable_prefix_cache)
-        self.policy = policy
         self.quant = quant
         self.spec_method = spec_method
         self.num_draft_tokens = int(num_draft_tokens)
@@ -386,7 +385,7 @@ class ServingEngine:
                 "kv_pages", lambda: (self._kp, self._vp))
         self.role = cfg.role
         self.sched = Scheduler(self.pool, cfg.max_seqs, cfg.token_budget,
-                               self.max_pages_per_seq, policy=cfg.policy,
+                               self.max_pages_per_seq,
                                drafter=self.drafter,
                                num_draft_tokens=cfg.num_draft_tokens
                                if self.drafter is not None else 0,

@@ -35,8 +35,7 @@ costs one ``is None`` check, microbench-pinned like the obs plane):
     ``AdmissionRejected`` carrying a retry-after estimate derived from
     the engine's observed service time (PR 9 telemetry), and the
     SLO-aware ``shed`` policy refuses requests whose predicted queue
-    wait already blows their ``ttft_deadline`` (goodput-protecting,
-    proven by ``tools/bench_serve.py --chaos``).
+    wait already blows their ``ttft_deadline`` (goodput-protecting).
 
 Arm per engine with ``EngineConfig(resilience=True | ResilienceConfig)``
 or globally with ``PADDLE_SERVE_RESILIENCE=1``;

@@ -24,11 +24,6 @@ step under one token budget:
     per-sequence verify chunks, so speculation accelerates idle decode
     capacity and yields to real work under load.
 
-``policy="static"`` degrades this scheduler to gang admission (admit only
-into an empty batch, run it dry) — the BatchingServer behavior — so
-tools/bench_serve.py measures the POLICY delta with identical per-step
-machinery.
-
 ``role="prefill"`` (disaggregated serving) re-purposes the same budget
 machinery: the WHOLE budget feeds chunked prefill, a chunk never
 includes the sequence's final pending token (feeding it would SAMPLE —
@@ -269,11 +264,9 @@ class Scheduler:
     the engine serializes submit/step under its lock."""
 
     def __init__(self, pool: KVBlockPool, max_seqs: int, token_budget: int,
-                 max_pages_per_seq: int, policy: str = "continuous",
-                 drafter=None, num_draft_tokens: int = 0, obs=None,
+                 max_pages_per_seq: int, drafter=None,
+                 num_draft_tokens: int = 0, obs=None,
                  role: Optional[str] = None):
-        if policy not in ("continuous", "static"):
-            raise ValueError(f"unknown scheduling policy {policy!r}")
         if role not in (None, "prefill", "decode"):
             raise ValueError(
                 f"unknown engine role {role!r} (want prefill|decode|None)")
@@ -288,7 +281,6 @@ class Scheduler:
         self.max_seqs = int(max_seqs)
         self.token_budget = int(token_budget)
         self.max_pages_per_seq = int(max_pages_per_seq)
-        self.policy = policy
         self.drafter = drafter
         self.num_draft_tokens = int(num_draft_tokens)
         self._drafter_warned = False
@@ -544,16 +536,11 @@ class Scheduler:
             budget -= chunk
             prefill_tokens += chunk
 
-        # 3) admission, strictly FIFO. Static policy: gang admission into
-        #    an empty batch only (the BatchingServer baseline).
-        can_admit = not self.running if self.policy == "static" else True
+        # 3) admission, strictly FIFO
         stopped_by = None
         while self.waiting:
             if self.draining:
                 stopped_by = "drain"
-                break
-            if not can_admit:
-                stopped_by = "policy"
                 break
             if not self._free_slots:
                 stopped_by = "no_slot"

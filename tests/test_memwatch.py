@@ -24,6 +24,7 @@ pytestmark = pytest.mark.mem
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOOLS = os.path.join(REPO, "tools")
+EVIDENCE = os.path.join(REPO, "tests", "data", "evidence")
 sys.path.insert(0, TOOLS)
 
 import mem_report  # noqa: E402
@@ -32,12 +33,12 @@ import mem_report  # noqa: E402
 
 @pytest.fixture(scope="module")
 def repo_ledger(tmp_path_factory):
-    """An evidence ledger built from the artifacts the repo commits
-    (BENCH_SERVE_*, MEM_WATCH_*, AOT_STATS_*) — the repo commits no
-    ledger of its own."""
+    """An evidence ledger built from the recorded artifacts under
+    tests/data/evidence (BENCH_SERVE_*, MEM_WATCH_*, AOT_STATS_*) — the
+    repo commits no ledger of its own."""
     from paddle_tpu.profiler import evidence
     path = str(tmp_path_factory.mktemp("ledger") / "ledger.jsonl")
-    evidence.build_ledger(REPO, path)
+    evidence.build_ledger(EVIDENCE, path)
     return path
 
 
@@ -600,7 +601,7 @@ class TestEvidence:
     def test_committed_mem_artifact_in_ledger(self, repo_ledger):
         """The committed MEM_WATCH artifact ingests and its rows land in
         a ledger built from the repo (the --build round-trip)."""
-        paths = [p for p in evidence.scan_repo(REPO)
+        paths = [p for p in evidence.scan_repo(EVIDENCE)
                  if os.path.basename(p).startswith("MEM_WATCH_")]
         assert paths, "no committed MEM_WATCH artifact"
         rows, _ = evidence.read_rows(repo_ledger)
@@ -696,7 +697,7 @@ class TestAotMem:
         # artifacts WITHOUT a mem block keep their pre-mem row digest
         # (content-addressed ledger stability)
         fixture_rows = evidence.ingest_aot_stats(
-            os.path.join(REPO, "AOT_STATS_cpu_fixture.json"))
+            os.path.join(EVIDENCE, "AOT_STATS_cpu_fixture.json"))
         assert all("mem" not in r["data"] for r in fixture_rows)
         ids = {r["id"] for r in evidence.read_rows(repo_ledger)[0]}
         assert all(r["id"] in ids for r in fixture_rows)

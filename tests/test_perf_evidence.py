@@ -17,6 +17,7 @@ pytestmark = pytest.mark.perf
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOOLS = os.path.join(REPO, "tools")
+EVIDENCE = os.path.join(REPO, "tests", "data", "evidence")
 sys.path.insert(0, TOOLS)
 
 import perf_report  # noqa: E402
@@ -67,7 +68,7 @@ def _write_session(root):
                       ("BENCH_SESSION_r04.json", _SESSION_R04),
                       ("BENCH_r05.json", _BENCH_R05)):
         (root / name).write_text(json.dumps(doc))
-    shutil.copy(os.path.join(REPO, "AOT_STATS_cpu_fixture.json"), root)
+    shutil.copy(os.path.join(EVIDENCE, "AOT_STATS_cpu_fixture.json"), root)
 
 
 @pytest.fixture(scope="module")
@@ -90,7 +91,8 @@ class TestIngestion:
         ingestion is deterministic (content-addressed ids do not depend
         on mtime or ingest order)."""
         _write_session(tmp_path)
-        paths = evidence.scan_repo(REPO) + evidence.scan_repo(str(tmp_path))
+        paths = evidence.scan_repo(EVIDENCE) + \
+            evidence.scan_repo(str(tmp_path))
         names = {os.path.basename(p) for p in paths}
         for expected in ("PROBE_r04.json", "PROBE_LATEST.json",
                          "BENCH_SESSION_r04.json", "BENCH_r05.json",

@@ -200,9 +200,10 @@ def test_disarmed_engine_step_fault_escapes():
     chaos.install_plan(chaos.FaultPlan(seed=0).add(
         "serve.engine_step", "error", at=(1,)))
     try:
-        eng.submit(_prompts(1)[0], max_new_tokens=2)
+        req = eng.submit(_prompts(1)[0], max_new_tokens=2)
         with pytest.raises(chaos.FaultInjected):
             eng.step()
+        assert not req.done                   # parked: nobody requeues it
     finally:
         chaos.clear_plan()
 
@@ -549,7 +550,7 @@ def test_batching_server_survives_engine_fault():
         server.close()
 
 
-# -- chaos drill + bench (fast modes) ------------------------------------------
+# -- chaos drill (fast mode) ---------------------------------------------------
 
 def test_chaos_drill_serve_inprocess_deterministic():
     """The --serve drill's in-process phase, twice with one seed: the
@@ -585,29 +586,6 @@ def test_chaos_drill_serve_supervised_kill_restart_replay():
     assert rep["stable"]["manifest_requests"] > 0
     assert rep["stable"]["replay_crc"] == rep["stable"]["oracle_crc"]
     assert rep["supervised"]["generations"] == 2
-
-
-def test_bench_serve_chaos_fast_mode(tmp_path):
-    """tools/bench_serve.py --chaos fast row: the baseline wedges and
-    parks requests, the resilient engine parks none and protects
-    goodput (the committed BENCH_SERVE_r13.json carries the full-size
-    pair)."""
-    import importlib
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
-                                    "tools"))
-    bench_serve = importlib.import_module("bench_serve")
-    res = bench_serve.run_bench(fast=True, seed=0, chaos=True,
-                                out_path=str(tmp_path / "B.json"))
-    base, resi = res["chaos_baseline"], res["chaos_resilient"]
-    assert base["wedged"] and base["parked"] > 0
-    assert not resi["wedged"] and resi["parked"] == 0
-    assert resi["engine_step_faults"] >= 1      # the fault DID fire
-    assert resi["finished"] + resi["shed"] == resi["requests"]
-    assert resi["goodput_tokens"] > base["goodput_tokens"]
-    assert res["chaos_goodput_ratio"] > 1.0
-    assert res["chaos_workload"]["fault"]["site"] == "serve.engine_step"
 
 
 # -- disarmed-path overhead ----------------------------------------------------

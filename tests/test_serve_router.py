@@ -668,23 +668,9 @@ class TestObservability:
             metrics.reset_registry()
 
 
-# -- bench + drill fast modes (tier-1 floors) ---------------------------------
+# -- drill fast mode (tier-1) -------------------------------------------------
 
-class TestBenchAndDrill:
-    def test_bench_router_fast_floor(self):
-        """tools/bench_serve.py --router fast rows: the N=2 affinity
-        fleet beats the single engine on tokens/s, beats random routing
-        on prefix-hit economics (asserted in-run too), and every policy
-        delivered identical greedy output."""
-        import importlib
-        bench_serve = importlib.import_module("bench_serve")
-        rows = bench_serve.run_router_pair(seed=0, fast=True)
-        assert rows["router_vs_single"] > 1.0
-        assert rows["router_affinity"]["prefix_hit_token_rate"] > \
-            rows["router_random"]["prefix_hit_token_rate"]
-        assert rows["router_affinity"]["output_crc32"] == \
-            rows["router_single"]["output_crc32"]
-
+class TestDrill:
     def test_chaos_drill_router_stable_per_seed(self):
         """tools/chaos_drill.py --router: the replica-death drill runs
         green and its stable subset is bit-identical per seed."""

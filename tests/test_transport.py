@@ -593,14 +593,22 @@ def test_duplicate_import_rejected_at_the_engine():
     assert dec.kv_handoffs_in == 1
 
 
-def test_lossy_links_converge_to_faultfree_outputs():
+@pytest.mark.parametrize("transport, membership, fired", [
+    (True, True, ("dropped", "deduped")),
+    # no dedup window, no retransmit, no leases: the duplicate IS
+    # delivered, and what sits above the transport must absorb it
+    (TransportConfig(max_attempts=1, dedup_window=0), None,
+     ("duplicate",)),
+], ids=["full_stack", "no_dedup_no_lease"])
+def test_lossy_links_converge_to_faultfree_outputs(transport, membership,
+                                                   fired):
     chaos.install_plan(
         chaos.FaultPlan(seed=9)
         .add("transport.send", "error", "drop", prob=0.05)
         .add("transport.send", "error", "dup", prob=0.05)
         .add("transport.send", "delay", "1", prob=0.05))
     counts = {}
-    r = _fleet(transport=True, membership=True)
+    r = _fleet(transport=transport, membership=membership)
     handles = []
     for i, p in enumerate(_prompts(4)):
         counts[i] = 0
@@ -614,6 +622,8 @@ def test_lossy_links_converge_to_faultfree_outputs():
     # exactly-once token emission: no request ever decoded twice
     assert counts == {i: len(out[i]) for i in range(4)}
     assert r._pending_handoffs == [] and r._inflight == {}
+    # the plan had teeth: a lossy run that loses nothing proves nothing
+    assert all(r.transport.counters[k] > 0 for k in fired)
 
 
 def test_suspect_replica_gets_no_new_dispatch():
@@ -809,27 +819,6 @@ def test_lock_order_ranks_the_new_planes():
     assert LOCK_OWNERS["MembershipTable"] == "membership"
     assert LOCK_BEARERS["transport"] == "transport"
     assert LOCK_BEARERS["membership"] == "membership"
-
-
-# -- bench fast floor (tier-1) -------------------------------------------------
-def test_bench_lossy_fast_floor():
-    """tools/bench_serve.py --lossy fast rows: the full reliability
-    stack absorbs a 5% drop/dup/delay plan with zero parked or failed
-    requests, crc equal to the fault-free oracle, and SLO attainment
-    >= 0.95 — the no-dedup/no-lease baseline is the measured cost."""
-    import importlib
-    bench_serve = importlib.import_module("bench_serve")
-    rows = bench_serve.run_lossy_pair(seed=0, fast=True)
-    oracle, res = rows["lossy_faultfree"], rows["lossy_resilient"]
-    assert oracle["parked"] == 0 and oracle["failed"] == 0
-    assert oracle["transport"]["counters"]["retransmits"] == 0
-    assert res["parked"] == 0 and res["failed"] == 0
-    assert res["output_crc32"] == oracle["output_crc32"]
-    assert res["slo_attainment"] >= 0.95
-    dropped = res["transport"]["counters"]["dropped"]
-    deduped = res["transport"]["counters"]["deduped"]
-    assert dropped > 0 and deduped > 0
-    assert rows["lossy_naive"]["parked"] == 0
 
 
 def test_serve_top_renders_transport_panel():
