@@ -141,7 +141,9 @@ def phase_kernels(cfg, tiny):
         check(math.isfinite(err) and err <= KERNEL_REL_L2,
               f"{name}: relative L2 error {err:.4g} vs _reference_bhsd "
               f"exceeds {KERNEL_REL_L2} at shape {(b, h, s, d)} bf16 causal")
-    return {"shape": [b, h, s, d], "interpret": tiny, "rel_l2": errs}
+    # [block_q, block_k, grid steps of the call] each kernel sized for itself
+    return {"shape": [b, h, s, d], "interpret": tiny, "rel_l2": errs,
+            "tiles": {n: list(t) for n, t in fp.call_tiles(q, k).items()}}
 
 
 def build_model(cfg):

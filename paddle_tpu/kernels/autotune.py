@@ -147,25 +147,19 @@ def clear():
 def flash_block_candidates(sq: int, sk: int, head_dim: int,
                            itemsize: int = 2) -> List[tuple]:
     """(block_q, block_k) candidates for the flash kernels: 128-multiples
-    that divide the sequence lengths (Mosaic tiling constraint), VMEM-
-    bounded (q/k/v/o tiles + fp32 scores + fp32 accumulators must fit
-    well under the ~16 MiB/core budget so the pipeline can double-
-    buffer)."""
+    that divide the sequence lengths (Mosaic tiling constraint) and whose
+    forward working set fits the kernels' VMEM budget, by the one formula
+    the kernels size themselves with (flash_pallas.tile_vmem_bytes). The
+    untimed default comes first: what flash_pallas.choose_tiles gives
+    these shapes, so switching autotune off changes nothing."""
+    from . import flash_pallas as fp
     qs = [b for b in (128, 256, 512, 1024) if sq % b == 0] or [sq]
     ks = [b for b in (128, 256, 512, 1024) if sk % b == 0] or [sk]
-    out = []
-    for q in qs:
-        for k in ks:
-            tiles = (q + 3 * k) * head_dim * itemsize     # q + k/v/o tiles
-            scores = q * k * 4                            # fp32 s and p
-            acc = q * head_dim * 4 * 2                    # fp32 scratch
-            if 2 * (tiles + scores) + acc <= 10 * 2 ** 20:
-                out.append((q, k))
-    if not out:
-        out = [(min(qs), min(ks))]
-    # default-first: 128x128 is the safe MXU tile
-    out.sort(key=lambda c: (c != (128, 128), c))
-    return out
+    default = fp.choose_tiles("fwd", sq, sk, head_dim, itemsize)[:2]
+    out = [(q, k) for q in qs for k in ks
+           if fp.tile_vmem_bytes("fwd", q, k, head_dim, itemsize)
+           <= fp.VMEM_BUDGET_BYTES]
+    return [default] + sorted(c for c in out if c != default)
 
 
 __all__ = ["pick", "cached", "record", "clear", "set_cache_path",

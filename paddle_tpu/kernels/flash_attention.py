@@ -51,7 +51,8 @@ def tune_blocks(q_bshd, k_bshd, v_bshd, causal: bool = False, scale=None):
     sq, sk, d = q_bshd.shape[1], k_bshd.shape[1], q_bshd.shape[3]
     sig = _tune_signature(q_bshd, k_bshd, causal)
     return autotune.pick(
-        "flash_fwd", sig, autotune.flash_block_candidates(sq, sk, d),
+        "flash_fwd", sig,
+        autotune.flash_block_candidates(sq, sk, d, q_bshd.dtype.itemsize),
         lambda c: flash_attention_bshd(q_bshd, k_bshd, v_bshd, causal=causal,
                                        scale=scale, block_q=c[0],
                                        block_k=c[1]))

@@ -20,11 +20,19 @@ MXU notes: all dots keep the input dtype (bf16 stays bf16) and accumulate in
 fp32 via preferred_element_type — casting inputs to fp32 first would run the
 MXU at a fraction of its bf16 rate. Probabilities are cast back to the value
 dtype before the p@v / p^T@dO dots for the same reason.
+
+Tiles: a grid step covers up to 1024 x 1024 of the score matrix. Each kernel
+sizes (block_q, block_k) for itself from the call's lengths, head size and
+item size (``choose_tiles``: the largest that divide the lengths and fit a
+VMEM budget), because on a v5e the time of a call on small tiles is the
+number of grid steps and not the arithmetic; an explicit block size and an
+autotuned one come first (``_resolve_blocks``).
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -33,8 +41,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.jax_compat import tpu_compiler_params as _tpu_compiler_params
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
 # Row statistics (lse, delta) are stored broadcast over a trailing lane dim:
 # Pallas TPU requires the last two block dims to be (8, 128)-divisible or
@@ -44,6 +50,88 @@ NEG_INF = -1e30
 LANES = 8
 
 _INTERPRET = False  # tests flip this to run the kernels off-TPU
+
+# -- tiles --------------------------------------------------------------------
+# On 128 x 128 tiles a call at [128, 2048, 128] is 32,768 grid steps and takes
+# 11-14 ms in each kernel, for 0.7-1.4 ms of arithmetic; at 1024 x 1024 it is
+# 512 steps and 1.7-2.9 ms (PERF.md section 6, PR 32: the ladder). So the tiles
+# are as large as VMEM allows, not the 128 x 128 the MXU would be content
+# with, even where a causal diagonal then runs through a large tile.
+KERNELS = ("fwd", "dq", "dkv")
+VMEM_BUDGET_BYTES = 20 * 2 ** 20    # what choose_tiles lets a grid step hold
+VMEM_LIMIT_BYTES = 96 * 2 ** 20     # Mosaic's scoped limit for these calls
+MAX_BLOCK = 1024    # no rung of the ladder longer than this on a side won
+_ROW_BYTES = 128 * 4    # a [rows, 1] or [rows, LANES] float32 block in VMEM
+
+
+class Tiles(NamedTuple):
+    block_q: int
+    block_k: int
+    grid_steps: int     # of the whole call: batch*heads x q blocks x kv blocks
+
+
+def tile_vmem_bytes(kernel: str, bq: int, bk: int, head_dim: int,
+                    itemsize: int) -> int:
+    """Bytes one grid step of ``kernel`` keeps in VMEM at tiles (bq, bk):
+    its operand and result blocks twice (the pipeline double-buffers them),
+    the float32 score-sized temporaries, the float32 accumulators. The
+    three kernels hold different things, so each is sized for itself."""
+    if kernel == "fwd":      # q, o | k, v | lse; acc, m, l; s, p
+        blocks = (2 * bq + 2 * bk) * head_dim * itemsize + bq * _ROW_BYTES
+        kept = bq * head_dim * 4 + 2 * bq * _ROW_BYTES
+        scores = 2
+    elif kernel == "dq":     # q, dO, dq | k, v | lse, delta; acc; p, dp, ds
+        blocks = (3 * bq + 2 * bk) * head_dim * itemsize \
+            + 2 * bq * _ROW_BYTES
+        kept = bq * head_dim * 4
+        scores = 3
+    elif kernel == "dkv":    # q, dO | k, v, dk, dv | lse, delta; two accs
+        blocks = (2 * bq + 4 * bk) * head_dim * itemsize \
+            + 2 * bq * _ROW_BYTES
+        kept = 2 * bk * head_dim * 4
+        scores = 3           # p, dp, ds
+    else:
+        raise ValueError(f"kernel {kernel!r} is not one of {KERNELS}")
+    return 2 * blocks + kept + scores * bq * bk * 4
+
+
+def _block_options(length: int) -> list:
+    """Multiples of 128 up to MAX_BLOCK that divide ``length``; a length
+    that has none is one block if it is short, else 128 (which the launcher
+    refuses)."""
+    return [b for b in range(128, min(length, MAX_BLOCK) + 1, 128)
+            if length % b == 0] or [min(length, 128)]
+
+
+def choose_tiles(kernel: str, sq: int, sk: int, head_dim: int, itemsize: int,
+                 batch_heads: int = 1) -> Tiles:
+    """The (block_q, block_k) a call runs on when nobody pinned or tuned
+    them, and the grid steps that makes: the tiles of the largest area, up
+    to MAX_BLOCK a side, that divide the lengths and whose working set fits
+    VMEM_BUDGET_BYTES; of equal areas the squarer, then the one longer in
+    keys (all three kernels lose more to a short block_k than to a short
+    block_q). Whether the call is causal is not asked: a diagonal through a
+    1024-wide tile costs less than the steps that smaller tiles add."""
+    *_, bk, bq = max(
+        (bq * bk, min(bq, bk), bk, bq)
+        for bq in _block_options(sq) for bk in _block_options(sk)
+        if tile_vmem_bytes(kernel, bq, bk, head_dim, itemsize)
+        <= VMEM_BUDGET_BYTES)
+    return Tiles(bq, bk, batch_heads * -(-sq // bq) * -(-sk // bk))
+
+
+def call_tiles(q, k) -> dict:
+    """{kernel: Tiles} of a call on q, k [batch, heads, seq, head_dim] that
+    pins and tunes nothing: what the tools print beside their timings."""
+    b, h, sq, d = q.shape
+    return {kernel: choose_tiles(kernel, sq, k.shape[2], d, q.dtype.itemsize,
+                                 batch_heads=b * h) for kernel in KERNELS}
+
+
+def _compiler_params():
+    return _tpu_compiler_params(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _dot(a, b, dims):
@@ -98,11 +186,8 @@ def _flashmask_visible(iq, ik, block_q, block_k, bounds, causal, window):
 
 def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
                nk, offset, masked=False, window=None):
-    if masked:
-        bounds_ref, o_ref, lse_ref, m_scratch, l_scratch, acc_scratch = rest
-    else:
-        bounds_ref = None
-        o_ref, lse_ref, m_scratch, l_scratch, acc_scratch = rest
+    bounds_ref = rest[0] if masked else None
+    o_ref, lse_ref, m_scratch, l_scratch, acc_scratch = rest[masked:]
     ik = pl.program_id(2)
     iq = pl.program_id(1)
 
@@ -116,7 +201,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
         q = q_ref[0]                                 # [Bq, d] (input dtype)
         k = k_ref[0]                                 # [Bk, d]
         v = v_ref[0]                                 # [Bk, d]
-        s = _dot(q, k, (((1,), (1,)))) * scale       # [Bq, Bk] fp32
+        s = _dot(q, k, ((1,), (1,))) * scale         # [Bq, Bk] fp32
         if vis is not None:
             s = jnp.where(vis, s, NEG_INF)
         elif causal and apply_causal:
@@ -251,8 +336,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, bounds=None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(),
         interpret=_INTERPRET,
     )(*inputs)
     return out.reshape(b, h, sq, d), lse
@@ -263,11 +347,8 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, bounds=None,
 def _fa_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, *rest, scale,
                   causal, block_q, block_k, nk, offset, masked=False,
                   window=None):
-    if masked:
-        bounds_ref, dq_ref, acc_scratch = rest
-    else:
-        bounds_ref = None
-        dq_ref, acc_scratch = rest
+    bounds_ref = rest[0] if masked else None
+    dq_ref, acc_scratch = rest[masked:]
     ik = pl.program_id(2)
     iq = pl.program_id(1)
 
@@ -322,11 +403,8 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, *rest, scale,
 def _fa_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, *rest,
                    scale, causal, block_q, block_k, nq, offset, masked=False,
                    window=None):
-    if masked:
-        bounds_ref, dk_ref, dv_ref, dk_scratch, dv_scratch = rest
-    else:
-        bounds_ref = None
-        dk_ref, dv_ref, dk_scratch, dv_scratch = rest
+    bounds_ref = rest[0] if masked else None
+    dk_ref, dv_ref, dk_scratch, dv_scratch = rest[masked:]
     iq = pl.program_id(2)
     ik = pl.program_id(1)
 
@@ -385,27 +463,41 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, *rest,
         dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
-                    bounds=None, window=None):
+def _flash_backward(q, k, v, out, lse, g, causal, scale, dq_blocks,
+                    dkv_blocks, bounds=None, window=None):
+    """dq and dk/dv from the saved lse: two kernels, each on its own tiles
+    (``dq_blocks`` and ``dkv_blocks`` are (block_q, block_k) pairs)."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    bh = b * h
+    rows = (q.reshape(bh, sq, d), k.reshape(bh, sk, d), v.reshape(bh, sk, d),
+            g.reshape(bh, sq, d))
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).reshape(bh, sq)
+    delta = jnp.broadcast_to(delta[:, :, None], (bh, sq, LANES))
+    s = scale if scale is not None else 1.0 / math.sqrt(d)
+    bounds_r = None if bounds is None else \
+        jnp.swapaxes(bounds.reshape(bh, sk, 4), 1, 2)
+    dq = _flash_dq(*rows, lse, delta, bounds_r, causal, s, window,
+                   *dq_blocks)
+    dk, dv = _flash_dkv(*rows, lse, delta, bounds_r, causal, s, window,
+                        *dkv_blocks)
+    return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
+            dv.reshape(b, h, sk, d))
+
+
+def _flash_dq(q_r, k_r, v_r, g_r, lse, delta, bounds_r, causal, scale,
+              window, block_q, block_k):
+    """[bh, s, d] operands, grid (bh, q blocks, kv blocks): a dq tile
+    accumulates in VMEM while the kv blocks sweep past it."""
+    bh, sq, d = q_r.shape
+    sk = k_r.shape[1]
     bq = min(block_q, sq)
     bk = min(block_k, sk)
     _check_divisible(sq, sk, bq, bk, causal)
     nq = sq // bq
     nk = sk // bk
-    bh = b * h
-    s = scale if scale is not None else 1.0 / math.sqrt(d)
-    masked = bounds is not None
-
-    q_r = q.reshape(bh, sq, d)
-    k_r = k.reshape(bh, sk, d)
-    v_r = v.reshape(bh, sk, d)
-    g_r = g.reshape(bh, sq, d)
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(bh, sq)
-    delta = jnp.broadcast_to(delta[:, :, None], (bh, sq, LANES))
-
+    masked = bounds_r is not None
     offset = sk - sq
     q_spec = pl.BlockSpec((1, bq, d), lambda ibh, i, j: (ibh, i, 0))
     row_spec = pl.BlockSpec((1, bq, LANES), lambda ibh, i, j: (ibh, i, 0))
@@ -434,24 +526,36 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
             kidx = kv_idx_dq(ibh, iq, ik)
             return (kidx[0], 0, kidx[1])
 
-        bounds_r = jnp.swapaxes(bounds.reshape(bh, sk, 4), 1, 2)
         dq_inputs.append(bounds_r)
         dq_in_specs.append(pl.BlockSpec((1, 4, bk), bounds_idx_dq))
-    dq = pl.pallas_call(
-        functools.partial(_fa_dq_kernel, scale=s, causal=causal, block_q=bq,
-                          block_k=bk, nk=nk, offset=sk - sq, masked=masked,
-                          window=window),
+    return pl.pallas_call(
+        functools.partial(_fa_dq_kernel, scale=scale, causal=causal,
+                          block_q=bq, block_k=bk, nk=nk, offset=offset,
+                          masked=masked, window=window),
         name="flash_dq",
         grid=(bh, nq, nk),
         in_specs=dq_in_specs,
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q_r.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(),
         interpret=_INTERPRET,
     )(*dq_inputs)
 
+
+def _flash_dkv(q_r, k_r, v_r, g_r, lse, delta, bounds_r, causal, scale,
+               window, block_q, block_k):
+    """[bh, s, d] operands, grid (bh, kv blocks, q blocks): a dk and a dv
+    tile accumulate in VMEM while the q blocks sweep past them."""
+    bh, sq, d = q_r.shape
+    sk = k_r.shape[1]
+    bq = min(block_q, sq)
+    bk = min(block_k, sk)
+    _check_divisible(sq, sk, bq, bk, causal)
+    nq = sq // bq
+    nk = sk // bk
+    masked = bounds_r is not None
+    offset = sk - sq
     kv_spec = pl.BlockSpec((1, bk, d), lambda ibh, ik, iq: (ibh, ik, 0))
     if causal:
         # mirror of the dq clamp (safe for flashmask for the same
@@ -477,27 +581,23 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         dkv_inputs.append(bounds_r)
         dkv_in_specs.append(
             pl.BlockSpec((1, 4, bk), lambda ibh, ik, iq: (ibh, 0, ik)))
-    dk, dv = pl.pallas_call(
-        functools.partial(_fa_dkv_kernel, scale=s, causal=causal, block_q=bq,
-                          block_k=bk, nq=nq, offset=sk - sq, masked=masked,
-                          window=window),
+    return pl.pallas_call(
+        functools.partial(_fa_dkv_kernel, scale=scale, causal=causal,
+                          block_q=bq, block_k=bk, nq=nq, offset=offset,
+                          masked=masked, window=window),
         name="flash_dkv",
         grid=(bh, nk, nq),
         in_specs=dkv_in_specs,
         out_specs=[kv_spec, kv_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, sk, d), k_r.dtype),
+            jax.ShapeDtypeStruct((bh, sk, d), v_r.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(),
         interpret=_INTERPRET,
     )(*dkv_inputs)
-
-    return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
-            dv.reshape(b, h, sk, d))
 
 
 # -- public op ----------------------------------------------------------------
@@ -516,54 +616,65 @@ def _reference_bhsd(q, k, v, causal, scale):
         .astype(q.dtype)
 
 
-def _resolve_blocks(which: str, q, k, causal, block_q, block_k):
-    """None block sizes resolve through the autotune cache (in-process or
-    its disk file), else the static defaults — so a
-    hardware-tuned decision reaches every call site without threading
-    config (reference switch_autotune cache role)."""
+def _resolve_blocks(which: str, kernel: str, q, k, causal, block_q,
+                    block_k):
+    """(block_q, block_k) of one kernel of a call: what the caller pinned,
+    else the autotune cache's winner for ``which`` (in-process or its disk
+    file), else ``choose_tiles`` for ``kernel`` — so a hardware-tuned
+    decision reaches every call site without threading config (reference
+    switch_autotune cache role)."""
     if block_q is not None and block_k is not None:
         return block_q, block_k
     from . import autotune
     sig = (q.shape[2], k.shape[2], q.shape[3], str(q.dtype), bool(causal))
     # fallback chain: flashmask inherits the dense-causal winner (same
     # tile geometry), and an untuned backward inherits the forward's
-    # blocks (runtime tune_blocks only times the forward) — 128x128 only
-    # when nothing was ever tuned
+    # blocks (runtime tune_blocks only times the forward); the shapes
+    # decide only when nothing was ever tuned
     chain = {"flashmask_fwd": ("flashmask_fwd", "flash_fwd"),
              "flashmask_bwd": ("flashmask_bwd", "flash_bwd", "flash_fwd"),
              "flash_bwd": ("flash_bwd", "flash_fwd")}.get(which, (which,))
-    hit = None
     for key in chain:
         hit = autotune.cached(key, sig)
         if hit is not None:
+            bq, bk = hit
             break
-    if hit is not None:
-        bq, bk = hit
     else:
-        bq, bk = DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
+        bq, bk, _ = choose_tiles(kernel, *sig[:3], q.dtype.itemsize)
     return (block_q or bq), (block_k or bk)
+
+
+def _forward(which, q, k, v, causal, scale, block_q, block_k, **mask):
+    bq, bk = _resolve_blocks(which, "fwd", q, k, causal, block_q, block_k)
+    return _flash_forward(q, k, v, causal, scale, bq, bk, **mask)
+
+
+def _backward(which, q, k, v, out, lse, g, causal, scale, block_q, block_k,
+              **mask):
+    dq_blocks, dkv_blocks = (
+        _resolve_blocks(which, kernel, q, k, causal, block_q, block_k)
+        for kernel in ("dq", "dkv"))
+    return _flash_backward(q, k, v, out, lse, g, causal, scale, dq_blocks,
+                           dkv_blocks, **mask)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=None, block_k=None):
     """q,k,v: [batch, heads, seq, head_dim]. block_q/block_k None =
-    autotune-cached (or the 128x128 default)."""
-    bq, bk = _resolve_blocks("flash_fwd", q, k, causal, block_q, block_k)
-    out, _ = _flash_forward(q, k, v, causal, scale, bq, bk)
-    return out
+    autotune-cached, else sized from the shapes (``choose_tiles``)."""
+    return _forward("flash_fwd", q, k, v, causal, scale, block_q,
+                    block_k)[0]
 
 
 def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
-    bq, bk = _resolve_blocks("flash_fwd", q, k, causal, block_q, block_k)
-    out, lse = _flash_forward(q, k, v, causal, scale, bq, bk)
+    out, lse = _forward("flash_fwd", q, k, v, causal, scale, block_q,
+                        block_k)
     return out, (q, k, v, out, lse)
 
 
 def _fa_bwd(causal, scale, block_q, block_k, res, g):
-    q, k, v, out, lse = res
-    bq, bk = _resolve_blocks("flash_bwd", q, k, causal, block_q, block_k)
-    return _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bk)
+    return _backward("flash_bwd", *res, g, causal, scale, block_q, block_k)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
@@ -579,28 +690,21 @@ def flashmask_attention(q, k, v, bounds, causal=False, scale=None,
     bounds (see _flashmask_visible). The sparse mask costs O(seq) memory and
     fully-masked tiles skip the MXU — the capability of the reference's
     flashmask_attention (flash_attention.py:1299) without a dense mask."""
-    bq, bk = _resolve_blocks("flashmask_fwd", q, k, causal, block_q,
-                             block_k)
-    out, _ = _flash_forward(q, k, v, causal, scale, bq, bk,
-                            bounds=bounds, window=window)
-    return out
+    return _forward("flashmask_fwd", q, k, v, causal, scale, block_q,
+                    block_k, bounds=bounds, window=window)[0]
 
 
 def _fm_fwd(q, k, v, bounds, causal, scale, window, block_q, block_k):
-    bq, bk = _resolve_blocks("flashmask_fwd", q, k, causal, block_q,
-                             block_k)
-    out, lse = _flash_forward(q, k, v, causal, scale, bq, bk,
-                              bounds=bounds, window=window)
+    out, lse = _forward("flashmask_fwd", q, k, v, causal, scale, block_q,
+                        block_k, bounds=bounds, window=window)
     return out, (q, k, v, bounds, out, lse)
 
 
 def _fm_bwd(causal, scale, window, block_q, block_k, res, g):
     q, k, v, bounds, out, lse = res
-    bq, bk = _resolve_blocks("flashmask_bwd", q, k, causal, block_q,
-                             block_k)
-    dq, dk, dv = _flash_backward(q, k, v, out, lse, g, causal, scale,
-                                 bq, bk, bounds=bounds,
-                                 window=window)
+    dq, dk, dv = _backward("flashmask_bwd", q, k, v, out, lse, g, causal,
+                           scale, block_q, block_k, bounds=bounds,
+                           window=window)
     return dq, dk, dv, None
 
 

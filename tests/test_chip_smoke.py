@@ -198,3 +198,34 @@ def test_paged_attention_compiles_at_the_serving_cells_geometries(
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "paged_attention" in text
     assert compiled.memory_analysis().output_size_in_bytes == rows * kvh * rep * 256
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv"])
+def test_flash_kernels_compile_at_the_training_cells_shape(one_chip, kernel):
+    """Mosaic accepts each flash kernel at cgpt13-train-2k's attention,
+    [8 x 16, 2048, 128] bfloat16 causal, on the tiles the call sizes for
+    itself and under the scoped VMEM limit it asks for; and the call keeps
+    the name the benchmark's rooflines find it by."""
+    import jax.numpy as jnp
+    from paddle_tpu.kernels import flash_pallas as fp
+
+    bh, s, d = 128, 2048, 128
+    tiles = fp.choose_tiles(kernel[len("flash_"):], s, s, d, 2,
+                            batch_heads=bh)
+    assert tiles.grid_steps <= 2048
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows, stats = shape((bh, s, d)), shape((bh, s, fp.LANES), jnp.float32)
+    if kernel == "flash_fwd":
+        fn = lambda q, k, v: fp._flash_forward(  # noqa: E731
+            q, k, v, True, None, *tiles[:2])
+        args = (shape((8, 16, s, d)),) * 3
+    else:
+        launch = fp._flash_dq if kernel == "flash_dq" else fp._flash_dkv
+        fn = lambda *a: launch(*a, None, True, d ** -0.5,  # noqa: E731
+                               None, *tiles[:2])
+        args = (rows,) * 4 + (stats,) * 2
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and kernel in text

@@ -103,6 +103,38 @@ def test_kernel_gradients_match_dense_oracle():
                                    rtol=2e-4, err_msg=f"d{name}")
 
 
+@pytest.mark.parametrize("block_q,block_k", [(256, 512), (512, 256),
+                                             (512, 512), (None, None)],
+                         ids=["256x512", "512x256", "512x512", "default"])
+def test_large_tiles_with_documents_inside_a_tile(block_q, block_k):
+    """Documents of 64 and 192 tokens under tiles of 256 and 512: a tile
+    holds several documents, so the block skip is coarser than the mask and
+    the mask inside the tile does the rest. out, dq, dk, dv against the
+    dense oracle; the last case takes the tiles the call sizes itself."""
+    b, h, s, d = 1, 1, 512, 64
+    q, k, v = _rand_bhsd(b, h, s, d, seed=4)
+    j = np.arange(s)
+    doc_end = np.where(j < 256, (j // 64 + 1) * 64,
+                       np.minimum(256 + ((j - 256) // 192 + 1) * 192, s))
+    se = jnp.asarray(np.broadcast_to(
+        doc_end.astype(np.int32)[None, None, :, None], (b, h, s, 1)))
+    bounds = _canonical_startend(se, s, True)
+    visible = _flashmask_dense_visible(bounds, s, s, True, None)
+    w = jnp.cos(jnp.arange(d, dtype=jnp.float32))
+
+    def run(attention):
+        out, vjp = jax.vjp(attention, q, k, v)
+        return (out, *vjp(jnp.broadcast_to(w, out.shape)))
+
+    got = run(lambda q, k, v: fp.flashmask_attention(
+        q, k, v, bounds, True, None, None, block_q, block_k))
+    want = run(lambda q, k, v: _oracle_bhsd(q, k, v, visible))
+    for a, b_, name, tol in zip(got, want, ("out", "dq", "dk", "dv"),
+                                (2e-5, 2e-4, 2e-4, 2e-4)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=tol,
+                                   rtol=tol, err_msg=name)
+
+
 def test_fully_masked_rows_produce_zero_output():
     # a column band masking every off-diagonal row still leaves the diagonal
     # visible; but a window of 0 keys with causal band from row 0 masks rows

@@ -3,12 +3,13 @@
 
 One process on a TPU; arguments, if any, are prefixes of the kernels' names
 to keep (``paged_attention``). Every Pallas kernel in
-``paddle_tpu/kernels`` that ``chip_smoke.py`` does not already gate (it
-checks the default-path flash forward and backward) is run once, compiled
-(never interpreted), at one production shape, against the jnp
-implementation the tests use as its oracle. They sit behind a flag that is
-off (``FLAGS_use_pallas_fused``) or behind an explicit MoE/packing option,
-and this survey is the record of which of them the installed Mosaic accepts
+``paddle_tpu/kernels`` is run once, compiled (never interpreted), at one
+production shape, against the jnp implementation the tests use as its
+oracle (``chip_smoke.py`` gates the default-path flash forward and backward
+at a smaller batch; here they run at the training cell's shape, and the
+row names the tiles and grid steps each kernel took). The others sit behind
+a flag that is off (``FLAGS_use_pallas_fused``) or behind an explicit
+MoE/packing option, and this survey is the record of which of them the installed Mosaic accepts
 (ROADMAP S7, D2). It turns no flag on. The serving step's paged attention
 kernel is on the engine's path on one chip; it is here at the serving
 cells' geometries against the gather-based reference.
@@ -62,8 +63,32 @@ def out_and_grads(fn, args, cotangent):
     return jax.jit(run)(args, cotangent)
 
 
+def flash_tiles(q, k):
+    """{kernel: [block_q, block_k, grid steps]} the flash kernels run this
+    call on when nothing is pinned or tuned."""
+    from paddle_tpu.kernels import flash_pallas as fp
+    return {"tiles": {n: list(t) for n, t in fp.call_tiles(q, k).items()}}
+
+
 # Each case returns its shape and {output name: (kernel result, oracle
-# result)}.
+# result)}; a third item, if any, is merged into the case's printed row.
+def case_flash_attention():
+    """Plain causal attention at cgpt13-train-2k's shape, default tiles."""
+    from paddle_tpu.kernels import flash_pallas as fp
+    shape = (8, 16, 2048, 128)
+    q, k, v, g = (rand(i, shape) for i in range(4))
+    got = out_and_grads(lambda q, k, v: fp.flash_attention(q, k, v, True),
+                        (q, k, v), g)
+    # the oracle holds [batch, 16, 2048, 2048] float32 scores and their
+    # gradients: half the batch at a time
+    halves = [out_and_grads(
+        lambda q, k, v: fp._reference_bhsd(q, k, v, True, None),
+        tuple(t[i:i + 4] for t in (q, k, v)), g[i:i + 4]) for i in (0, 4)]
+    want = [jnp.concatenate(pair) for pair in zip(*halves)]
+    return shape, dict(zip(("out", "dq", "dk", "dv"), zip(got, want))), \
+        flash_tiles(q, k)
+
+
 def case_flashmask():
     """Packed documents of 256 tokens: causal within a document."""
     from paddle_tpu.kernels import flash_pallas as fp
@@ -90,7 +115,7 @@ def case_flashmask():
         (q, k, v), g)
     want = out_and_grads(oracle, (q, k, v), g)
     return (b, h, s, d), dict(zip(("out", "dq", "dk", "dv"),
-                                  zip(got, want)))
+                                  zip(got, want))), flash_tiles(q, k)
 
 
 def case_fused_rope():
@@ -234,6 +259,8 @@ def case_paged_attention(cell):
 
 
 CASES = (
+    ("flash_attention", "F.scaled_dot_product_attention on a TPU",
+     case_flash_attention, REL_L2),
     ("flashmask", "attn_startend_row_indices", case_flashmask, REL_L2),
     ("fused_rope", "FLAGS_use_pallas_fused", case_fused_rope, REL_L2),
     ("fused_rms_norm", "FLAGS_use_pallas_fused", case_fused_rms_norm,
@@ -261,7 +288,8 @@ def main() -> int:
         row = {"kernel": name, "reached_by": reached_by}
         t0 = time.perf_counter()
         try:
-            shape, pairs = fn()
+            shape, pairs, *extra = fn()
+            row.update(*extra)
             errs = {k: round(rel_l2(a, b), 6) for k, (a, b) in pairs.items()}
             row.update(shape=shape, rel_l2=errs, tolerance=tol)
             bad = {k: e for k, e in errs.items() if not e <= tol}
