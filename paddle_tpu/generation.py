@@ -173,8 +173,11 @@ def _join_entries(k_pools, v_pools):
     D] each, entry ``e`` at pages ``e * P ..`` (no element moves): the form
     every ragged step threads through its layers, writes in place and
     attends from."""
-    joined = (-1,) + k_pools.shape[2:]
-    return k_pools.reshape(joined), v_pools.reshape(joined)
+    return _join_entry_pages(k_pools), _join_entry_pages(v_pools)
+
+
+def _join_entry_pages(pools):
+    return pools.reshape((pools.shape[0] * pools.shape[1],) + pools.shape[2:])
 
 
 @jax.named_scope("kv_write")
@@ -227,16 +230,17 @@ def _kv_write_pages(kf, vf, k, v, scatter):
     dropped row; ``_page_plan``'s hit and src). Scope ``kv_write``: with
     ``_join_entries``, ``_page_plan`` and ``_entry_seams``' targets the
     whole cost of keeping the pools, whatever the attention reads."""
+    return _write_pages(kf, k, scatter), _write_pages(vf, v, scatter)
+
+
+def _write_pages(pool, new, scatter):
+    """One pool's half of ``_kv_write_pages`` (a latent pool is one)."""
     pages, hit, src = scatter
-    at = jnp.minimum(pages, kf.shape[0] - 1)
-
-    def write(pool, new):
-        rows = new[:, 0][src].transpose(0, 2, 1, 3)      # [T, kvh, bs, D]
-        page = jnp.where(hit[:, None, :, None], rows.astype(pool.dtype),
-                         pool[at])
-        return pool.at[pages].set(page, mode="drop")
-
-    return write(kf, k), write(vf, v)
+    at = jnp.minimum(pages, pool.shape[0] - 1)
+    rows = new[:, 0][src].transpose(0, 2, 1, 3)          # [T, kvh, bs, D]
+    page = jnp.where(hit[:, None, :, None], rows.astype(pool.dtype),
+                     pool[at])
+    return pool.at[pages].set(page, mode="drop")
 
 
 @jax.named_scope("kv_write")
@@ -255,12 +259,19 @@ class _LlamaDecoder:
     pin superseded arrays, and weight updates need no cache invalidation.
     """
 
+    # what the serving attention takes besides the heads: the scores' scale
+    # (None: hd ** -0.5) and the value's width inside a latent cache's row
+    # (None: K and V pages per head)
+    attn_scale = None
+    latent_dim = None
+
     def __init__(self, model):
         cfg = model.config
         self.cfg = cfg
         self.n_heads = cfg.num_attention_heads
         self.n_kv = cfg.num_key_value_heads or self.n_heads
         self.hd = cfg.hidden_size // self.n_heads
+        self.v_dim = self.hd      # a V row's width; 0 where no V is kept
         self.eps = cfg.rms_norm_eps
         # two numbers: layers that hold weights, and K/V cache entries a
         # token keeps (what the caches' and the pools' first axis counts).
@@ -269,6 +280,11 @@ class _LlamaDecoder:
         self.cache_entries = self.n_layers
         self.tied = model.lm_head is None
         self.embed_key = "model.embed_tokens.weight"
+
+    def describe(self):
+        """What ``telemetry()["model"]`` says of this decoder beside its
+        layers and cache entries: nothing here."""
+        return {}
 
     def _static_key(self):
         """Everything the traced step() reads off `self` — two decoders
@@ -504,6 +520,12 @@ class _OuroDecoder(_LlamaDecoder):
         ``input_layernorm_2``, ``post_attention_layernorm_2``."""
         return _rms(x, self._lw(w, i, norm + "_2.weight"), self.eps)
 
+    def step_counts(self, passes, rows):
+        """``serve.emit``'s arguments from what a step brought back beside
+        its tokens: the pass each sampled row left after."""
+        return {"exit_pass_sum": int(passes[rows].sum()),
+                "exit_rows": len(rows)}
+
     @jax.named_scope("loop_exit")
     def _pass_end(self, w, h):
         """``model.norm`` on a pass's output: what the next pass starts
@@ -574,6 +596,241 @@ class _OuroDecoder(_LlamaDecoder):
         return self._logits(w, self._exit(w, states)[0]), kcs, vcs
 
 
+class _LongcatDecoder(_LlamaDecoder):
+    """Pure functions over a LongcatFlashForCausalLM state dict
+    (``models/longcat_flash.py`` has the equations): a DOUBLE layer with two
+    latent-attention blocks, two dense FFNs and one expert layer whose
+    output skips over the second half.
+
+    The cache is a LATENT one: a token keeps, for each of the ``2 *
+    n_layers`` attention blocks, one row of ``row_dim`` numbers: the normed,
+    rescaled latent (``latent_dim``), the roped key all heads share, and
+    zeros up to whole lane tiles (512 + 64 -> 640). There is no V cache
+    (``v_dim`` 0: the pools' and caches' V side is zero wide): the value is
+    the row's first ``latent_dim`` columns. Attention runs ABSORBED, in the
+    prefill rows of a step as in its decode rows: ``kv_b_proj``'s key part is
+    folded into the query and its value part into the output, so every head
+    attends to the one row (``n_kv`` 1).
+
+    The expert layer routes in float32 over every output of the router,
+    sorts the (token, expert) pairs that fall on the held experts into tiles
+    of one expert each (``kernels.grouped_experts_pallas``), runs one grouped
+    product over them at shapes fixed by the number of rows alone, and
+    gathers each token's weighted results back; the zero experts are one
+    multiply by their summed weights. Nothing is dropped, whatever the
+    routing. The step's routing counts come back beside the sampled tokens
+    (``COUNTERS``)."""
+
+    # what step_ragged returns beside the logits, summed over the layers
+    COUNTERS = ("moe_pairs", "moe_pairs_held", "moe_pairs_zero",
+                "moe_peak_tokens", "moe_experts_touched")
+
+    def __init__(self, model):
+        cfg = model.config
+        self.cfg = cfg
+        self.n_heads = cfg.num_attention_heads
+        self.n_kv = 1
+        self.latent_dim = cfg.kv_lora_rank
+        self.rope_dim = cfg.qk_rope_head_dim
+        self.hd = -(-(self.latent_dim + self.rope_dim) // 128) * 128
+        self.v_dim = 0
+        self.attn_scale = cfg.attn_scale
+        self.eps = cfg.rms_norm_eps
+        self.n_layers = cfg.num_layers
+        self.cache_entries = 2 * self.n_layers
+        self.tied = False
+        self.embed_key = "model.embed_tokens.weight"
+
+    def _static_key(self):
+        import dataclasses
+        return (type(self), dataclasses.astuple(self.cfg))
+
+    def describe(self):
+        """What ``telemetry()["model"]`` adds for this decoder."""
+        c = self.cfg
+        return {"cache": "latent", "latent_row": self.latent_dim
+                + self.rope_dim, "latent_row_padded": self.hd,
+                "experts_held": c.experts_held,
+                "experts_published": c.n_routed_experts,
+                "zero_experts": c.zero_expert_num, "experts_a_token":
+                c.moe_topk}
+
+    def step_counts(self, beside, rows):
+        """``serve.emit``'s arguments from the counters a step brought
+        back."""
+        out = {k: int(v) for k, v in zip(self.COUNTERS, beside)}
+        out["moe_held_mean_tokens"] = out["moe_pairs_held"] \
+            / self.cfg.experts_held
+        return out
+
+    def quant_plan(self):
+        raise NotImplementedError(
+            "weight-only quantization is not offered for LongCat-Flash")
+
+    def tp_specs(self):
+        """Replicated: expert sharding over a mesh is not offered yet."""
+        return {}
+
+    # -- pieces ---------------------------------------------------------------
+    def _block(self, w, i, j):
+        """MLA block ``j`` of layer ``i``: its leaves by their short names."""
+        from .models.longcat_flash import LongcatFlashMLA
+        pre = f"model.layers.{i}.self_attn.{j}."
+        return {n: w[pre + n] for n in LongcatFlashMLA.NAMES}
+
+    def _norm(self, w, i, name, j, x):
+        return _rms(x, w[f"model.layers.{i}.{name}.{j}.weight"], self.eps)
+
+    def _mla_rows(self, w, i, j, x, cos, sin):
+        """x: [..., hidden] -> (q [..., heads, row_dim]: the absorbed query
+        beside the roped one; row [..., row_dim]: what the token keeps)."""
+        from .models.longcat_flash import kv_b_parts, mla_project
+        p = self._block(w, i, j)
+        q_nope, q_rope, c, k_rope = mla_project(p, x, cos, sin, self.cfg)
+        w_k, _ = kv_b_parts(p["kv_b_proj.weight"], self.cfg)
+        q_lat = jnp.einsum("...hn,chn->...hc", q_nope, w_k)
+        pad = self.hd - self.latent_dim - self.rope_dim
+        q = jnp.concatenate(
+            [q_lat, q_rope, jnp.zeros(q_lat.shape[:-1] + (pad,), q_lat.dtype)],
+            axis=-1)
+        row = jnp.concatenate(
+            [c, k_rope, jnp.zeros(c.shape[:-1] + (pad,), c.dtype)], axis=-1)
+        return q, row
+
+    def _mla_out(self, w, i, j, o_lat):
+        """o_lat: [..., heads, latent], the probabilities' sum of latents ->
+        the block's output [..., hidden]."""
+        from .models.longcat_flash import kv_b_parts
+        p = self._block(w, i, j)
+        _, w_v = kv_b_parts(p["kv_b_proj.weight"], self.cfg)
+        o = jnp.einsum("...hc,chv->...hv", o_lat, w_v)
+        return _mm(o.reshape(o.shape[:-2] + (-1,)), p, "o_proj.weight")
+
+    def _ffn(self, w, i, j, x):
+        from .models.longcat_flash import swiglu
+        pre = f"model.layers.{i}.mlps.{j}."
+        return swiglu(x, w[pre + "gate_proj.weight"],
+                      w[pre + "up_proj.weight"], w[pre + "down_proj.weight"])
+
+    def _moe(self, w, i, h, valid, shard=None):
+        """The expert layer on h: [T, hidden]; valid: [T] bool, rows that
+        are somebody's (the others are routed nowhere). Returns (m [T,
+        hidden], counters [5] int32)."""
+        from .kernels import grouped_experts_pallas as ge
+        from .models.longcat_flash import route
+        cfg = self.cfg
+        pre = f"model.layers.{i}.mlp."
+        t, k, held_n = h.shape[0], cfg.moe_topk, cfg.experts_held
+        with jax.named_scope("moe_route"):
+            chosen, weight = route(h, w[pre + "router.classifier.weight"],
+                                   w[pre + "router.e_score_correction_bias"],
+                                   cfg)
+            local = chosen - cfg.first_expert
+            held = (local >= 0) & (local < held_n) & valid[:, None]
+            zero = (chosen >= cfg.n_routed_experts) & valid[:, None]
+            keys = jnp.where(held, local, held_n).reshape(-1)
+            sizes, tile_group, n_live, row_pair, pair_row = ge.group_plan(
+                keys, held_n)
+            live = row_pair >= 0
+            xs = jnp.where(live[:, None],
+                           h[jnp.maximum(row_pair, 0) // k], 0)
+        with jax.named_scope("moe_experts"):
+            ys = ge.grouped_experts(
+                xs, tile_group, n_live, w[pre + "experts.gate_proj"],
+                w[pre + "experts.up_proj"], w[pre + "experts.down_proj"],
+                kernel=shard is None)
+            mine = ys[jnp.maximum(pair_row, 0)].reshape(t, k, -1)
+            m = jnp.sum(jnp.where(held[..., None],
+                                  weight[..., None] * mine.astype(jnp.float32),
+                                  0.0), axis=1)
+        with jax.named_scope("moe_zero"):
+            m = m + jnp.sum(jnp.where(zero, weight, 0.0), axis=1)[:, None] \
+                * h.astype(jnp.float32)
+        with jax.named_scope("moe_route"):
+            counters = jnp.stack([
+                valid.sum() * k, held.sum(), zero.sum(), sizes.max(),
+                (sizes > 0).sum()]).astype(jnp.int32)
+        return m.astype(h.dtype), counters
+
+    def _double_layer(self, w, i, h, attention, valid, shard=None):
+        """One double layer on h [..., hidden]; ``attention(j, x)`` is MLA
+        block ``j`` on the normed input (it owns the cache)."""
+        shortcut = counters = None
+        for j in (0, 1):
+            a = h + attention(j, self._norm(w, i, "input_layernorm", j, h))
+            with jax.named_scope("mlp"):
+                x = self._norm(w, i, "post_attention_layernorm", j, a)
+            if j == 0:
+                flat = x.reshape(-1, x.shape[-1])
+                shortcut, counters = self._moe(w, i, flat, valid, shard)
+                shortcut = shortcut.reshape(x.shape)
+            with jax.named_scope("mlp"):
+                h = a + self._ffn(w, i, j, x)
+        return h + shortcut, counters
+
+    @jax.named_scope("head")
+    def _logits(self, w, h):
+        h = _rms(h, w["model.norm.weight"], self.eps)
+        return h @ w["lm_head.weight"].T
+
+    # -- the two step programs ---------------------------------------------------
+    def step_ragged(self, w, tokens, positions, k_pools, v_pools, scatter,
+                    attend, shard=None):
+        """See _LlamaDecoder.step_ragged; k_pools: [2 * n_layers, P, 1, bs,
+        row_dim], v_pools zero wide and handed back as they came. The
+        second result is the step's routing counters, [5] int32
+        (``COUNTERS``)."""
+        h, cos, sin = self._embed_ragged(w, tokens, positions)
+        seam = _entry_seams(k_pools.shape, scatter, attend)
+        valid = scatter[0] < k_pools.shape[1]
+        with jax.named_scope("kv_write"):
+            kf = _join_entry_pages(k_pools)
+        total = jnp.zeros(len(self.COUNTERS), jnp.int32)
+        for i in range(self.n_layers):
+            def attention(j, x, i=i):
+                nonlocal kf
+                with jax.named_scope("attn_proj"):
+                    q, row = self._mla_rows(w, i, j, x, cos, sin)
+                sc, att = seam(2 * i + j)
+                with jax.named_scope("kv_write"):
+                    kf = _write_pages(kf, row[:, :, None], sc)
+                o_lat = att(q[:, 0], kf, None)[:, None]
+                with jax.named_scope("attn_proj"):
+                    return self._mla_out(w, i, j, o_lat)
+
+            h, counters = self._double_layer(w, i, h, attention, valid, shard)
+            total = total + counters
+        with jax.named_scope("kv_write"):
+            kp = kf.reshape(k_pools.shape)
+        return self._logits(w, h)[:, 0], total, kp, v_pools
+
+    def step(self, w, tokens, positions, kcs, vcs, write_pos, score_mask):
+        """See _LlamaDecoder.step; kcs: [2 * n_layers, B, M, 1, row_dim],
+        vcs zero wide."""
+        h = w[self.embed_key][tokens]
+        cos = w["__rope_cos"][positions]      # [B, S, rope/2]
+        sin = w["__rope_sin"][positions]
+        valid = jnp.ones(h.shape[0] * h.shape[1], bool)
+        new = []
+        for i in range(self.n_layers):
+            def attention(j, x, i=i):
+                q, row = self._mla_rows(w, i, j, x, cos, sin)
+                kc = jax.lax.dynamic_update_slice(
+                    kcs[2 * i + j], row[:, :, None].astype(kcs.dtype),
+                    (0, write_pos, 0, 0))
+                new.append(kc)
+                rows = kc[:, :, 0].astype(jnp.float32)        # [B, M, row]
+                scores = jnp.einsum("bshd,bmd->bhsm", q.astype(jnp.float32),
+                                    rows) * self.attn_scale
+                p = jax.nn.softmax(jnp.where(score_mask, scores, NEG_INF),
+                                   axis=-1)
+                o_lat = jnp.einsum("bhsm,bmc->bshc", p,
+                                   rows[..., :self.latent_dim])
+                return self._mla_out(w, i, j, o_lat.astype(x.dtype))
+
+            h, _ = self._double_layer(w, i, h, attention, valid)
+        return self._logits(w, h), jnp.stack(new), vcs
+
 
 def _ln(x, w, b, eps):
     x32 = x.astype(jnp.float32)
@@ -592,6 +849,9 @@ class _GPTDecoder:
     — dropped-token decode could never match the full forward). All
     experts run densely and combine through exact 0/1 masks, so a no-drop
     eval forward is reproduced bit-for-bit."""
+
+    attn_scale = None         # see _LlamaDecoder
+    latent_dim = None
 
     def __init__(self, model):
         cfg = model.config
@@ -639,11 +899,15 @@ class _GPTDecoder:
         self.n_heads = cfg.num_attention_heads
         self.n_kv = self.n_heads
         self.hd = cfg.hidden_size // self.n_heads
+        self.v_dim = self.hd
         self.eps = cfg.layer_norm_epsilon
         self.n_layers = cfg.num_hidden_layers
         self.cache_entries = self.n_layers    # see _LlamaDecoder
         self.tied = model.lm_head is None
         self.embed_key = "transformer.wte.weight"
+
+    def describe(self):
+        return {}                 # see _LlamaDecoder
 
     def _static_key(self):
         """See _LlamaDecoder._static_key. The MoE fingerprint keys the
@@ -853,7 +1117,8 @@ def _prefill(dec, w, ids, mask, max_new):
         jnp.cumsum(mask, axis=1).astype(jnp.int32) - 1, 0)   # [B, S]
     kcs = jnp.zeros((dec.cache_entries, b, m_total, dec.n_kv, dec.hd),
                     w[dec.embed_key].dtype)
-    vcs = jnp.zeros_like(kcs)
+    # a latent cache keeps no V: its V side is zero wide
+    vcs = jnp.zeros(kcs.shape[:-1] + (dec.v_dim,), kcs.dtype)
     t_idx = jnp.arange(m_total)[None, None, None, :]         # key slots
     q_idx = jnp.arange(s)[None, None, :, None]
     key_mask = jnp.concatenate(
@@ -1184,9 +1449,11 @@ def _decoder_for(model):
     configs hash equal, so the module jits share executables across
     instances)."""
     from .models.gpt import GPTForCausalLM
+    from .models.longcat_flash import LongcatFlashForCausalLM
     from .models.ouro import OuroForCausalLM
     cls = _GPTDecoder if isinstance(model, GPTForCausalLM) \
         else _OuroDecoder if isinstance(model, OuroForCausalLM) \
+        else _LongcatDecoder if isinstance(model, LongcatFlashForCausalLM) \
         else _LlamaDecoder
     struct = (cls, model.lm_head is None,    # head tying is baked into the
               _live_moe_struct(model))       # traced logits branch

@@ -222,11 +222,13 @@ def _argmax_rows(logits):
 
 
 @jax.jit
-def _beside_exits(tokens, exits):
-    """The sampled tokens with the pass each row's logits were taken after
-    (a decoder that runs its layers several times a token reports it) as a
-    second row: one array, so one transfer brings both back."""
-    return jnp.stack([tokens, exits])
+def _beside_exits(tokens, beside):
+    """The sampled tokens with what the step program returned beside its
+    logits behind them (the pass each row's logits were taken after, where
+    the decoder runs its layers several times a token; the routing counts
+    of a decoder with an expert layer): one int32 array, so one transfer
+    brings both back. The decoder's ``step_counts`` reads the tail."""
+    return jnp.concatenate([tokens, beside])
 
 
 @jax.jit
@@ -288,7 +290,8 @@ def _engine_step_impl(dec, shard, w, tokens, slot_ids, positions, valid,
     pages = jnp.where(bad, p_total, page)
     offs = positions % bs
     attend = _ragged.make_attend(tables, slot_ids, positions, valid,
-                                 dec.n_heads // dec.n_kv, shard=shard)
+                                 dec.n_heads // dec.n_kv, shard=shard,
+                                 scale=dec.attn_scale, latent=dec.latent_dim)
     logits, exits, kp, vp = dec.step_ragged(w, tokens, positions, k_pools,
                                             v_pools, (pages, offs), attend,
                                             shard=shard)
@@ -347,12 +350,15 @@ class ServingEngine:
             num_blocks = cfg.max_seqs * self.max_pages_per_seq
         dtype = self._w[self.dec.embed_key].dtype
         # first axis: the K/V cache entries a token keeps, which is the
-        # layers of weights only where each runs once a token
+        # layers of weights only where each runs once a token. A latent
+        # cache is ONE pool: its row holds what K and V are both made of
+        # (``dec.v_dim`` 0), and the V side is zero wide: no bytes, and the
+        # page operations below need no second spelling
         shape = (self.dec.cache_entries, num_blocks, self.dec.n_kv, bs,
                  self.dec.hd)
         self._pool_shape, self._pool_dtype = shape, dtype
-        self._kp = self._new_pool()
-        self._vp = self._new_pool()
+        self._kp = self._new_pool("_kp")
+        self._vp = self._new_pool("_vp")
         # device bytes of one page across K+V and every cache entry — the
         # unit the telemetry/memwatch byte accounting is denominated in
         self.page_bytes = (self._kp.nbytes + self._vp.nbytes) // num_blocks
@@ -452,12 +458,15 @@ class ServingEngine:
         return NamedSharding(self.mesh,
                              PartitionSpec(None, None, "mp", None, None))
 
-    def _new_pool(self):
-        """A zeroed device pool in the engine's placement — construction
-        and the step-fault containment rebuild share one spelling."""
+    def _new_pool(self, name):
+        """A zeroed device pool (``"_kp"`` or ``"_vp"``) in the engine's
+        placement — construction and the step-fault containment rebuild
+        share one spelling."""
         # created in place: under a mesh each chip zeroes its own KV-head
         # shard, and no chip ever holds the whole pool
-        return jnp.zeros(self._pool_shape, self._pool_dtype,
+        shape = self._pool_shape if name == "_kp" \
+            else self._pool_shape[:-1] + (self.dec.v_dim,)
+        return jnp.zeros(shape, self._pool_dtype,
                          device=self._pool_sharding())
 
     def _weight_sharding(self, name, ndim):
@@ -1143,7 +1152,7 @@ class ServingEngine:
         for name in ("_kp", "_vp"):
             arr = getattr(self, name)
             if getattr(arr, "is_deleted", lambda: False)():
-                setattr(self, name, self._new_pool())
+                setattr(self, name, self._new_pool(name))
                 pools_rebuilt = True
         if pools_rebuilt or kind == "nan_logits":
             # rebuilt pools hold zeros, and garbage logits mean NOTHING
@@ -1232,11 +1241,11 @@ class ServingEngine:
                 if exits is None:
                     all_tok = np.asarray(_argmax_rows(logits))
                 else:
-                    all_tok, passes = np.asarray(
+                    got = np.asarray(
                         _beside_exits(_argmax_rows(logits), exits))
-                    rows = [i for _, i in sample_points]
-                    counts = {"exit_pass_sum": int(passes[rows].sum()),
-                              "exit_rows": len(rows)}
+                    all_tok, beside = got[:len(valid)], got[len(valid):]
+                    counts = self.dec.step_counts(
+                        beside, [i for _, i in sample_points])
         with RecordEvent("serve.emit", **counts):
             return self._emit_sampled(plan, sample_points, all_tok, armed)
 
@@ -1563,8 +1572,13 @@ class ServingEngine:
                     self._shard, self._pool_shape, self._pool_dtype),
                 # two numbers, equal unless the model runs its layers
                 # several times a token: the pools are cache_entries deep
+                # and what a cached token costs across them, K and V or
+                # the one latent row, as the pools hold it
                 "model": {"weight_layers": self.dec.n_layers,
-                          "cache_entries": self.dec.cache_entries},
+                          "cache_entries": self.dec.cache_entries,
+                          "cached_token_bytes":
+                              self.page_bytes // self.pool.block_size,
+                          **self.dec.describe()},
             }
             if self.mesh is not None:
                 base["mesh"] = {"mp": int(self.mesh.shape["mp"]),

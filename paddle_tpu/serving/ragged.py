@@ -44,21 +44,29 @@ NEG_INF = -1e30
 
 
 def ragged_paged_attention(q, k_pool, v_pool, page_tables, slot_ids,
-                           positions, valid, rep=1):
+                           positions, valid, rep=1, scale=None, latent=None):
     """Pure-JAX reference. q: [T, H, D] packed mixed-phase queries;
     k_pool/v_pool: [P, kvh, bs, D]; page_tables: [S, MP] int32 (-1 =
     unassigned); slot_ids: [T] int32; positions: [T] int32; valid: [T]
     bool (False = padding row, output is zeroed); rep = H // kvh (GQA
-    query groups per kv head). Returns [T, H, D] in q.dtype."""
+    query groups per kv head); scale multiplies the scores (``D ** -0.5``
+    unless given). Returns [T, H, D] in q.dtype. With ``latent`` the pool
+    is a latent one (``kernels.ragged_pallas``): ``v_pool`` is not read,
+    the value is the key row's first ``latent`` columns and the result is
+    [T, H, latent]."""
     t, h, d = q.shape
     p_total, kvh, bs, _ = k_pool.shape
     mp = page_tables.shape[1]
+    over = np.sqrt(d) if scale is None else 1.0 / scale
     tabs = page_tables[slot_ids]                       # [T, MP]
     safe = jnp.clip(tabs, 0, p_total - 1)
     kg = k_pool[safe]                                  # [T, MP, kvh, bs, D]
-    vg = v_pool[safe]
     kg = kg.transpose(0, 2, 1, 3, 4).reshape(t, kvh, mp * bs, d)
-    vg = vg.transpose(0, 2, 1, 3, 4).reshape(t, kvh, mp * bs, d)
+    if latent is None:
+        vg = v_pool[safe].transpose(0, 2, 1, 3, 4).reshape(
+            t, kvh, mp * bs, d)
+    else:
+        vg = kg[..., :latent]
     slot_pos = jnp.arange(mp * bs)[None, :]            # [1, MP*bs]
     live = (slot_pos <= positions[:, None]) & valid[:, None]
     page_ok = jnp.broadcast_to((tabs >= 0)[:, :, None],
@@ -66,18 +74,18 @@ def ragged_paged_attention(q, k_pool, v_pool, page_tables, slot_ids,
     live = live & page_ok
     if rep == 1:
         scores = jnp.einsum("thd,thmd->thm", q.astype(jnp.float32),
-                            kg.astype(jnp.float32)) / np.sqrt(d)
+                            kg.astype(jnp.float32)) / over
         scores = jnp.where(live[:, None, :], scores, NEG_INF)
         p = jax.nn.softmax(scores, axis=-1)
         out = jnp.einsum("thm,thmd->thd", p, vg.astype(jnp.float32))
     else:
         qg = q.reshape(t, kvh, rep, d)
         scores = jnp.einsum("tgrd,tgmd->tgrm", qg.astype(jnp.float32),
-                            kg.astype(jnp.float32)) / np.sqrt(d)
+                            kg.astype(jnp.float32)) / over
         scores = jnp.where(live[:, None, None, :], scores, NEG_INF)
         p = jax.nn.softmax(scores, axis=-1)
         out = jnp.einsum("tgrm,tgmd->tgrd", p, vg.astype(jnp.float32))
-        out = out.reshape(t, h, d)
+        out = out.reshape(t, h, -1)
     out = jnp.where(valid[:, None, None], out, 0.0)
     return out.astype(q.dtype)
 
@@ -94,7 +102,8 @@ def attention_path(shard, pool_shape, dtype) -> str:
     return "reference"
 
 
-def make_attend(page_tables, slot_ids, positions, valid, rep, shard=None):
+def make_attend(page_tables, slot_ids, positions, valid, rep, shard=None,
+                scale=None, latent=None):
     """Bind the ragged metadata into the ``attend(q, kp, vp)`` callable
     ``generation.step_ragged`` expects. ``attention_path`` selects what
     implements it (``shard`` is the engine's tensor-parallel annotator,
@@ -110,7 +119,9 @@ def make_attend(page_tables, slot_ids, positions, valid, rep, shard=None):
     (``generation._entry_seams`` binds ``first_page`` a layer). The page
     tables are shifted by ``first_page``, so neither path copies the entry
     out. Without ``first_page`` the pools are one entry's, read as they
-    are."""
+    are. ``scale`` and ``latent`` are the decoder's (``attn_scale``,
+    ``latent_dim``): a latent pool is one pool, ``vp`` is not read and the
+    rows come back ``latent`` wide."""
     from ..kernels import ragged_pallas as _rp
     with jax.named_scope("paged_attention"):
         # once a step, not once a layer, and outside any loop the step
@@ -123,8 +134,10 @@ def make_attend(page_tables, slot_ids, positions, valid, rep, shard=None):
             page_tables >= 0, page_tables + first_page, -1)
         if attention_path(shard, kp.shape, kp.dtype) == "reference":
             return ragged_paged_attention(q, kp, vp, tables, slot_ids,
-                                          positions, valid, rep)
-        return _rp.paged_attention(q, kp, vp, tables, *meta, rep=rep)
+                                          positions, valid, rep,
+                                          scale=scale, latent=latent)
+        return _rp.paged_attention(q, kp, vp, tables, *meta, rep=rep,
+                                   scale=scale, latent=latent)
 
     return attend
 

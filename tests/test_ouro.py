@@ -225,7 +225,9 @@ def test_engine_logits_match_the_reference(threshold):
     assert eng._kp.shape == eng._vp.shape == (PASSES * LAYERS, 48, 4, 8, 16)
     tel = eng.telemetry()
     assert tel["model"] == {"weight_layers": LAYERS,
-                            "cache_entries": PASSES * LAYERS}
+                            "cache_entries": PASSES * LAYERS,
+                            "cached_token_bytes":
+                                2 * PASSES * LAYERS * 4 * 16 * 4}
     assert tel["pool"]["page_bytes"] == 2 * PASSES * LAYERS * 4 * 8 * 16 * 4
     steps = _record(eng)
     # 37 and 21 tokens against a budget of 16: chunks share steps with decode

@@ -187,7 +187,8 @@ def case_gmm():
     return (t, dm, dff, e), dict(zip(("out", "dx", "dw"), zip(got, want)))
 
 
-def paged_step(entries, pages, kvh, rep, rows, slots, table, plan, seed=0):
+def paged_step(entries, pages, kvh, rep, rows, slots, table, plan, seed=0,
+               d=128, pools=2):
     """One packed serving step at a cell's geometry, as ``_pack_plan``
     lays it out: ``plan`` is (rows, context) per scheduled sequence, one
     page-table slot each from slot 1 on (slot 0 stays idle), live pages
@@ -196,8 +197,8 @@ def paged_step(entries, pages, kvh, rep, rows, slots, table, plan, seed=0):
     joined into one run, as the step program threads them, and the tables
     point into the LAST entry, the farthest page offset ``make_attend``
     applies. Returns (q, k_pool, v_pool, (tables, slot_ids, positions,
-    valid))."""
-    bs, d = 16, 128
+    valid)); with ``pools`` 1 (a latent cache) ``v_pool`` is None."""
+    bs = 16
     rng = np.random.default_rng(seed)
     tables = np.full((slots, table), -1, np.int32)
     slot = np.zeros(rows, np.int32)
@@ -215,7 +216,8 @@ def paged_step(entries, pages, kvh, rep, rows, slots, table, plan, seed=0):
     q = rand(seed, (rows, kvh * rep, d))
     # under jit: a pool of gigabytes is drawn with no float32 copy beside it
     kp, vp = (jax.jit(rand, static_argnums=(0, 1))(
-        seed + k, (entries * pages, kvh, bs, d)) for k in (1, 2))
+        seed + k, (entries * pages, kvh, bs, d)) if k <= pools else None
+        for k in (1, 2))
     return q, kp, vp, tuple(jnp.asarray(a) for a in
                             (tables, slot, pos, valid))
 
@@ -258,6 +260,90 @@ def case_paged_attention(cell):
     return (rows, kvh * rep, 128, entries * pages, 16), {"out": (got, want)}
 
 
+def timed_ms(fn, *args, n=20):
+    """Milliseconds a call of the jitted ``fn``: ``n`` calls queued one
+    behind the other, the last one waited for, on an idle device."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(n):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return round((time.perf_counter() - t0) / n * 1e3, 4)
+
+
+# longcat560-serve-batch: 8 latent entries of 18,944 pages, 64 heads on one
+# row of 640 (512 of latent, 64 of roped key, 64 of padding), 320 rows, 256
+# slots, tables of 74 pages: 244 decodes over contexts of 40..1,012, a
+# 50-row prompt chunk and a 26-row chunk that continues a prompt mid-page
+LATENT_GEOMETRY = (8, 18944, 64, 640, 512, 320, 256, 74,
+                   [(1, c) for c in range(40, 1180, 4)][:244]
+                   + [(50, 50), (26, 150)])
+
+
+def case_latent_paged_attention():
+    """The latent path of the step's attention kernel against the
+    gather-based reference, with the milliseconds a call."""
+    from paddle_tpu.kernels import ragged_pallas as rp
+    from paddle_tpu.serving.ragged import ragged_paged_attention
+    entries, pages, heads, d, latent, rows, slots, table, plan = \
+        LATENT_GEOMETRY
+    q, kp, _, args = paged_step(entries, pages, 1, heads, rows, slots, table,
+                                plan, d=d, pools=1)
+    kw = dict(scale=192 ** -0.5, latent=latent)
+    kernel = jax.jit(lambda q, kp, tables, slot, pos, valid:
+                     rp.paged_attention(
+                         q, kp, None, tables,
+                         *rp.seq_meta(slot, pos, valid, tables.shape[0]),
+                         rep=heads, **kw))
+    got = kernel(q, kp, *args)
+    want = jax.jit(lambda q, kp, *a: ragged_paged_attention(
+        q, kp, None, *a, rep=heads, **kw))(q, kp, *args)
+    live = sum(c for _, c in plan)
+    jax.block_until_ready(want)
+    return ((rows, heads, d, latent, entries * pages, 16),
+            {"out": (got, want)},
+            {"ms_a_call": timed_ms(kernel, q, kp, *args),
+             "live_rows": live, "live_bytes": live * d * 2})
+
+
+def case_grouped_experts():
+    """The held experts' grouped product at the cell's shapes (3,840 pairs
+    of 320 rows x 12, 16 experts of 6144 x 2048) under uneven routing: one
+    expert takes 300 pairs, one none, the rest 1 to 9; the pairs no expert
+    here takes are the other 3,470."""
+    from paddle_tpu.kernels import grouped_experts_pallas as ge
+    e, h, f, pairs = 16, 6144, 2048, 320 * 12
+    sizes = [300, 0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 5, 5, 5, 5, 5]
+    keys = np.full(pairs, e, np.int32)
+    keys[:sum(sizes)] = np.repeat(np.arange(e), sizes)
+    keys = jnp.asarray(np.random.default_rng(0).permutation(keys))
+    tm = ge.TM
+    x = rand(0, (pairs, h))
+    banks = [jax.jit(rand, static_argnums=(0, 1, 2, 3))(
+        k, s, jnp.bfloat16, 0.02) for k, s in
+        ((1, (e, h, f)), (2, (e, h, f)), (3, (e, f, h)))]
+
+    def run(kernel, x, keys, *banks):
+        _, tile_group, n_live, row_pair, _ = ge.group_plan(keys, e, tm)
+        xs = jnp.where((row_pair >= 0)[:, None], x[jnp.maximum(row_pair, 0)],
+                       0)
+        ys = ge.grouped_experts(xs, tile_group, n_live, *banks,
+                                kernel=kernel)
+        return jnp.where((row_pair >= 0)[:, None], ys, 0)
+
+    fast = jax.jit(functools.partial(run, True))
+    got = fast(x, keys, *banks)
+    want = jax.jit(functools.partial(run, False))(x, keys, *banks)
+    jax.block_until_ready(want)
+    even = jnp.asarray(np.random.default_rng(1).permutation(
+        np.where(np.arange(pairs) < 64, np.arange(pairs) % e, e)
+        .astype(np.int32)))
+    return ((pairs, e, h, f, tm), {"out": (got, want)},
+            {"ms_a_call_uneven": timed_ms(fast, x, keys, *banks),
+             "ms_a_call_4_a_expert": timed_ms(fast, x, even, *banks),
+             "touched_bytes_4_a_expert": e * 3 * h * f * 2})
+
+
 CASES = (
     ("flash_attention", "F.scaled_dot_product_attention on a TPU",
      case_flash_attention, REL_L2),
@@ -273,6 +359,10 @@ CASES = (
      functools.partial(case_paged_attention, "chat"), REL_L2),
     ("paged_attention/looped", "ServingEngine on one chip",
      functools.partial(case_paged_attention, "looped"), REL_L2),
+    ("latent_paged_attention", "ServingEngine on one chip, a latent cache",
+     case_latent_paged_attention, REL_L2),
+    ("grouped_experts", "ServingEngine on one chip, an expert layer",
+     case_grouped_experts, REL_L2),
 )
 
 
