@@ -429,6 +429,7 @@ class ServingEngine:
         self.aot_warm_result = self._warm_start()
         self.steps = 0
         self.tokens_generated = 0
+        self.attn_tiles = self.attn_tiles_ahead = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.spec_rollback_pages = 0
@@ -812,6 +813,10 @@ class ServingEngine:
                     self._work.clear()
                 has_work = self.sched.has_work()
             else:
+                tiles = self._attn_tiles(plan)
+                ahead = max(tiles - 1, 0)
+                self.attn_tiles += tiles
+                self.attn_tiles_ahead += ahead
                 try:
                     with RecordEvent(
                             "serve.run",
@@ -822,6 +827,8 @@ class ServingEngine:
                             pages_walked=self._pages_walked(plan),
                             pages_tabled=self.config.token_budget
                             * self.max_pages_per_seq,
+                            attn_tiles=tiles,
+                            attn_tiles_ahead=ahead,
                             layer_visits=self.dec.cache_entries):
                         sampled = self._run_plan(plan, armed)
                 except Exception as exc:  # noqa: BLE001 — containment seam
@@ -1258,6 +1265,16 @@ class ServingEngine:
         return sum(-(-(e.start + e.n + len(e.draft)) // bs)
                    for e in plan.entries)
 
+    def _attn_tiles(self, plan) -> int:
+        """Query tiles the step's attention kernel walks: each scheduled
+        sequence's rows (drafts included) in tiles of ``ragged_pallas.TQ``.
+        All but the first of a step find their first pages already on
+        their way, fetched while the tile before was computed: ``serve.run``
+        carries the count and that part of it, ``attn_tiles_ahead``."""
+        from ..kernels.ragged_pallas import TQ
+        tq = min(TQ, self.config.token_budget)
+        return sum(-(-(e.n + len(e.draft)) // tq) for e in plan.entries)
+
     def _pack_plan(self, plan, armed: bool):
         """The numpy fill of the step program's inputs (and of the page
         table rows of the scheduled slots). Returns (tokens, slots,
@@ -1548,7 +1565,7 @@ class ServingEngine:
         with self._lock:
             s = self.pool.stats
             base = {
-                "version": 3,
+                "version": 4,
                 "steps": self.steps,
                 "tokens_generated": self.tokens_generated,
                 "queue_depth": self.sched.queue_depth(),
@@ -1570,6 +1587,11 @@ class ServingEngine:
                 "spec": self.spec_stats(),
                 "attention": _ragged.attention_path(
                     self._shard, self._pool_shape, self._pool_dtype),
+                # query tiles its kernel walked, and those of them whose
+                # first pages were fetched ahead (all but a step's first)
+                "attention_tiles": {"attn_tiles": self.attn_tiles,
+                                    "attn_tiles_ahead":
+                                        self.attn_tiles_ahead},
                 # two numbers, equal unless the model runs its layers
                 # several times a token: the pools are cache_entries deep
                 # and what a cached token costs across them, K and V or

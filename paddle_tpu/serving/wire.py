@@ -337,7 +337,8 @@ WIRE_SCHEMAS = {
     },
     "telemetry_line": {
         "family": "telemetry_line",
-        "version": 3,                  # 2: "attention" (PR 27); 3: "model"
+        # 2: "attention" (PR 27); 3: "model"; 4: "attention_tiles" (PR 35)
+        "version": 4,
         "version_key": "version",
         "required": {
             "version": "int",
@@ -360,12 +361,14 @@ WIRE_SCHEMAS = {
             "mem": "dict",
             "resilience": "dict",
             "attention": "str",
+            "attention_tiles": "dict",
             "model": "dict",
         },
         "item_key": None,
         "item_required": {},
         "item_optional": {},
-        "key_hashes": {1: "f2b55577", 2: "a5410fb5", 3: "3a9c8544"},
+        "key_hashes": {1: "f2b55577", 2: "a5410fb5", 3: "3a9c8544",
+                       4: "4b58fb5c"},
         "byte_stable": False,
         "builders": ("serving/engine.py::telemetry",),
         "consumers": (),

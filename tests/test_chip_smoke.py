@@ -191,10 +191,11 @@ def test_paged_attention_compiles_at_the_serving_cells_geometries(
     assert rp.tiles(pool.shape, pool.dtype)
     per_slot = shape((slots,), jnp.int32)
     compiled = jax.jit(
-        lambda q, kp, vp, tables, starts, counts, ctx: rp.paged_attention(
-            q, kp, vp, tables, starts, counts, ctx, rep=rep)).lower(
+        lambda q, kp, vp, tables, *meta: rp.paged_attention(
+            q, kp, vp, tables, *meta, rep=rep)).lower(
         q, pool, pool, shape((slots, table), jnp.int32),
-        per_slot, per_slot, per_slot).compile()
+        per_slot, per_slot, per_slot,
+        shape((slots + 1,), jnp.int32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "paged_attention" in text
     assert compiled.memory_analysis().output_size_in_bytes == rows * kvh * rep * 256

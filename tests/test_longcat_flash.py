@@ -272,18 +272,39 @@ def test_absorbed_attention_equals_unabsorbed():
 
 
 # -- (c) the paged kernel on latent pages against the oracle --------------------
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
-                                       (jnp.bfloat16, 2e-2)])
-def test_latent_paged_kernel_matches_the_gather_oracle(monkeypatch, dtype, tol):
-    monkeypatch.setattr(ragged_pallas, "_INTERPRET", True)
-    rng = np.random.default_rng(11)
-    heads, d, latent, bs, pages, slots, mp = 4, 128, 64, 8, 40, 5, 6
-    t = 24
-    pool = jnp.asarray(rng.normal(0, 1, (pages, 1, bs, d)), dtype)
-    tables = np.full((slots, mp), -1, np.int32)
+# (slot, first position, rows) per scheduled sequence in packing order; the
+# table's width in pages of 8 (a block of the kernel's walk is 32 of them)
+_LATENT_PLANS = {
     # slot 0: a chunk of 11 rows after 13 cached; slot 2: one decode row at
     # position 40; slot 3: a chunk of 3 from the start; slot 4: one row at 0
-    plan = [(0, 13, 11), (2, 40, 1), (3, 0, 3), (4, 0, 1)]
+    "mixed": ([(0, 13, 11), (2, 40, 1), (3, 0, 3), (4, 0, 1)], 6),
+    # a one-row slot before and after a chunk of 17 rows: tiles of 1, 16, 1
+    # (the chunk's second) and 1 in turn, each fetched by the one before
+    "one-chunk-one": ([(0, 70, 1), (1, 30, 17), (3, 9, 1)], 12),
+    # contexts of exactly one page, 16 rows, one block and one row past it
+    "edges": ([(0, 7, 1), (1, 15, 1), (2, 255, 1), (3, 256, 1),
+               (4, 250, 17)], 40),
+    # three blocks then two then one: a first block in either half
+    "odd-even": ([(1, 599, 1), (2, 299, 1), (4, 5, 2)], 80),
+    "one-live": ([(3, 20, 5)], 6),
+    "none-live": ([], 6),
+}
+
+
+# -- (c) the paged kernel on latent pages against the oracle --------------------
+@pytest.mark.parametrize("dtype,tol,plan", [
+    (jnp.float32, 2e-5, "mixed"), (jnp.bfloat16, 2e-2, "mixed"),
+    *((jnp.float32, 2e-5, name) for name in list(_LATENT_PLANS)[1:])])
+def test_latent_paged_kernel_matches_the_gather_oracle(monkeypatch, dtype, tol,
+                                                       plan):
+    monkeypatch.setattr(ragged_pallas, "_INTERPRET", True)
+    rng = np.random.default_rng(11)
+    plan, mp = _LATENT_PLANS[plan]
+    heads, d, latent, bs, slots = 4, 128, 64, 8, 5
+    t = 24
+    pages = 40 + sum(-(-(start + n) // bs) for _, start, n in plan)
+    pool = jnp.asarray(rng.normal(0, 1, (pages, 1, bs, d)), dtype)
+    tables = np.full((slots, mp), -1, np.int32)
     slot_ids = np.zeros(t, np.int32)
     positions = np.zeros(t, np.int32)
     valid = np.zeros(t, bool)
@@ -452,13 +473,13 @@ def test_the_kernels_compile_at_the_batch_cells_shapes(one_chip, monkeypatch):
     rows, heads, row, latent, slots, table = 320, 64, 640, 512, 256, 74
     per_slot = shape((slots,), jnp.int32)
     compiled = jax.jit(
-        lambda q, kp, tables, starts, counts, ctx:
-        ragged_pallas.paged_attention(q, kp, None, tables, starts, counts,
-                                      ctx, rep=heads, scale=192 ** -0.5,
+        lambda q, kp, tables, *meta:
+        ragged_pallas.paged_attention(q, kp, None, tables, *meta, rep=heads,
+                                      scale=192 ** -0.5,
                                       latent=latent)).lower(
         shape((rows, heads, row)), shape((8 * 18944, 1, 16, row)),
-        shape((slots, table), jnp.int32), per_slot, per_slot,
-        per_slot).compile()
+        shape((slots, table), jnp.int32), per_slot, per_slot, per_slot,
+        shape((slots + 1,), jnp.int32)).compile()
     assert "latent_paged_attention" in compiled.as_text()
     assert compiled.memory_analysis().output_size_in_bytes \
         == rows * heads * latent * 2

@@ -1,7 +1,9 @@
-"""The count that says how far the paged attention kernel engages:
-``pages_walked`` / ``pages_tabled`` on the engine's ``serve.run`` span against
-a hand count, and the benchmark's ``paged_walk_share.serve`` metric file against
-the names that exist (its reader, its span, its arguments, its cells)."""
+"""The counts that say how far the paged attention kernel engages:
+``pages_walked`` / ``pages_tabled`` and ``attn_tiles`` / ``attn_tiles_ahead``
+on the engine's ``serve.run`` span against a hand count, and the benchmark's
+metric files over them (``paged_walk_share.serve``,
+``attn_tiles_ahead_share.serve``) against the names that exist (reader, span,
+arguments, cells)."""
 import gzip
 import importlib
 import json
@@ -43,7 +45,16 @@ def run_spans():
         while eng.step():
             pass
     assert eng.max_pages_per_seq == 16
-    return [e["args"] for e in p._events if e["name"] == "serve.run"]
+    runs = [e["args"] for e in p._events if e["name"] == "serve.run"]
+    # the engine keeps the sums; a step that schedules nothing opens no
+    # ``serve.run`` and counts nothing
+    assert eng.telemetry()["attention_tiles"] == {
+        "attn_tiles": sum(a["attn_tiles"] for a in runs),
+        "attn_tiles_ahead": sum(a["attn_tiles_ahead"] for a in runs)}
+    assert not eng.step() and eng._attn_tiles(eng.sched.schedule()) == 0
+    assert len([e for e in p._events if e["name"] == "serve.run"]) \
+        == len(runs)
+    return runs
 
 
 def test_pages_walked_and_tabled_equal_the_hand_count(run_spans):
@@ -58,6 +69,43 @@ def test_pages_walked_and_tabled_equal_the_hand_count(run_spans):
     assert second_decode["pages_walked"] == 6 + 2 + 4
     # what the gather read: the whole table once a packed row, every step
     assert {a["pages_tabled"] for a in run_spans} == {48 * 16}
+
+
+def test_attn_tiles_and_those_fetched_ahead_equal_the_hand_count(run_spans):
+    prefill, first_decode = run_spans[:2]
+    # rows 21, 6 and 11 in tiles of 16: 2 + 1 + 1; all but the step's first
+    # tile find their first pages fetched by the tile before
+    assert (prefill["attn_tiles"], prefill["attn_tiles_ahead"]) == (4, 3)
+    # a decode row is a tile
+    assert (first_decode["attn_tiles"],
+            first_decode["attn_tiles_ahead"]) == (3, 2)
+    assert all(a["attn_tiles_ahead"] == a["attn_tiles"] - 1
+               for a in run_spans)
+
+
+# the batch cell's twin (``.batch.serve``, ``serve_tok_s``) is not declared:
+# ``tests/bench/test_bench_longcat.py`` holds that cell to PR 34's 23 metrics
+@pytest.mark.parametrize("name,moves,cells", [
+    ("attn_tiles_ahead_share.serve", "itl_p95_s",
+     ["mistral7b-serve-chat", "cgpt67-serve-decode", "ouro26-serve-decode"]),
+])
+def test_the_tiles_ahead_metric_files_name_what_exists(run_spans, name, moves,
+                                                       cells):
+    spec = json.load(open(os.path.join(REPO, "bench", "metrics",
+                                       name + ".json")))
+    assert spec["reader"] == "span_counts" and spec["span"] == "serve.run"
+    assert (spec["num"], spec["den"]) == (["attn_tiles_ahead"],
+                                          ["attn_tiles"])
+    assert set(spec["num"]) | set(spec["den"]) <= set(run_spans[0])
+    (entry,) = [m for m in BENCHMARK["per_layer"] if m["name"] == name]
+    # appended by PR 35, after everything PR 34 left
+    assert BENCHMARK["per_layer"].index(entry) >= 62
+    assert entry == {"name": name, "unit": "%", "better": "higher",
+                     "source": "program_counter",
+                     "layer": "kernels, serving", "moves": moves,
+                     "workloads": cells}
+    assert (spec["unit"], spec["layer"], spec["moves"]) \
+        == (entry["unit"], entry["layer"], entry["moves"])
 
 
 def test_the_metric_file_names_what_exists(run_spans):
@@ -80,6 +128,25 @@ def test_the_metric_file_names_what_exists(run_spans):
     assert len(serving) == 3
     assert entry["workloads"] == serving and entry["better"] == "lower"
     assert entry["source"] == "program_counter"
+
+
+def test_the_tiles_ahead_share_of_the_served_steps_is_the_hand_count(
+        monkeypatch, run_spans):
+    from bench.lib import spans as S
+    from bench.readers import span_counts
+    tiles = json.load(open(os.path.join(
+        REPO, "bench", "metrics", "attn_tiles_ahead_share.serve.json")))
+
+    class TilesCell(Cell):
+        def metric_file(self, name):
+            return tiles
+
+    two = [(20 * i, 10, "serve.run", {k: str(v) for k, v in a.items()})
+           for i, a in enumerate(run_spans[:2])]
+    monkeypatch.setattr(S, "of_run", lambda ctx: {"spans": two, "ops": []})
+    # 3 of the prefill step's 4 tiles and 2 of the decode step's 3
+    assert span_counts.read({"cell": TilesCell()}, "m") \
+        == pytest.approx(100.0 * 5 / 7)
 
 
 class Cell:

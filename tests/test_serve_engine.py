@@ -196,6 +196,59 @@ def _decode_step(rng, rep, dtype, t, kvh=2, d=8, bs=4):
                        jnp.asarray(pos), jnp.asarray(valid))
 
 
+def _planned(plan, slots, mp, t, holes=()):
+    """A step builder for ``plan``, (slot, first position, rows) per
+    scheduled sequence in packing order: each slot gets the pages its
+    context needs from a shuffled pool, ``holes`` are (slot, table column)
+    entries set back to -1. Pages of 4: a block of the kernel's walk is 64
+    pages, so a context past 256 has a second block."""
+    def step(rng, rep, dtype, t_, kvh=2, d=8, bs=4):
+        assert t_ == t
+        need = {sl: -(-(first + n) // bs) for sl, first, n in plan}
+        free = list(rng.permutation(sum(need.values()) + 3))
+        pools = [jnp.asarray(rng.standard_normal((len(free), kvh, bs, d)),
+                             dtype) for _ in range(2)]
+        tables = np.full((slots, mp), -1, np.int32)
+        slot, pos = np.zeros(t, np.int32), np.zeros(t, np.int32)
+        valid = np.zeros(t, bool)
+        i = 0
+        for sl, first, n in plan:
+            tables[sl, :need[sl]] = [free.pop() for _ in range(need[sl])]
+            slot[i:i + n] = sl
+            pos[i:i + n] = np.arange(first, first + n)
+            valid[i:i + n] = True
+            i += n
+        for sl, col in holes:
+            tables[sl, col] = -1
+        q = jnp.asarray(rng.standard_normal((t, kvh * rep, d)), dtype)
+        return q, *pools, tuple(jnp.asarray(a) for a in
+                                (tables, slot, pos, valid))
+    return step
+
+
+# what the copies' chain across tiles and slots makes delicate
+_CHAIN_STEPS = {
+    # live slots with empty ones before, between and after them
+    "chain-gaps": (_planned([(1, 9, 1), (4, 6, 7), (5, 300, 1)], 8, 80, 12),
+                   1, 12),
+    "chain-one-live": (_planned([(3, 20, 5)], 5, 8, 8), 2, 8),
+    "chain-none-live": (_planned([], 4, 8, 8), 1, 8),
+    # three blocks, then two, then one, then a chunk of two tiles: a slot's
+    # first block lands in either half of the buffer
+    "chain-odd-even": (_planned([(0, 599, 1), (1, 299, 1), (2, 40, 1),
+                                 (3, 250, 20)], 4, 160, 24), 1, 24),
+    # a -1 entry inside the context, in a block that the slot before it
+    # fetched: in its first block and in its second
+    "chain-hole": (_planned([(0, 30, 1), (2, 280, 1), (3, 10, 3)], 4, 80, 8,
+                            holes=[(2, 1), (2, 66), (3, 0)]), 2, 8),
+    # the budget's last tile is clamped back inside q, after a fetch ahead
+    "chain-clamped": (_planned([(0, 5, 3), (1, 8, 14)], 2, 8, 17), 1, 17),
+    # contexts of exactly one page, one block, and one slot past a block
+    "chain-edges": (_planned([(0, 3, 1), (1, 15, 1), (2, 255, 1),
+                              (3, 256, 1), (4, 240, 17)], 5, 80, 24), 1, 24),
+}
+
+
 @pytest.mark.parametrize("step,rep,dtype,t,tol", [
     (_mixed_step, 1, jnp.float32, 24, 2e-5),      # MHA
     (_mixed_step, 4, jnp.float32, 24, 2e-5),      # GQA, Mistral's ratio
@@ -204,8 +257,10 @@ def _decode_step(rng, rep, dtype, t, kvh=2, d=8, bs=4):
     (_mixed_step, 4, jnp.bfloat16, 40, 2e-2),
     (_decode_step, 1, jnp.float32, 10, 2e-5),
     (_decode_step, 2, jnp.float32, 10, 2e-5),
+    *((step, rep, jnp.float32, t, 2e-5)
+      for step, rep, t in _CHAIN_STEPS.values()),
 ], ids=["mixed-mha", "mixed-gqa4", "mixed-gqa2-clamped", "mixed-mha-bf16",
-        "mixed-gqa4-bf16", "decode-mha", "decode-gqa2"])
+        "mixed-gqa4-bf16", "decode-mha", "decode-gqa2", *_CHAIN_STEPS])
 def test_paged_kernel_matches_reference(monkeypatch, step, rep, dtype, t,
                                         tol):
     from paddle_tpu.kernels import ragged_pallas as rp
@@ -228,11 +283,15 @@ def test_seq_meta_is_the_plan():
     from paddle_tpu.kernels import ragged_pallas as rp
     _, _, _, (tables, slot, pos, valid) = _mixed_step(
         np.random.default_rng(0), 1, jnp.float32, 24)
-    starts, counts, ctx = rp.seq_meta(slot, pos, valid, tables.shape[0])
+    starts, counts, ctx, live_from = rp.seq_meta(slot, pos, valid,
+                                                 tables.shape[0])
     assert counts.tolist() == [1, 7, 3, 0, 6]
     assert [s for s, n in zip(starts.tolist(), counts.tolist()) if n] \
         == [0, 1, 8, 11]
     assert ctx.tolist() == [10, 13, 20, 0, 15]
+    # the chain the kernel's copies follow: the first live slot from each
+    # slot on, 5 (no slot) past the last
+    assert live_from.tolist() == [0, 1, 2, 4, 4, 5]
 
 
 def test_attention_path_is_chosen_by_backend_mesh_and_geometry(monkeypatch):

@@ -140,7 +140,8 @@ def test_engine_emits_each_phase_once_a_step_with_the_plans_counts(
         # only the counts a metric reads ride on the spans
         assert set(a) == {"prefill_tokens", "decode_tokens",
                           "first_scheduled", "first_wait_s",
-                          "pages_walked", "pages_tabled", "layer_visits"}
+                          "pages_walked", "pages_tabled", "attn_tiles",
+                          "attn_tiles_ahead", "layer_visits"}
         assert int(a["layer_visits"]) == 2     # one visit a layer
         assert not any(s.get("args") for s in inside if s is not run)
     runs = [s["args"] for s in spans if s["name"] == "serve.run"]

@@ -187,39 +187,47 @@ def case_gmm():
     return (t, dm, dff, e), dict(zip(("out", "dx", "dw"), zip(got, want)))
 
 
-def paged_step(entries, pages, kvh, rep, rows, slots, table, plan, seed=0,
-               d=128, pools=2):
-    """One packed serving step at a cell's geometry, as ``_pack_plan``
-    lays it out: ``plan`` is (rows, context) per scheduled sequence, one
-    page-table slot each from slot 1 on (slot 0 stays idle), live pages
-    drawn from a shuffled entry of ``pages`` pages; the rest of the budget
-    is padding rows. The pools are the ``entries`` cache entries' pages
-    joined into one run, as the step program threads them, and the tables
-    point into the LAST entry, the farthest page offset ``make_attend``
-    applies. Returns (q, k_pool, v_pool, (tables, slot_ids, positions,
-    valid)); with ``pools`` 1 (a latent cache) ``v_pool`` is None."""
+def packed_plan(entries, pages, rows, slots, table, plan, seed=0,
+                first_slot=1):
+    """(tables, slot_ids, positions, valid) of one packed serving step, as
+    ``_pack_plan`` lays it out: ``plan`` is (rows, context) per scheduled
+    sequence, one page-table slot each from ``first_slot`` on, live pages
+    drawn from a shuffled entry of ``pages`` pages (drawn again from its
+    start if the plan holds more); the rest of the budget is padding rows.
+    The tables point into the LAST of ``entries`` entries joined into one
+    pool, the farthest page offset ``make_attend`` applies."""
     bs = 16
-    rng = np.random.default_rng(seed)
+    perm = np.random.default_rng(seed).permutation(pages)
     tables = np.full((slots, table), -1, np.int32)
     slot = np.zeros(rows, np.int32)
     pos = np.zeros(rows, np.int32)
     valid = np.zeros(rows, bool)
-    perm, at, row = rng.permutation(pages), 0, 0
-    for s_, (n, ctx) in enumerate(plan, start=1):
+    at, row = 0, 0
+    for s_, (n, ctx) in enumerate(plan, start=first_slot):
         need = -(-ctx // bs)
-        tables[s_, :need] = (entries - 1) * pages + perm[at:at + need]
+        tables[s_, :need] = (entries - 1) * pages \
+            + perm[np.arange(at, at + need) % pages]
         at += need
         slot[row:row + n] = s_
         pos[row:row + n] = np.arange(ctx - n, ctx)
         valid[row:row + n] = True
         row += n
+    return tuple(jnp.asarray(a) for a in (tables, slot, pos, valid))
+
+
+def paged_step(entries, pages, kvh, rep, rows, slots, table, plan, seed=0,
+               d=128, pools=2):
+    """``packed_plan`` with its operands: (q, k_pool, v_pool, the plan).
+    The pools are the ``entries`` cache entries' pages joined into one
+    run, as the step program threads them; with ``pools`` 1 (a latent
+    cache) ``v_pool`` is None."""
     q = rand(seed, (rows, kvh * rep, d))
     # under jit: a pool of gigabytes is drawn with no float32 copy beside it
     kp, vp = (jax.jit(rand, static_argnums=(0, 1))(
-        seed + k, (entries * pages, kvh, bs, d)) if k <= pools else None
+        seed + k, (entries * pages, kvh, 16, d)) if k <= pools else None
         for k in (1, 2))
-    return q, kp, vp, tuple(jnp.asarray(a) for a in
-                            (tables, slot, pos, valid))
+    return q, kp, vp, packed_plan(entries, pages, rows, slots, table, plan,
+                                  seed)
 
 
 # (cache entries, pages an entry, KV heads, query heads a KV head, token
