@@ -14,7 +14,7 @@ import gc
 import numpy as np
 
 from ..lib import lengths, serving
-from ..lib.window import TraceSlice, clock, memory_peak_bytes
+from ..lib.window import clock, memory_peak_bytes
 
 
 class Clients:
@@ -52,8 +52,8 @@ def run(cell, args, run):
 
     for _ in range(t["fill_steps"]):
         step(False)
+    slice_ = serving.traced(args, run, lambda: step(False))   # 3 more, traced
     fill = len(book.steps)
-    slice_ = TraceSlice(args.trace, run.trace_dir, args.seconds)
     run.open_window()
     t0 = clock()
     while True:
@@ -72,9 +72,9 @@ def run(cell, args, run):
     # a traced run takes its gaps from before the profiler's first stall
     gaps = serving.gaps_between_tokens(clients.records, t0,
                                        slice_.quiet_end(t_end))
-    finished = [r for r in clients.records
-                if r.handle is not None and r.handle.done and r.times
-                and r.times[-1] >= t0]
+    in_window = lambda r: bool(r.handle is not None and r.handle.done
+                               and r.times and r.times[-1] >= t0)
+    finished = [r for r in clients.records if in_window(r)]
     measured = {
         "serve_tok_s": out_tokens / window,
         "itl_p95_s": float(np.percentile(gaps, 95)),
@@ -84,7 +84,12 @@ def run(cell, args, run):
         "steps": book.steps[fill:], "slice": (slice_.first_step, slice_.last_step),
     }
     failed = sum(r.failed for r in clients.records)
-    seqs = serving.sequences(serving.sample(finished, args.seed, t["check_requests"]))
+    # compared: drawn from the first half of the fill, in flight when the
+    # window opens with at most half their outputs to go, so a window at half
+    # this speed still finishes them and two runs of a seed compare the same
+    # tokens; which of them ended before the window is a matter of steps
+    sure = [r for r in clients.records[:clients.n // 2] if in_window(r)]
+    seqs = serving.sequences(serving.sample(sure, args.seed, t["check_requests"]))
     del engine, book.engine, book
     gc.collect()
     numbers = serving.compare_served(cell, args.seed, seqs, run)

@@ -17,7 +17,7 @@ import time
 import numpy as np
 
 from ..lib import lengths, serving
-from ..lib.window import TraceSlice, clock, memory_peak_bytes
+from ..lib.window import clock, memory_peak_bytes
 
 
 def schedule(traffic, vocab, seconds, seed):
@@ -56,7 +56,7 @@ def run(cell, args, run):
     serving.warm(engine, cfg["vocab_size"], prof)
     records = schedule(t, cfg["vocab_size"], args.seconds, args.seed)
     book = serving.Book(engine, prof)
-    slice_ = TraceSlice(args.trace, run.trace_dir, args.seconds)
+    slice_ = serving.traced(args, run, *serving.warm_again(engine, cfg["vocab_size"], prof))
     run.open_window()
     t0, t_end = drive(book, records, args.seconds, slice_)
     run.close_window()

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import system, weights as W
-from .window import clock
+from .window import TraceSlice, clock
 
 
 def build_engine(cell, seed):
@@ -122,9 +122,50 @@ def gaps_between_tokens(records, t_from, t_to):
     return np.concatenate(out) if out else np.zeros(0)
 
 
+def warm_again(engine, vocab, prof):
+    """``(step, drain)`` for ``traced``: two warm-up requests once more (other
+    ids: no prefix hit), submitted when ``step`` is first called, so that an
+    untraced run, which never calls it, submits nothing."""
+    made = []
+
+    def book():
+        if not made:
+            rng = np.random.default_rng(1)
+            made.append(Book(engine, prof))
+            for n in (40, 70):
+                made[0].submit(Record(rng.integers(0, vocab, n).tolist(), 4, 0.0))
+        return made[0]
+
+    def drain():
+        while engine.has_work():
+            book().step(True)
+
+    return (lambda: book().step(True)), drain
+
+
+def traced(args, run, step, drain=None):
+    """The run's profiler slice. In a traced run ``TraceSlice.calibrate``
+    first traces three calls of ``step`` (an engine step on the cell's own
+    program: one fixed shape, so any step leaves the events a window's step
+    does), counts what the export holds of them and sets the slice's limit by
+    steps; ``drain`` then empties the engine again; the count and the limit go
+    into the run's notes. An untraced run makes the slice and nothing else."""
+    slice_ = TraceSlice(args.trace, run.trace_dir, args.seconds)
+    if slice_.on:
+        slice_.calibrate(step)
+        if drain is not None:
+            drain()
+        run.note(trace_events_a_step=slice_.events_a_step,
+                 trace_max_steps=slice_.max_steps)
+    return slice_
+
+
 # -- the comparison ------------------------------------------------------------
 def sample(records, seed, k):
-    """``k`` finished requests drawn from the seed, the longest among them."""
+    """``k`` of ``records`` drawn from the seed, the longest among them; of
+    those only the ones that finished whole. Hand it requests that EVERY run
+    finishes, in the order they were submitted: the draw is then the same in
+    two runs of one seed, however many requests each window got through."""
     done = [r for r in records if r.handle is not None and r.handle.done
             and not r.failed and len(r.handle.output) == r.new]
     if not done:

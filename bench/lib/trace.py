@@ -22,12 +22,20 @@ from collections import defaultdict
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 GAP_FLOOR_US = 20.0        # shorter breaks between operations are not "idle"
+STEP_SPANS = ("bench.engine_step", "bench.train_step")   # one a step, the kinds'
 
 
 def find(trace_dir: str):
     found = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.trace.json.gz")))
     return found[-1] if found else None
+
+
+def count_events(path: str) -> int:
+    """The events the export holds, as its cap counts them: all but the
+    metadata."""
+    with gzip.open(path) as f:
+        return sum(1 for e in json.load(f)["traceEvents"] if e.get("ph") != "M")
 
 
 def load(path: str) -> dict:
@@ -133,10 +141,52 @@ def idle_gaps(ops, spans, start, end, n=10):
     return [[k, by[k] / 1e6] for k in best]
 
 
-def reduce_dir(trace_dir: str, window_s: float) -> dict:
+def kept(ctx, module):
+    """What the trace kept of the slice's steps, for every reader that divides
+    by steps or by time. The profiler's export keeps the earliest million
+    events (PERF.md section 7), so a slice may be cut: this counts the
+    executions of the step program (``module``: the start of its name on the
+    ``XLA Modules`` line) that the trace holds and pairs them with the first
+    that many steps of the slice that ran a plan. Where the trace holds fewer
+    executions than the slice has steps it was cut, and the last execution may
+    have lost operations: it is left out. Returns None where nothing was kept,
+    else
+
+      steps     the kept steps' records, as the kinds keep them
+      seconds   the kept executions' summed device seconds
+      busy_s    seconds in which an operation ran, from the first kept
+                execution's start to the start of the one after the last
+                (to the last operation's end in an uncut trace): whole
+                periods, the argmax and check programs between steps in them
+      cut       whether the trace was cut
+
+    Nothing here is divided by the slice's length."""
+    t, m = ctx["trace"], ctx["measured"]
+    first, last = m.get("slice", (None, None))
+    if first is None or last is None or last <= first:
+        return None
+    steps = [s for s in m["steps"][first:last] if s[2] > 0]
+    runs = sorted((ts, d) for ts, d, n in t["modules"] if n.startswith(module))
+    cut = len(runs) < len(steps)
+    n = min(len(runs) - 1 if cut else len(runs), len(steps))
+    if n <= 0:
+        return None
+    lo = runs[0][0]
+    hi = runs[n][0] if n < len(runs) else max(op[0] + op[1] for op in t["ops"])
+    return {"steps": steps[:n], "seconds": sum(d for _, d in runs[:n]) / 1e6,
+            "busy_s": busy_us([op for op in t["ops"] if lo <= op[0] < hi]) / 1e6,
+            "cut": cut}
+
+
+def reduce_dir(trace_dir: str, window_s: float, steps: int = None) -> dict:
     """Everything the readers and the result line take from one trace: the
     one device's. (A cell across chips brings the reduction over several
-    devices with it: PERF.md section 7.)"""
+    devices with it: PERF.md section 7.) ``window_s`` is the slice's length
+    on the host's clock and ``steps`` the steps the kind ran in it. Where the
+    trace holds fewer of the kinds' step spans (``STEP_SPANS``) than that, the
+    export dropped the slice's end (``trace_cut``): the window is then from
+    the first kept step's start to the last kept one's, busy time is taken
+    inside it, and the last step, which may have lost operations, is outside."""
     path = find(trace_dir)
     if path is None:
         raise RuntimeError(f"the profiler left no trace under {trace_dir}")
@@ -150,12 +200,21 @@ def reduce_dir(trace_dir: str, window_s: float) -> dict:
         raise RuntimeError("the trace holds no device operation")
     lo = min(op[0] for op in ops)
     hi = max(op[0] + op[1] for op in ops)
+    step_starts = [s[0] for s in t["spans"] if s[2] in STEP_SPANS]
+    cut = steps is not None and 1 < len(step_starts) < steps
+    inside = ops
+    if cut:
+        lo, hi = step_starts[0], step_starts[-1]
+        window_s = (hi - lo) / 1e6
+        inside = [op for op in ops if lo <= op[0] < hi]
     return {
         "window_s": window_s,
-        "busy_s": busy_us(ops) / 1e6,
+        "busy_s": busy_us(inside) / 1e6,
+        "trace_cut": cut,
+        "steps_kept": len(step_starts) - 1 if cut else len(step_starts),
         "ops": ops,
         "modules": dev["modules"],
         "spans": t["spans"],
         "top_ops": top_ops(ops),
-        "idle_gaps": idle_gaps(ops, t["spans"], lo, hi),
+        "idle_gaps": idle_gaps(inside, t["spans"], lo, hi),
     }

@@ -1,4 +1,6 @@
-"""Share of the traced slice in which no operation ran on the device."""
+"""Share of the traced slice in which no operation ran on the device. For
+training, whose steps leave 4,000 events each and are never cut by the
+export; the serving cells read ``serve_step``'s ``idle`` over the kept steps."""
 
 
 def read(ctx, name):

@@ -5,9 +5,8 @@ stored without its padding), and absorbed attention's operations over each
 row's live context (``attention_flops``), the larger of bytes over the peak
 bandwidth and operations over the peak rate, over the device time of the
 kernel the metric file names, over the steps the trace kept
-(``kept_steps.kept``). No such kernel in the trace: reads nothing."""
-from bench.lib import spans as S
-from bench.readers import kept_steps
+(``bench.lib.trace.kept``). No such kernel in the trace: reads nothing."""
+from bench.lib import spans as S, trace as T
 
 
 def read(ctx, name):
@@ -17,12 +16,12 @@ def read(ctx, name):
     if not t:
         return None
     us = S.time_in(t, [spec["kernel"]])
-    got = kept_steps.kept(ctx, spec["module"])
+    got = T.kept(ctx, spec["module"])
     runs = sum(1 for _, _, n in ctx["trace"]["modules"]
                if n.startswith(spec["module"]))
     if us <= 0 or got is None or not runs:
         return None
-    _, steps = got
+    steps = got["steps"]
     seconds = us / 1e6 * len(steps) / runs
     moved = arch.kv_bytes_per_token(cfg) * sum(s[5] for s in steps) \
         / ctx["peaks"]["hbm_bytes_per_s"]

@@ -100,7 +100,7 @@ def collect(cell, args, run, out, device):
                                   "unit": m["unit"]}
     else:
         sl = out["trace"]
-        reduced = tracelib.reduce_dir(sl.dir, sl.t1 - sl.t0)
+        reduced = tracelib.reduce_dir(sl.dir, sl.t1 - sl.t0, sl.last_step - sl.first_step)
         ctx = {"cell": cell, "arch": cell.arch(), "measured": measured,
                "trace": reduced, "notes": run.notes,
                "peaks": spec.peaks(device["kind"])}
@@ -108,8 +108,8 @@ def collect(cell, args, run, out, device):
             value = cell.reader(m["name"])(ctx, m["name"])
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-        result["device"]["busy_s"] = reduced["busy_s"]
-        result["device"]["window_s"] = reduced["window_s"]
+        for key in ("busy_s", "window_s", "trace_cut", "steps_kept"):
+            result["device"][key] = reduced[key]
         result["breakdown"] = {"device_ops": reduced["top_ops"],
                                "idle_gaps": reduced["idle_gaps"]}
     result["compared"] = compared       # last: each number beside its limit

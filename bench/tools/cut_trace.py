@@ -71,6 +71,14 @@ def cut(events, skip, steps):
     return meta + out
 
 
+def earliest(events, n):
+    """What the profiler's export does to a trace of more than a million
+    events: the metadata, and the ``n`` events that start first."""
+    timed = sorted((e for e in events if e.get("ph") != "M"),
+                   key=lambda e: e["ts"])[:n]
+    return [e for e in events if e.get("ph") == "M"] + timed
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("trace_dir")

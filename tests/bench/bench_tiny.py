@@ -34,10 +34,13 @@ CHAT = {"kind": "open-loop-paced", "engine": ENGINE, "rate": 8.0, "tail_s": 1.0,
         "output_len": {"shape": "lognormal", "median": 8, "sigma": 0.5,
                        "min": 4, "max": 16},
         "check_requests": 3}
-CLOSED = {"kind": "closed-loop", "engine": ENGINE, "clients": 4, "requests": 12,
-          "fill_steps": 4, "order_seed": 3,
+# eight callers: the comparison draws from the first half of the fill, whose
+# outputs the kind cuts to an eighth .. a half, so the outputs are long enough
+# for four of them to hold some thirty tokens
+CLOSED = {"kind": "closed-loop", "engine": dict(ENGINE, max_seqs=8, num_blocks=96),
+          "clients": 8, "requests": 24, "fill_steps": 4, "order_seed": 3,
           "prompt_len": {"shape": "uniform", "min": 8, "max": 24},
-          "output_len": {"shape": "uniform", "min": 6, "max": 14},
+          "output_len": {"shape": "uniform", "min": 12, "max": 40},
           "check_requests": 3}
 # set from readings at these sizes on the CPU: bfloat16 runs read loss gaps of
 # 5e-6..5e-5, gradient gaps of 0.001..0.005 and change gaps of 0.001..0.012
@@ -104,3 +107,34 @@ def args(seed=1, seconds=1.5, trace=0):
 
 
 DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}
+PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+
+
+def recorded_steps(path):
+    """The step records a kind would have kept for the steps of a recorded
+    trace, from its ``serve.run`` spans: rows from their counts; each
+    sequence's live context taken as its walked pages, whole: an overcount."""
+    from bench.lib import spans as S
+    steps = []
+    for run in S.named(S.load(path), "serve.run"):
+        a = run[3]
+        rows = int(a["prefill_tokens"]) + int(a["decode_tokens"])
+        live = 16 * int(a.get("pages_walked", 0))
+        steps.append((0.0, 0.0, rows, int(a["decode_tokens"]), live, live,
+                      None, 0))
+    return steps
+
+
+def recorded_context(monkeypatch, path, cell, steps=None, **quiet):
+    """What ``run.collect`` hands a reader, over a recorded trace: the
+    reduction of the one device, and a slice whose steps are ``steps``
+    (``recorded_steps`` of the trace itself unless given: a run's records do
+    not shrink when the export is cut); ``quiet``: what the kind measured on
+    the host's clock before the profiler started."""
+    from bench.lib import trace as T
+    monkeypatch.setattr(T, "find", lambda _dir: path)
+    steps = recorded_steps(path) if steps is None else steps
+    reduced = T.reduce_dir("/nowhere", 1.0, len(steps))
+    return {"cell": cell, "arch": cell.arch(), "trace": reduced, "notes": {},
+            "measured": dict(quiet, steps=steps, slice=(0, len(steps))),
+            "peaks": PEAKS}

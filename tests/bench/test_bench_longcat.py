@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 import bench_tiny as tiny
+import bench_contract as contract
 import longcat_tiny
 from bench import run as R
 from bench.archs import longcat_flash as arch
@@ -36,27 +37,12 @@ def real_cell():
 
 # -- the new cell's files ---------------------------------------------------------
 def test_the_new_cells_files_load_and_name_each_other():
-    cell = real_cell()
-    assert cell.arch() is arch and cell.chips == 1
-    assert cell.kind().__name__ == "bench.kinds.closed_loop"
-    assert importlib.import_module(arch.REFERENCE) is ref
-    assert {m["name"] for m in cell.end_to_end()} == {"serve_tok_s", "setup_s"}
-    mine = cell.per_layer()
-    assert len(mine) == 23 and all(m["moves"] == "serve_tok_s" and
-                                   m["workloads"] == [CELL] for m in mine)
-    for m in mine:
-        f = cell.metric_file(m["name"])
-        assert (f["unit"], f["layer"], f["moves"]) == (
-            m["unit"], m["layer"], m["moves"])
-        assert callable(cell.reader(m["name"]))
-    # on no list that was there: those move metrics this cell does not report
-    for m in cell.benchmark["per_layer"]:
-        assert (CELL in m.get("workloads", ())) == (m["moves"] == "serve_tok_s")
-    limits = json.load(open(os.path.join(
-        tiny.REPO, "bench", "limits", CELL + ".json")))["limits"]
-    assert set(limits) == {"token_gap_max", "token_gap_mean"}
-    for name in {n for n, _ in arch.walk(cell.config)} | {"embed", "head"}:
-        assert callable(getattr(ref, name))
+    """``contract.batch_cell``: the cell's 23 metrics of PR 34 by name and
+    what later PRs gave it, each moving ``serve_tok_s`` and listing the cell,
+    its files and its limits; the cell on no list whose ``moves`` it does not
+    report."""
+    cell = contract.batch_cell(tiny.REPO)
+    assert cell.arch() is arch
 
 
 def test_the_configuration_is_the_catalogs_with_two_keys_reduced():
@@ -212,39 +198,18 @@ def test_a_stand_in_is_not_correct_on_three_seeds(root, stand_in):
 # -- the new readers on two steps of a traced run on a v5e -----------------------
 TWO_STEPS = os.path.join(tiny.DATA, "longcat_two_steps.trace.json.gz")
 CHAT = os.path.join(tiny.DATA, "chat_two_steps.trace.json.gz")      # PR 26
-PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-
-
-def context(monkeypatch, path, cell, **quiet):
-    """What ``run.collect`` hands a reader, over a recorded trace: the
-    reduction of the one device, and a slice whose steps are those of the
-    trace's ``serve.run`` spans (rows from their counts; each sequence's
-    live context taken as its walked pages, whole: an overcount);
-    ``quiet``: what the kind measured on the host's clock before the
-    profiler started."""
-    monkeypatch.setattr(T, "find", lambda _dir: path)
-    reduced = T.reduce_dir("/nowhere", 1.0)
-    steps = []
-    for run in S.named(S.load(path), "serve.run"):
-        a = run[3]
-        rows = int(a["prefill_tokens"]) + int(a["decode_tokens"])
-        live = 16 * int(a.get("pages_walked", 0))
-        steps.append((0.0, 0.0, rows, int(a["decode_tokens"]), live, live,
-                      None, 0))
-    return {"cell": cell, "arch": cell.arch(), "trace": reduced, "notes": {},
-            "measured": dict(quiet, steps=steps, slice=(0, len(steps))),
-            "peaks": PEAKS}
 
 
 def test_the_new_readers_read_two_recorded_steps(monkeypatch):
     cell = real_cell()
     # the run these steps were cut from: a gap of 34.2 ms untraced
     quiet = {"itl_mean_s": 0.0342, "itl_p95_s": 0.0357, "engine_step_s": 0.0337}
-    ctx = context(monkeypatch, TWO_STEPS, cell, **quiet)
+    ctx = tiny.recorded_context(monkeypatch, TWO_STEPS, cell, **quiet)
     assert len(ctx["measured"]["steps"]) == 2
     got = {m["name"]: cell.reader(m["name"])(ctx, m["name"])
            for m in cell.per_layer()}
-    host = {"batch_pool_live_share"}             # sampled a step by the kind
+    # sampled a step by the kind, or a counter the recording (PR 34) lacks
+    host = {"batch_pool_live_share", "attn_tiles_ahead_share.batch.serve"}
     assert {n for n, v in got.items() if v is None} == host
     assert (got["batch_gap_mean_s"], got["batch_gap_p95_s"],
             got["batch_step_s"]) == tuple(quiet.values())
@@ -289,7 +254,7 @@ def test_the_new_readers_read_nothing_from_a_program_without_them(monkeypatch):
     (the chat cell's recorded steps stand for it), and another architecture
     has no expert layer: every new reader answers None and raises nothing."""
     cell = real_cell()
-    ctx = context(monkeypatch, CHAT, cell)
+    ctx = tiny.recorded_context(monkeypatch, CHAT, cell)
     new = ("step_mfu.batch.serve", "step_hbm_roofline.batch.serve",
            "moe_experts_roofline.serve", "latent_attn_roofline.serve",
            "moe_experts_time_share.serve", "moe_route_time_share.serve",

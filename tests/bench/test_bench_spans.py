@@ -332,7 +332,9 @@ NEW_SERVE = {"step_schedule_s", "step_prepare_s", "step_emit_s",
              "paged_attn_time_share.serve", "kv_write_time_share.serve",
              "dense_time_share.serve", "paged_attn_inferred_share.serve",
              "kv_write_inferred_share.serve", "dense_inferred_share.serve"}
-NEW_CHAT = NEW_SERVE | {"sched_queue_wait_mean_s", "sched_prefill_token_share"}
+# chat_ttft_mean_s: the mean the cell was judged on until PR 36, per layer since
+NEW_CHAT = NEW_SERVE | {"sched_queue_wait_mean_s", "sched_prefill_token_share",
+                        "chat_ttft_mean_s"}
 NEW_TRAIN = {"flash_fwd_roofline", "flash_bwd_roofline",
              "recompute_time_share.train", "head_loss_time_share.train",
              "recompute_inferred_share.train", "head_loss_inferred_share.train"}

@@ -3,11 +3,11 @@ operation and byte counts against hand-worked numbers, the request sets, the
 peaks table, and BENCHMARK.json against the limits of its contract."""
 import json
 import os
-import re
 
 import pytest
 
 import bench_tiny as tiny
+import bench_contract as contract
 from bench.archs import gpt2, llama
 from bench.lib import flops, lengths, spec, trace as T
 
@@ -72,6 +72,85 @@ def test_recorded_trace_two_training_steps_on_a_v5e():
     top = T.top_ops(ops, 3)
     assert top[0][0].startswith("custom-call bf16[64,2048,128]")
     assert top[0][1] > top[1][1] > top[2][1]
+
+
+# -- the profiler's slice -----------------------------------------------------------
+class Clock:
+    def __init__(self):
+        self.now = 100.0
+
+    def __call__(self):
+        return self.now
+
+
+@pytest.fixture
+def profiler(monkeypatch):
+    """``bench.lib.window`` with the profiler's start and stop recorded and
+    the clock in the test's hand."""
+    from bench.lib import window
+    calls, clock = [], Clock()
+    monkeypatch.setattr(window, "start_profiler", lambda d: calls.append(("start", d)))
+    monkeypatch.setattr(window.jax.profiler, "stop_trace", lambda: calls.append(("stop",)))
+    monkeypatch.setattr(window, "clock", clock)
+    return window, calls, clock
+
+
+def test_the_slice_ends_by_steps_as_well_as_by_seconds(profiler, tmp_path):
+    window, calls, clock = profiler
+    sl = window.TraceSlice(True, str(tmp_path / "t"), seconds=45.0)
+    assert (sl.start_at, sl.length, sl.max_steps) == (27.0, 8.0, None)
+    sl.max_steps = 45                 # 20,000 events a step: the looped cell
+    sl.boundary(26.9, 530)
+    assert calls == []
+    sl.boundary(27.1, 534)
+    assert calls == [("start", sl.dir)] and sl.first_step == 534
+    for step in range(535, 579):      # 44 steps of 50 ms: 2.2 of the 8 s
+        clock.now += 0.05
+        sl.boundary(27.1 + clock.now - 100.0, step)
+    assert len(calls) == 1
+    clock.now += 0.05
+    sl.boundary(29.4, 579)            # the 45th step has run
+    assert calls[-1] == ("stop",) and (sl.first_step, sl.last_step) == (534, 579)
+    sl.boundary(40.0, 800)
+    sl.stop(900)
+    assert len(calls) == 2 and sl.last_step == 579
+    assert sl.quiet_end(window_end=150.0) == 100.0   # when the profiler started
+    # no limit by steps (training, 4,000 events a step): the 8 seconds end it
+    calls.clear()
+    by_time = window.TraceSlice(True, str(tmp_path / "u"), seconds=45.0)
+    by_time.boundary(27.0, 10)
+    clock.now += 7.9
+    by_time.boundary(34.9, 18)
+    assert len(calls) == 1
+    clock.now += 0.2
+    by_time.boundary(35.1, 19)
+    assert calls[-1] == ("stop",) and by_time.last_step == 19
+    off = window.TraceSlice(False, str(tmp_path / "v"), seconds=45.0)
+    off.calibrate(lambda: 1 / 0)      # never called
+    off.boundary(30.0, 5)
+    assert off.t0 is None and off.quiet_end(150.0) == 150.0 and len(calls) == 2
+
+
+def test_the_slices_steps_come_from_the_events_a_step_leaves(profiler, monkeypatch,
+                                                            tmp_path):
+    """``calibrate`` traces a few steps in set-up and counts what the export
+    holds of them, as its cap counts: every event but the metadata."""
+    window, calls, _ = profiler
+    chat = os.path.join(tiny.DATA, "chat_two_steps.trace.json.gz")
+    monkeypatch.setattr(T, "find", lambda d: chat if d.endswith(".calibration") else None)
+    ran = []
+    sl = window.TraceSlice(True, str(tmp_path / "t"), seconds=45.0)
+    sl.calibrate(lambda: ran.append(1), steps=2)
+    assert calls == [("start", sl.dir + ".calibration"), ("stop",)] and len(ran) == 2
+    events = T.count_events(chat)
+    assert 4000 < events < 5000 and sl.events_a_step == events / 2
+    assert sl.max_steps == int(window.SLICE_EVENTS / sl.events_a_step)
+    assert sl.max_steps * sl.events_a_step <= 900_000 < (sl.max_steps + 1) * sl.events_a_step
+    # no export to count (the CPU of the tests may leave none): no limit
+    monkeypatch.setattr(T, "find", lambda d: None)
+    blind = window.TraceSlice(True, str(tmp_path / "u"), seconds=45.0)
+    blind.calibrate(lambda: None)
+    assert blind.max_steps is None
 
 
 # -- operations and bytes, by hand ----------------------------------------------
@@ -139,6 +218,29 @@ def test_same_requests_in_the_same_order_for_every_seed():
     assert len(firsts) == 90                       # no shared first page
 
 
+@pytest.mark.parametrize("kind", ["closed-loop", "open-loop-paced", "train-batches"])
+def test_token_ids_are_drawn_under_the_configurations_own_vocabulary(kind):
+    """A sliced vocabulary is a smaller vocabulary: every kind draws its ids
+    under the ``vocab_size`` the configuration's file holds (here a quarter
+    of a published 131,072), so traffic, logits and comparison are over the
+    slice alike."""
+    from bench.kinds import closed_loop, open_loop_paced, train_batches
+    held = 131072 // 4
+    seed = 2**31 + 36
+    if kind == "closed-loop":
+        mix = json.load(open(os.path.join(tiny.REPO, "bench", "traffic",
+                                          "decode-closed16.json")))
+        ids = [i for p, _ in closed_loop.Clients(mix, held, seed).reqs for i in p]
+    elif kind == "open-loop-paced":
+        ids = [i for r in open_loop_paced.schedule(CHAT, held, 45.0, seed)
+               for i in r.prompt]
+    else:
+        mix = {"batch": 2, "seq": 2048}
+        ids = train_batches.Feed(mix, held, seed).next().ravel().tolist()
+    assert len(ids) > 1000 and min(ids) >= 0
+    assert 0.9 * held < max(ids) < held
+
+
 @pytest.mark.parametrize("key", ["order_seed", "jitter"])
 def test_a_mix_states_its_order_and_its_arrivals(key):
     """No default for either: a traffic file that leaves one out is refused."""
@@ -179,58 +281,40 @@ def test_peaks_by_exact_device_kind():
         spec.peaks("cpu")
 
 
-NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}\Z")
-
-
 def test_benchmark_json_keeps_to_its_contract():
-    bm = json.load(open(os.path.join(tiny.REPO, "BENCHMARK.json")))
-    assert set(bm) == {"command", "paths", "run_seconds", "configs",
-                       "workloads", "end_to_end", "per_layer"}
-    assert 1 <= bm["run_seconds"] <= 51
-    cells = [w["name"] for w in bm["workloads"]]
-    configs = [c["name"] for c in bm["configs"]]
-    assert len(set(cells)) == len(cells) and len(set(configs)) == len(configs)
-    assert {w["config"] for w in bm["workloads"]} == set(configs)
-    assert len({(w["config"], w["traffic"]) for w in bm["workloads"]}) == len(cells)
-    assert sum(w["chips"] == 4 for w in bm["workloads"]) <= max(1, len(cells) // 4)
-    for c in bm["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert os.path.exists(os.path.join(tiny.REPO, c["file"]))
-        assert len(c["why"]) <= 200 and NAME.match(c["name"])
-        held = json.load(open(os.path.join(tiny.REPO, c["file"])))
-        assert set(c["reduced"]) == set(held["reduced"])
-        for key in c["reduced"]:
-            assert not key.endswith(("_dim", "_rank", "_size")), key
-            assert held[key] != held["published"][key]
-    for w in bm["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert len(w["why"]) <= 200 and NAME.match(w["name"]) and NAME.match(w["traffic"])
-        assert os.path.exists(os.path.join(
-            tiny.REPO, "bench", "traffic", w["traffic"] + ".json"))
-        assert os.path.exists(os.path.join(
-            tiny.REPO, "bench", "limits", w["name"] + ".json"))
-    e2e = {m["name"]: m for m in bm["end_to_end"]}
-    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.1
-    for m in bm["end_to_end"]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
-        assert 0.01 <= m["bound"] <= 0.1 and m["source"] in ("host_clock", "device_trace")
-    layers = set()
-    for m in bm["per_layer"]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
-                                          "layer", "moves"}
-        assert NAME.match(m["name"]) and re.fullmatch(r"[A-Za-z0-9_/%.\-]{1,16}", m["unit"])
-        assert m["moves"] in e2e and m["better"] in ("lower", "higher")
-        assert os.path.exists(os.path.join(
-            tiny.REPO, "bench", "metrics", m["name"] + ".json"))
-        moved = e2e[m["moves"]].get("workloads", cells)
-        assert set(m.get("workloads", cells)) <= set(moved)
-        assert set(m.get("workloads", cells)) <= set(cells)
-        layers.add(m["layer"])
-    perf = open(os.path.join(tiny.REPO, "PERF.md")).read()
-    for layer in layers:
-        assert layer in perf, f"PERF.md's list of layers lacks {layer!r}"
-    for cell in cells:          # every cell: setup_s, one more, one per-layer
-        mine = [m for m in bm["end_to_end"] if cell in m.get("workloads", cells)]
-        assert len(mine) >= 2
-        assert any(cell in m.get("workloads", cells) for m in bm["per_layer"])
-    assert len(json.dumps(bm)) < 64 * 1024
+    contract.benchmark_json(tiny.REPO)
+
+
+HELD = {"num_hidden_layers": 11, "n_routed_experts": 128, "vocab_size": 32768,
+        "hidden_size": 4096, "moe_topk": 22, "kv_lora_rank": 512,
+        "published": {"num_hidden_layers": 88, "n_routed_experts": 512,
+                      "vocab_size": 131072, "hidden_size": 8192,
+                      "moe_topk": 44, "kv_lora_rank": 1024}}
+
+
+@pytest.mark.parametrize("keys,change,refused", [
+    (["num_hidden_layers", "n_routed_experts", "vocab_size"], {}, None),
+    (["vocab_size"], {"vocab_size": 131072 // 8}, None),     # the floor itself
+    (["vocab_size"], {"vocab_size": 131072 // 16}, "under an eighth"),
+    (["vocab_size"], {"vocab_size": 131072}, "equals the published"),
+    (["n_routed_experts"], {"n_routed_experts": 8}, None),
+    (["n_routed_experts"], {"n_routed_experts": 4}, "under the floor of 8"),
+    (["hidden_size"], {}, "a width may not be cut"),
+    (["kv_lora_rank"], {}, "a width may not be cut"),
+    (["moe_topk"], {}, "a width may not be cut"),
+    (["n_embd"], {"n_embd": 1, "published": {"n_embd": 2}}, "a width may not be cut"),
+])
+def test_reduced_lists_depth_experts_held_and_vocabulary_never_a_width(
+        keys, change, refused):
+    """The ``model-configs`` guide's cut: a width may not be listed in
+    ``reduced``; the vocabulary may, down to an eighth of the published rows,
+    and a count of experts held down to 8."""
+    held = dict(HELD, **change, reduced={k: "test" for k in keys})
+    entry = {"reduced": keys}
+    if refused is None:
+        contract.reduced_keys(entry, held)
+    else:
+        with pytest.raises(AssertionError, match=refused):
+            contract.reduced_keys(entry, held)
+    with pytest.raises(AssertionError, match="list other reduced keys"):
+        contract.reduced_keys({"reduced": keys + ["n_layer"]}, held)
