@@ -1450,10 +1450,12 @@ def _decoder_for(model):
     instances)."""
     from .models.gpt import GPTForCausalLM
     from .models.longcat_flash import LongcatFlashForCausalLM
+    from .models.nemotron_h import NemotronHForCausalLM
     from .models.ouro import OuroForCausalLM
     cls = _GPTDecoder if isinstance(model, GPTForCausalLM) \
         else _OuroDecoder if isinstance(model, OuroForCausalLM) \
         else _LongcatDecoder if isinstance(model, LongcatFlashForCausalLM) \
+        else _NemotronHDecoder if isinstance(model, NemotronHForCausalLM) \
         else _LlamaDecoder
     struct = (cls, model.lm_head is None,    # head tying is baked into the
               _live_moe_struct(model))       # traced logits branch
@@ -1463,6 +1465,257 @@ def _decoder_for(model):
         dec._struct = struct
         model.__dict__["_decode_cache"] = dec
     return dec
+
+
+class _NemotronHDecoder(_LlamaDecoder):
+    """Pure functions over a NemotronHForCausalLM state dict
+    (``models/nemotron_h.py`` has the equations, and every one used here is
+    that file's): each layer ONE part, a Mamba-2 mixer, an attention layer
+    without rotary or an expert layer in a latent width, by
+    ``hybrid_override_pattern``.
+
+    Only the attention layers keep pages (``cache_entries`` counts them).
+    A Mamba layer keeps a STATE a sequence, whatever the context: the
+    recurrence's state and the convolution's last inputs, in two pools
+    ``[state layers, slots, ...]`` that the engine holds beside the page
+    pools by the request's slot (``state_shapes``; None on every other
+    decoder) and threads through ``step_ragged``. A step's rows of one
+    sequence pass through the recurrence in order, from what the slot holds
+    or from nothing where the first row stands at position 0, and leave the
+    slot holding what the next step starts from
+    (``kernels.ssm_pallas.ssm_scan``). The expert layer is dispatched as
+    ``_LongcatDecoder``'s is, over two-bank ``relu2`` experts, and counts
+    under the same names."""
+
+    COUNTERS = _LongcatDecoder.COUNTERS
+
+    def __init__(self, model):
+        from .kernels import ssm_pallas as ssm
+        cfg = model.config
+        self.cfg = cfg
+        self.kinds = cfg.pattern
+        self.n_heads = cfg.num_attention_heads
+        self.n_kv = cfg.num_key_value_heads
+        self.hd = self.v_dim = cfg.head_dim
+        self.eps = cfg.layer_norm_epsilon
+        self.n_layers = cfg.num_hidden_layers
+        self.cache_entries = self.kinds.count("*")
+        self.tied = False
+        self.embed_key = "backbone.embeddings.weight"
+        # what a sequence keeps in each Mamba layer, (shape, dtype; None =
+        # the activations'): the engine's pools are [state_layers, slots]
+        # + shape
+        self.state_layers = self.kinds.count("M")
+        self.state_shapes = (
+            (ssm.pool_shape(cfg.mamba_num_heads, cfg.n_groups,
+                            cfg.mamba_head_dim, cfg.ssm_state_size),
+             "float32"),
+            # the tail's K - 1 inputs side by side in one row: [3, 10240]
+            # would be padded to 16 sublanes a slot, five times its bytes
+            (((cfg.conv_kernel - 1) * cfg.conv_dim,), None))
+
+    def _static_key(self):
+        import dataclasses
+        return (type(self), dataclasses.astuple(self.cfg))
+
+    @staticmethod
+    def weights(model):
+        return {n: t._data for n, t in model.named_state().items()}
+
+    def describe(self):
+        """What ``telemetry()["model"]`` adds for this decoder."""
+        c = self.cfg
+        return {"layer_kinds": self.kinds, "state_layers": self.state_layers,
+                "experts_held": c.experts_held,
+                "experts_published": c.n_routed_experts,
+                "experts_a_token": c.num_experts_per_tok}
+
+    step_counts = _LongcatDecoder.step_counts
+
+    def quant_plan(self):
+        raise NotImplementedError(
+            "weight-only quantization is not offered for Nemotron-H")
+
+    def tp_specs(self):
+        """Replicated: a mesh engine is refused for a decoder with a
+        state (``ServingEngine``)."""
+        return {}
+
+    def step(self, w, tokens, positions, kcs, vcs, write_pos, score_mask):
+        raise NotImplementedError(
+            "generate() carries K/V caches and nothing else; a Nemotron-H "
+            "sequence keeps a recurrent state beside them: serve it "
+            "through paddle_tpu.serving.ServingEngine (submit / step), "
+            "which holds that state by the request's slot")
+
+    # -- pieces ---------------------------------------------------------------
+    @staticmethod
+    def _part(w, i):
+        """Layer ``i``'s mixer: its leaves by their short names."""
+        pre = f"backbone.layers.{i}.mixer."
+        return {n[len(pre):]: a for n, a in w.items() if n.startswith(pre)}
+
+    @jax.named_scope("ssm_conv")
+    def _tap_plan(self, meta, positions):
+        """Where each row's convolution finds its ``K - 1`` earlier inputs,
+        once a step: in the step's rows while they are its sequence's, else
+        in the slot's tail, else (before position 0) nowhere. Returns (for
+        each j = 1 .. K - 1: the row j back, whether it is the sequence's,
+        and for each place in the tail whether that holds the input
+        instead: with ``d`` rows of the sequence before this one in the
+        step, place ``K - 1 - j + d``), each row's slot, and the scheduled
+        sequences' (slots, last rows) for the new tails; an unscheduled
+        entry's slot is one past the pool, which a scatter drops."""
+        k = self.cfg.conv_kernel
+        rows = jnp.arange(positions.shape[0], dtype=jnp.int32)
+        slot = jnp.maximum(meta.slots, 0)
+        slots = meta.order.shape[0]
+        start = jnp.zeros(slots, jnp.int32).at[meta.order].set(meta.starts)
+        since = rows - start[slot]             # rows of mine before this one
+        back = [(jnp.maximum(rows - j, 0), since >= j,
+                 {k - 1 - j + d: (since == d) & (positions >= j)
+                  for d in range(j)})
+                for j in range(1, k)]
+        live = jnp.arange(slots) < meta.n_live
+        return back, slot, (jnp.where(live, meta.order, slots),
+                            jnp.maximum(meta.starts + meta.counts - 1, 0))
+
+    def _mamba(self, w, i, x, m, states, tails, meta, plan, kernel):
+        """Mamba layer ``i``, the ``m``-th of them, on normed rows x [T,
+        hidden]. Returns (y, states', tails')."""
+        from .kernels import ssm_pallas as ssm
+        from .models import nemotron_h as nh
+        cfg, p = self.cfg, self._part(w, i)
+        back, slot, (last_slot, last_row) = plan
+        with jax.named_scope("ssm_proj"):
+            # held once: its three readers stand far apart, and the
+            # compiler would rather compute the product again for each
+            z, xbc, dt_raw = nh.mamba_split(jax.lax.optimization_barrier(
+                x @ p["in_proj.weight"]), cfg)
+        with jax.named_scope("ssm_conv"):
+            c = xbc.shape[1]
+            # [T, (K - 1) * C], read once: a second reading would have to
+            # come before the new tails are written, and so cost a copy of
+            # the pool
+            tail = jax.lax.optimization_barrier(tails[m][slot])
+            taps = [xbc]
+            for row, here, places in back:         # 1 .. K - 1 rows back
+                tap = jnp.where(here[:, None], xbc[row], 0)
+                for at, kept in places.items():
+                    tap = jnp.where(kept[:, None],
+                                    tail[:, at * c:(at + 1) * c], tap)
+                taps.append(tap)
+            taps = jnp.stack(taps[::-1], axis=1)   # oldest first
+            act = nh.conv_act(taps, p["conv1d.weight"], p["conv1d.bias"])
+            tails = tails.at[m, last_slot].set(
+                taps[last_row, 1:].reshape(last_row.shape[0], -1),
+                mode="drop")
+        with jax.named_scope("ssm_scan"):
+            xs, b, c, dt, decay = nh.ssm_terms(act, dt_raw, p, cfg)
+            y, states = ssm.ssm_scan(states, m, xs, b, c, dt, decay, meta,
+                                     kernel=kernel)
+            y = y + (p["D"].astype(jnp.float32)[:, None]
+                     * xs.astype(jnp.float32)).reshape(y.shape)
+        with jax.named_scope("ssm_proj"):
+            y = nh.gated_norm(y, z, p["norm.weight"], cfg)
+            return y @ p["out_proj.weight"], states, tails
+
+    def _attention(self, w, i, x, kf, vf, scatter, attend):
+        """Attention layer ``i`` on normed rows x [T, hidden], on its cache
+        entry of the joined pools. Returns (y, kf', vf')."""
+        from .models import nemotron_h as nh
+        p = self._part(w, i)
+        with jax.named_scope("attn_proj"):
+            q, k, v = nh.attention_rows(x, p, self.cfg)
+        kf, vf = _kv_write_pages(kf, vf, k[:, None], v[:, None], scatter)
+        att = attend(q, kf, vf).reshape(x.shape[0], -1)
+        with jax.named_scope("attn_proj"):
+            return att @ p["o_proj.weight"], kf, vf
+
+    def _moe(self, w, i, x, valid, kernel):
+        """Expert layer ``i`` on normed rows x [T, hidden]; valid: [T]
+        bool, rows that are somebody's (the others are routed nowhere).
+        Returns (y, counters [5] int32)."""
+        from .kernels import grouped_experts_pallas as ge
+        from .models import nemotron_h as nh
+        cfg, p = self.cfg, self._part(w, i)
+        t, k, held_n = x.shape[0], cfg.num_experts_per_tok, cfg.experts_held
+        with jax.named_scope("moe_route"):
+            chosen, weight = nh.route(x, p["gate.weight"],
+                                      p["gate.e_score_correction_bias"], cfg)
+            local = chosen - cfg.first_expert
+            held = (local >= 0) & (local < held_n) & valid[:, None]
+            keys = jnp.where(held, local, held_n).reshape(-1)
+            sizes, tile_group, n_live, row_pair, pair_row = ge.group_plan(
+                keys, held_n)
+        with jax.named_scope("moe_latent"):
+            lat = x @ p["fc1_latent_proj.weight"]
+        with jax.named_scope("moe_route"):
+            xs = jnp.where((row_pair >= 0)[:, None],
+                           lat[jnp.maximum(row_pair, 0) // k], 0)
+        with jax.named_scope("moe_experts"):
+            ys = ge.grouped_experts(xs, tile_group, n_live, None,
+                                    p["experts.up_proj"],
+                                    p["experts.down_proj"], kernel=kernel)
+            mine = ys[jnp.maximum(pair_row, 0)].reshape(t, k, -1)
+            y = jnp.sum(jnp.where(held[..., None],
+                                  weight[..., None] * mine.astype(jnp.float32),
+                                  0.0), axis=1).astype(x.dtype)
+        with jax.named_scope("moe_latent"):
+            y = y @ p["fc2_latent_proj.weight"]
+        with jax.named_scope("moe_shared"):
+            y = y + nh.relu2_ffn(x, p["shared_experts.up_proj.weight"],
+                                 p["shared_experts.down_proj.weight"])
+        with jax.named_scope("moe_route"):
+            counters = jnp.stack([
+                valid.sum() * k, held.sum(), 0, sizes.max(),
+                (sizes > 0).sum()]).astype(jnp.int32)
+        return y, counters
+
+    @jax.named_scope("head")
+    def _logits(self, w, h):
+        return _rms(h, w["backbone.norm_f.weight"], self.eps) \
+            @ w["lm_head.weight"].T
+
+    # -- the step program --------------------------------------------------------
+    def step_ragged(self, w, tokens, positions, k_pools, v_pools, scatter,
+                    attend, state, shard=None):
+        """See _LlamaDecoder.step_ragged; k_pools, v_pools: one entry an
+        ATTENTION layer. state: ((states [state layers, slots, tiles,
+        state, lanes] float32, tails [state layers, slots, (K - 1) x conv
+        channels]), slot_ids [T], valid [T]): the engine's state pools and
+        whose each row is. The second result is the step's routing counters
+        (``COUNTERS``), the fifth the state pools advanced: the scheduled
+        sequences' slots hold what their next rows start from, every other
+        slot is as it came."""
+        from .kernels import ssm_pallas as ssm
+        (states, tails), slot_ids, valid = state
+        kernel = shard is None
+        with jax.named_scope("embed"):
+            h = w[self.embed_key][tokens]
+        seam = _entry_seams(k_pools.shape, scatter, attend)
+        kf, vf = _join_entries(k_pools, v_pools)
+        with jax.named_scope("ssm_scan"):
+            meta = ssm.scan_meta(slot_ids, positions, valid, states.shape[1])
+        plan = self._tap_plan(meta, positions)
+        total = jnp.zeros(len(self.COUNTERS), jnp.int32)
+        seen = {"M": 0, "*": 0}
+        for i, kind in enumerate(self.kinds):
+            with jax.named_scope({"M": "ssm_proj", "*": "attn_proj",
+                                  "E": "moe_route"}[kind]):
+                x = _rms(h, w[f"backbone.layers.{i}.norm.weight"], self.eps)
+            if kind == "M":
+                y, states, tails = self._mamba(w, i, x, seen["M"], states,
+                                               tails, meta, plan, kernel)
+            elif kind == "*":
+                y, kf, vf = self._attention(w, i, x, kf, vf, *seam(seen["*"]))
+            else:
+                y, counters = self._moe(w, i, x, valid, kernel)
+                total = total + counters
+            seen[kind] = seen.get(kind, 0) + 1
+            h = h + y
+        return (self._logits(w, h), total,
+                *_split_entries(kf, vf, k_pools.shape), (states, tails))
 
 
 __all__ = ["generate", "draft_greedy", "draft_greedy_batch"]

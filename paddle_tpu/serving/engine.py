@@ -357,13 +357,13 @@ class ServingEngine:
         shape = (self.dec.cache_entries, num_blocks, self.dec.n_kv, bs,
                  self.dec.hd)
         self._pool_shape, self._pool_dtype = shape, dtype
-        self._kp = self._new_pool("_kp")
-        self._vp = self._new_pool("_vp")
+        self._kp, self._vp, self._state = (
+            self._new_pool(name) for name in ("_kp", "_vp", "_state"))
         # device bytes of one page across K+V and every cache entry — the
         # unit the telemetry/memwatch byte accounting is denominated in
         self.page_bytes = (self._kp.nbytes + self._vp.nbytes) // num_blocks
-        self.pool = KVBlockPool(num_blocks, bs,
-                                enable_prefix_cache=cfg.enable_prefix_cache)
+        self.pool = KVBlockPool(num_blocks, bs, enable_prefix_cache=(
+            cfg.enable_prefix_cache and not self._state))
         spec_opts = dict(cfg.spec_options)
         if cfg.spec_method == "draft_model":
             if cfg.draft_model is None:
@@ -386,9 +386,8 @@ class ServingEngine:
         from ..profiler.memwatch import resolve_watcher
         self.memwatch = resolve_watcher(cfg.memwatch)
         if self.memwatch is not None:
-            self.memwatch.register_pool("params", lambda: self._w)
-            self.memwatch.register_pool(
-                "kv_pages", lambda: (self._kp, self._vp))
+            for name, pool in self._watched_pools():
+                self.memwatch.register_pool(name, pool)
         self.role = cfg.role
         self.sched = Scheduler(self.pool, cfg.max_seqs, cfg.token_budget,
                                self.max_pages_per_seq,
@@ -460,14 +459,15 @@ class ServingEngine:
                              PartitionSpec(None, None, "mp", None, None))
 
     def _new_pool(self, name):
-        """A zeroed device pool (``"_kp"`` or ``"_vp"``) in the engine's
-        placement — construction and the step-fault containment rebuild
-        share one spelling."""
+        """A zeroed device pool (``"_kp"``, ``"_vp"`` or ``"_state"``) in
+        the engine's placement — construction and the step-fault
+        containment rebuild share one spelling."""
+        if name == "_state":
+            return self._new_state()
         # created in place: under a mesh each chip zeroes its own KV-head
         # shard, and no chip ever holds the whole pool
-        shape = self._pool_shape if name == "_kp" \
-            else self._pool_shape[:-1] + (self.dec.v_dim,)
-        return jnp.zeros(shape, self._pool_dtype,
+        width = self._pool_shape[-1] if name == "_kp" else self.dec.v_dim
+        return jnp.zeros(self._pool_shape[:-1] + (width,), self._pool_dtype,
                          device=self._pool_sharding())
 
     def _weight_sharding(self, name, ndim):
@@ -526,8 +526,8 @@ class ServingEngine:
         ``PADDLE_AOT_CACHE`` env), else the plain jitted program."""
         from ..aot.cache import cached_jit, resolve_store
         store = resolve_store(self.config.aot_cache)
-        if store is None:
-            return partial(_engine_step, self.dec, self._shard)
+        if store is None or self._state:     # a state rides the plain jit
+            return self._plain_step_call()
         dec = self.dec
         shard = self._shard
 
@@ -827,9 +827,9 @@ class ServingEngine:
                             pages_walked=self._pages_walked(plan),
                             pages_tabled=self.config.token_budget
                             * self.max_pages_per_seq,
-                            attn_tiles=tiles,
-                            attn_tiles_ahead=ahead,
-                            layer_visits=self.dec.cache_entries):
+                            attn_tiles=tiles, attn_tiles_ahead=ahead,
+                            layer_visits=self.dec.cache_entries,
+                            **self._state_counts(plan)):
                         sampled = self._run_plan(plan, armed)
                 except Exception as exc:  # noqa: BLE001 — containment seam
                     if self.resilience is None:
@@ -995,13 +995,13 @@ class ServingEngine:
     def import_handoff(self, req, record) -> None:
         """Receive one prefill-complete hand-off INTO this decode-pool
         engine: allocate pages, scatter the exported contents, attach
-        pages + position to the request, and queue it — the next step's
-        admission feeds the one pending prompt token and samples the
-        first output token, bit-identically to a single-engine run (the
-        imported K/V is byte-for-byte what this engine would have
-        computed). Raises ``PoolExhausted`` (or lets a ``serve.kv_alloc``
-        chaos fault through) when pages are unobtainable, with NOTHING
-        mutated — the router falls back to ``adopt_recompute``."""
+        pages + position to the request, and queue it — the next step
+        feeds the one pending prompt token and samples the first output
+        token, bit-identically to a single-engine run. Raises
+        ``PoolExhausted`` (or lets a ``serve.kv_alloc`` chaos fault
+        through) when pages are unobtainable, with NOTHING mutated — the
+        router falls back to ``adopt_recompute``."""
+        self._refuse_beside_state("a page hand-off")
         with self._lock:
             if self._draining:
                 raise _res.AdmissionRejected(
@@ -1156,9 +1156,9 @@ class ServingEngine:
         # old buffers leaves self._kp/_vp deleted — rebuild them (zeros:
         # every sequence recomputes from scratch anyway)
         pools_rebuilt = False
-        for name in ("_kp", "_vp"):
-            arr = getattr(self, name)
-            if getattr(arr, "is_deleted", lambda: False)():
+        for name in ("_kp", "_vp", "_state"):
+            if any(a.is_deleted() for a in jax.tree_util.tree_leaves(
+                    getattr(self, name))):
                 setattr(self, name, self._new_pool(name))
                 pools_rebuilt = True
         if pools_rebuilt or kind == "nan_logits":
@@ -1255,6 +1255,80 @@ class ServingEngine:
                         beside, [i for _, i in sample_points])
         with RecordEvent("serve.emit", **counts):
             return self._emit_sampled(plan, sample_points, all_tok, armed)
+
+    # -- a state beside the pages (decoders with ``state_shapes``) -------------
+    def _new_state(self) -> list:
+        """The pools of what a sequence keeps BESIDE its pages, whatever
+        its context: ``[]`` for a decoder without ``state_shapes`` (every
+        one whose layers all cache K/V), else ONE entry, a tuple of zeroed
+        arrays ``[state layers, max_seqs] + shape``, indexed by the
+        request's slot. The step program takes them after the page pools,
+        donated like them, and hands them back advanced; nobody clears a
+        slot (a sequence's first row at position 0 starts from nothing, in
+        the program). What moves pages only cannot keep a state, and is
+        refused here in words rather than run wrong: speculation (a
+        rejected draft is rolled back by dropping pages; a state cannot be
+        un-updated), the prefill/decode roles' page hand-off, a mesh. The
+        pool is built with prefix reuse off (a hit would skip rows whose
+        state nobody kept), so preemption and the step-fault requeue
+        recompute from position 0; an AOT store is passed by."""
+        shapes = getattr(self.dec, "state_shapes", None)
+        if shapes is None:
+            return []
+        cfg = self.config
+        for what, on in (("spec_method", cfg.spec_method is not None),
+                         ("a prefill or decode role", cfg.role is not None),
+                         ("a mesh", self.mesh is not None)):
+            if on:
+                self._refuse_beside_state(what, ValueError)
+        layers = self.dec.state_layers
+        return [tuple(jnp.zeros((layers, cfg.max_seqs) + tuple(shape),
+                                dtype or self._pool_dtype)
+                      for shape, dtype in shapes)]
+
+    def _refuse_beside_state(self, what: str, error=RuntimeError) -> None:
+        """``what`` moves, shares or shards pages only: not offered for a
+        decoder that keeps a state beside them."""
+        if getattr(self.dec, "state_shapes", None) is not None:
+            raise error(
+                f"{what} is not offered for {type(self.model).__name__}: "
+                "each sequence keeps a recurrent state beside its K/V pages "
+                "(one fixed-size entry a slot), and this path moves, shares "
+                "or shards pages only")
+
+    def _plain_step_call(self):
+        """The jitted step program with decoder and annotator bound. With a
+        state, a call takes and returns what a stateless one does and the
+        state pools ride behind the page pools, kept on the engine."""
+        if not self._state:
+            return partial(_engine_step, self.dec, self._shard)
+
+        def step_call(*args):
+            logits, exits, kp, vp, *self._state = _engine_step_state(
+                self.dec, self._shard, *args, *self._state)
+            return logits, exits, kp, vp
+
+        return step_call
+
+    def _watched_pools(self):
+        """(name, thunk) of each device pool ``memwatch`` accounts."""
+        pools = [("params", lambda: self._w),
+                 ("kv_pages", lambda: (self._kp, self._vp))]
+        if self._state:
+            pools.append(("state", lambda: self._state))
+        return pools
+
+    def _state_counts(self, plan) -> dict:
+        """``serve.run``'s arguments for a decoder with a state: the
+        sequences whose state the step reads and writes, the prefill rows
+        (of ``max_seqs`` slots), the prefill rows that pass through it, the
+        sequences that begin at position 0. Nothing without one."""
+        if not self._state:
+            return {}
+        return {"state_slots": len(plan.entries),
+                "state_slots_max": self.config.max_seqs,
+                "state_rows_prefill": plan.prefill_tokens,
+                "state_resets": sum(e.start == 0 for e in plan.entries)}
 
     def _pages_walked(self, plan) -> int:
         """Pages the step's attention has to read: each scheduled
@@ -1534,6 +1608,8 @@ class ServingEngine:
             raise ValueError(
                 "a prefill-role engine never decodes — speculative "
                 "decoding belongs on the decode pool")
+        if role is not None:
+            self._refuse_beside_state("a prefill or decode role", ValueError)
         with self._lock:
             if self._live_requests():
                 raise RuntimeError(
@@ -1602,6 +1678,17 @@ class ServingEngine:
                               self.page_bytes // self.pool.block_size,
                           **self.dec.describe()},
             }
+            if self._state:
+                # what a sequence keeps beside its pages, whatever its
+                # context, and what cannot be kept with it
+                leaves = jax.tree_util.tree_leaves(self._state)
+                base["model"].update(
+                    state_bytes_a_sequence=sum(a.nbytes for a in leaves)
+                    // self.config.max_seqs,
+                    state_bytes=sum(a.nbytes for a in leaves),
+                    prefix_reuse="off: a cached prefix has pages and no "
+                                 "state; preempted and requeued requests "
+                                 "recompute from position 0")
             if self.mesh is not None:
                 base["mesh"] = {"mp": int(self.mesh.shape["mp"]),
                                 "devices": self.mesh.devices.size}
@@ -1703,6 +1790,41 @@ class ServingEngine:
                                       self.config.quant)
                 if self.config.quant
                 else self.dec.weights(self.model))
+
+
+class _BesideState:
+    """A decoder as ``_engine_step_impl`` sees it, with the state pools and
+    whose each row is bound into its ``step_ragged``; ``state`` holds the
+    advanced pools after the call."""
+
+    def __init__(self, dec, state, slot_ids, valid):
+        self._dec, self.state = dec, state
+        self._rows = (slot_ids, valid)
+
+    def __getattr__(self, name):
+        return getattr(self._dec, name)
+
+    def step_ragged(self, *args, **kw):
+        *out, self.state = self._dec.step_ragged(
+            *args, (self.state, *self._rows), **kw)
+        return out
+
+
+def _engine_step_impl_state(dec, shard, w, tokens, slot_ids, positions,
+                            valid, tables, k_pools, v_pools, state):
+    """``_engine_step_impl`` for a decoder that keeps a state beside the
+    pages: the same program with ``state`` (the engine's pools ``[state
+    layers, max_seqs, ...]``, donated like the page pools) threaded through
+    the decoder's step and returned advanced. A decoder without a state
+    never comes here, so its program is what it was."""
+    bound = _BesideState(dec, state, slot_ids, valid)
+    return (*_engine_step_impl(bound, shard, w, tokens, slot_ids, positions,
+                               valid, tables, k_pools, v_pools), bound.state)
+
+
+_engine_step_state = partial(jax.jit, static_argnums=(0, 1),
+                             donate_argnums=(8, 9, 10))(
+                                 _engine_step_impl_state)
 
 
 class EnginePredictor:

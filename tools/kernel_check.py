@@ -314,6 +314,18 @@ def case_latent_paged_attention():
              "live_rows": live, "live_bytes": live * d * 2})
 
 
+def grouped_run(kernel, e, tm, x, keys, *banks):
+    """The grouped product of ``x``'s pairs by their experts ``keys`` over
+    two banks (no gate) or three, the padding rows nought."""
+    from paddle_tpu.kernels import grouped_experts_pallas as ge
+    _, tile_group, n_live, row_pair, _ = ge.group_plan(keys, e, tm)
+    xs = jnp.where((row_pair >= 0)[:, None], x[jnp.maximum(row_pair, 0)], 0)
+    ys = ge.grouped_experts(xs, tile_group, n_live,
+                            *(None,) * (3 - len(banks)), *banks,
+                            kernel=kernel)
+    return jnp.where((row_pair >= 0)[:, None], ys, 0)
+
+
 def case_grouped_experts():
     """The held experts' grouped product at the cell's shapes (3,840 pairs
     of 320 rows x 12, 16 experts of 6144 x 2048) under uneven routing: one
@@ -331,17 +343,10 @@ def case_grouped_experts():
         k, s, jnp.bfloat16, 0.02) for k, s in
         ((1, (e, h, f)), (2, (e, h, f)), (3, (e, f, h)))]
 
-    def run(kernel, x, keys, *banks):
-        _, tile_group, n_live, row_pair, _ = ge.group_plan(keys, e, tm)
-        xs = jnp.where((row_pair >= 0)[:, None], x[jnp.maximum(row_pair, 0)],
-                       0)
-        ys = ge.grouped_experts(xs, tile_group, n_live, *banks,
-                                kernel=kernel)
-        return jnp.where((row_pair >= 0)[:, None], ys, 0)
-
-    fast = jax.jit(functools.partial(run, True))
+    fast = jax.jit(functools.partial(grouped_run, True, e, tm))
     got = fast(x, keys, *banks)
-    want = jax.jit(functools.partial(run, False))(x, keys, *banks)
+    want = jax.jit(functools.partial(grouped_run, False, e, tm))(
+        x, keys, *banks)
     jax.block_until_ready(want)
     even = jnp.asarray(np.random.default_rng(1).permutation(
         np.where(np.arange(pairs) < 64, np.arange(pairs) % e, e)
@@ -350,6 +355,86 @@ def case_grouped_experts():
             {"ms_a_call_uneven": timed_ms(fast, x, keys, *banks),
              "ms_a_call_4_a_expert": timed_ms(fast, x, even, *banks),
              "touched_bytes_4_a_expert": e * 3 * h * f * 2})
+
+
+
+def case_grouped_experts_two_banks():
+    """The two-bank ``relu2`` product at nemotron120-serve-batch's shapes
+    (7,040 pairs of 320 rows x 22, 128 held experts of 1024 x 2688, a width
+    512 does not divide): every expert touched, 14 pairs each but one that
+    takes 200; 5,062 pairs fall on experts held elsewhere."""
+    from paddle_tpu.kernels import grouped_experts_pallas as ge
+    e, h, f, pairs = 128, 1024, 2688, 320 * 22
+    sizes = [200] + [14] * (e - 1)
+    keys = np.full(pairs, e, np.int32)
+    keys[:sum(sizes)] = np.repeat(np.arange(e), sizes)
+    keys = jnp.asarray(np.random.default_rng(0).permutation(keys))
+    tm = ge.TM
+    x = rand(0, (pairs, h))
+    banks = [jax.jit(rand, static_argnums=(0, 1, 2, 3))(
+        k, s, jnp.bfloat16, 0.02) for k, s in
+        ((1, (e, h, f)), (2, (e, f, h)))]
+
+    fast = jax.jit(functools.partial(grouped_run, True, e, tm))
+    got = fast(x, keys, *banks)
+    want = jax.jit(functools.partial(grouped_run, False, e, tm))(
+        x, keys, *banks)
+    jax.block_until_ready(want)
+    return ((pairs, e, h, f, tm, ge.width_block(h, f, 2, 2)),
+            {"out": (got, want)},
+            {"ms_a_call": timed_ms(fast, x, keys, *banks),
+             "touched_bytes": e * 2 * h * f * 2})
+
+
+def case_ssm_scan():
+    """The Mamba-2 state update at nemotron120-serve-batch's shapes but 2
+    layers of its 5 (the oracle keeps a second pool): 192 slots of 128
+    heads x 64 x 128 float32, 320 rows: 188
+    sequences that decode one row each, a 100-row chunk that begins a
+    prompt (from nothing, whatever its slot holds) and a 28-row chunk that
+    continues one; two slots unscheduled, which must come back bit for
+    bit. The milliseconds are one layer's call on a pool the call keeps."""
+    from paddle_tpu.kernels import ssm_pallas as ssm
+    layers, slots, heads, groups, p, n, rows = 2, 192, 128, 8, 64, 128, 320
+    plan = [(1, 40 + 3 * i) for i in range(188)] + [(100, 100), (28, 150)]
+    slot_ids, positions = [], []
+    free = list(np.random.default_rng(0).permutation(slots))
+    for count, ctx in plan:
+        s = int(free.pop())
+        slot_ids += [s] * count
+        positions += list(range(ctx - count, ctx))
+    valid = np.arange(rows) < len(slot_ids)
+    pad = rows - len(slot_ids)
+    slot_ids = jnp.asarray(slot_ids + [0] * pad, jnp.int32)
+    positions = jnp.asarray(positions + [0] * pad, jnp.int32)
+    f32 = jnp.float32
+    pool = jax.jit(lambda k: jax.random.normal(
+        k, (layers, slots) + ssm.pool_shape(heads, groups, p, n), f32))(
+        jax.random.PRNGKey(0))
+    x = rand(1, (rows, heads, p))
+    b, c = rand(2, (rows, groups, n)), rand(3, (rows, groups, n))
+    dt = jax.random.uniform(jax.random.PRNGKey(4), (rows, heads), f32,
+                            0.05, 1.5)
+    decay = jnp.exp(-dt * 2.0)
+
+    def run(kernel, pool, layer):
+        meta = ssm.scan_meta(slot_ids, positions, jnp.asarray(valid), slots)
+        return ssm.ssm_scan(pool, layer, x, b, c, dt, decay, meta,
+                            kernel=kernel)
+
+    fast = jax.jit(functools.partial(run, True))
+    y, new = fast(pool, 1)
+    y_want, want = jax.jit(functools.partial(run, False))(pool, 1)
+    idle = jnp.asarray([int(s) for s in free], jnp.int32)
+    kept = bool(jnp.array_equal(new[1, idle], pool[1, idle])
+                & jnp.array_equal(new[0], pool[0]))
+    moved = 2 * len(plan) * heads * p * n * 4
+    ms = timed_ms(lambda pool: fast(pool, 1)[1], pool)
+    return ((layers, slots, heads, p, n, rows), {"y": (y, y_want),
+                                                 "state": (new[1], want[1])},
+            {"ms_a_layer": ms, "state_bytes_moved": moved,
+             "share_of_819_GB_s": round(moved / 819e9 / (ms / 1e3), 4),
+             "unscheduled_slots_bit_for_bit": kept})
 
 
 CASES = (
@@ -371,6 +456,10 @@ CASES = (
      case_latent_paged_attention, REL_L2),
     ("grouped_experts", "ServingEngine on one chip, an expert layer",
      case_grouped_experts, REL_L2),
+    ("grouped_experts_two_banks", "ServingEngine on one chip, relu2 experts "
+     "in a latent width", case_grouped_experts_two_banks, REL_L2),
+    ("ssm_scan", "ServingEngine on one chip, a recurrent state beside the "
+     "pages", case_ssm_scan, REL_L2_F32),
 )
 
 
