@@ -286,6 +286,13 @@ class _LlamaDecoder:
         layers and cache entries: nothing here."""
         return {}
 
+    def beside_width(self, rows):
+        """Entries of what ``step_ragged`` returns beside its logits for
+        ``rows`` packed rows, as the engine's step program hands them back
+        behind the sampled tokens: the counters where the decoder has
+        ``COUNTERS``, else none."""
+        return len(getattr(self, "COUNTERS", ()))
+
     def _static_key(self):
         """Everything the traced step() reads off `self` — two decoders
         with equal keys produce identical traces, so they may share jit
@@ -519,6 +526,10 @@ class _OuroDecoder(_LlamaDecoder):
         """Each branch is normed again before it joins the residual:
         ``input_layernorm_2``, ``post_attention_layernorm_2``."""
         return _rms(x, self._lw(w, i, norm + "_2.weight"), self.eps)
+
+    def beside_width(self, rows):
+        """One exit pass a row."""
+        return rows
 
     def step_counts(self, passes, rows):
         """``serve.emit``'s arguments from what a step brought back beside
@@ -908,6 +919,9 @@ class _GPTDecoder:
 
     def describe(self):
         return {}                 # see _LlamaDecoder
+
+    def beside_width(self, rows):
+        return 0                  # see _LlamaDecoder
 
     def _static_key(self):
         """See _LlamaDecoder._static_key. The MoE fingerprint keys the

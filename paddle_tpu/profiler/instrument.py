@@ -374,13 +374,15 @@ def record_serve_queue_depth(depth: int) -> None:
 
 def record_serve_step(admitted: int, finished: int, preempted: int,
                       queue_depth: int, running: int,
-                      pool_utilization: float) -> None:
-    """One continuous-batching engine step's worth of scheduler events."""
+                      pool_utilization: float, launched: bool = True) -> None:
+    """One continuous-batching engine step's worth of scheduler events
+    (``launched`` False: the call only read back the step in flight)."""
     if not _enabled[0]:
         return
     r = _reg()
-    r.counter("serve_steps_total", "serving engine steps (device calls)") \
-        .inc()
+    if launched:
+        r.counter("serve_steps_total",
+                  "serving engine steps (device calls)").inc()
     if admitted:
         r.counter("serve_admitted_total",
                   "requests admitted into the continuous batch") \

@@ -12,9 +12,10 @@ Composes the pieces PR 1-5 left on the table into a serving tier:
   * ``serving.ragged`` — the step's attention: the Pallas paged kernel
     on a single TPU chip, the pure-JAX reference on the CPU and on a mesh.
 
-Sampling runs host-side (greedy, or temperature with a seeded generator
-per engine) so the device program stays sampling-agnostic and requests
-stream tokens as they land. ``EnginePredictor`` wraps the engine in the
+Greedy sampling runs inside the step program, so a step's tokens feed the
+next step on the device: the engine launches step n+1 before it reads step
+n's tokens back (one step in flight), and requests stream tokens as they
+are read. ``EnginePredictor`` wraps the engine in the
 ``inference.Predictor`` duck type so ``PredictorPool`` clones and
 ``BatchingServer`` delegate to ONE shared engine instead of stacking
 per-predictor state.
@@ -38,7 +39,7 @@ from . import resilience as _res
 from .kv_pool import KVBlockPool
 from .locking import OrderedLock
 from .obs import resolve_observer
-from .scheduler import Request, Scheduler, WAITING
+from .scheduler import InFlight, Request, Scheduler, RUNNING, WAITING
 from .speculative import make_drafter, verify_greedy
 
 
@@ -211,24 +212,20 @@ class _MeshShard:
         """[E, P, kvh, bs, D] stacked pools: per-KV-head shards."""
         return self._c(pools, None, None, "mp", None, None)
 
+    def whole(self, x):
+        """Replicated on every chip: the step's output, which the next
+        step takes back as ``prev``."""
+        return self._c(x)
+
 
 @jax.jit
 def _argmax_rows(logits):
     """Greedy token for EVERY packed row — fixed [T] shape, so the one
     compiled program serves any mix of decode/prefill/verify entries
     (a per-step gather of just the sampling rows would recompile on
-    every distinct row-count the speculative planner produces)."""
+    every distinct row-count the speculative planner produces). The step
+    program's sampler: the engine hands it in as a static argument."""
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-
-@jax.jit
-def _beside_exits(tokens, beside):
-    """The sampled tokens with what the step program returned beside its
-    logits behind them (the pass each row's logits were taken after, where
-    the decoder runs its layers several times a token; the routing counts
-    of a decoder with an expert layer): one int32 array, so one transfer
-    brings both back. The decoder's ``step_counts`` reads the tail."""
-    return jnp.concatenate([tokens, beside])
 
 
 @jax.jit
@@ -236,7 +233,8 @@ def _all_finite(logits):
     """The StepGuard-style sample guard (serving/resilience.py): one
     fused reduce over the step's logits — NaN/inf anywhere means the
     sampled tokens cannot be trusted and the whole step is a fault.
-    Fixed [T, V] shape, so it shares the engine's one-compile story."""
+    Run inside the step program where the nan guard is armed; its
+    verdict is the last entry of what the program returns."""
     return jnp.all(jnp.isfinite(logits))
 
 
@@ -268,17 +266,17 @@ def _copy_page(k_pools, v_pools, src, dst):
             v_pools.at[:, dst].set(v_pools[:, src]))
 
 
-def _engine_step_impl(dec, shard, w, tokens, slot_ids, positions, valid,
-                      tables, k_pools, v_pools):
-    """The one compiled serving program: scatter targets from the page
-    tables, ragged attention over the pools, logits for every packed
-    token. Pools are donated — each step reuses the previous buffers,
-    and ``step_ragged`` writes this step's rows into them in place (a page
-    a row; nothing of a pool's size is copied).
-    ``shard`` (static, None on a single chip) is the tensor-parallel
-    annotator pinning the TP layout through the ragged path. (The
-    un-jitted body, so the AOT cache path can close over ``dec`` and
-    ``shard`` and export a program of array-only inputs.)"""
+def _ragged_forward(dec, shard, w, tokens, slot_ids, positions, valid,
+                    tables, k_pools, v_pools):
+    """Scatter targets from the page tables, ragged attention over the
+    pools, logits for every packed token: (logits, beside, k_pools',
+    v_pools'), ``beside`` being what the decoder returns beside its logits
+    (the pass each row's logits were taken after, where it runs its layers
+    several times a token; the routing counts of an expert layer) or None.
+    ``step_ragged`` writes this step's rows into the pools in place (a page
+    a row; nothing of a pool's size is copied). ``shard`` (static, None on
+    a single chip) is the tensor-parallel annotator pinning the TP layout
+    through the ragged path."""
     bs = k_pools.shape[3]
     p_total = k_pools.shape[1]
     mp = tables.shape[1]
@@ -292,18 +290,64 @@ def _engine_step_impl(dec, shard, w, tokens, slot_ids, positions, valid,
     attend = _ragged.make_attend(tables, slot_ids, positions, valid,
                                  dec.n_heads // dec.n_kv, shard=shard,
                                  scale=dec.attn_scale, latent=dec.latent_dim)
-    logits, exits, kp, vp = dec.step_ragged(w, tokens, positions, k_pools,
-                                            v_pools, (pages, offs), attend,
-                                            shard=shard)
+    logits, beside, kp, vp = dec.step_ragged(w, tokens, positions, k_pools,
+                                             v_pools, (pages, offs), attend,
+                                             shard=shard)
     if shard is not None:
         # pin the donated outputs to the per-KV-head layout the next
         # step's inputs commit to (no silent gather between steps)
         kp, vp = shard.pools(kp), shard.pools(vp)
-    return logits, exits, kp, vp
+    return logits, beside, kp, vp
 
 
-_engine_step = partial(jax.jit, static_argnums=(0, 1),
-                       donate_argnums=(8, 9))(_engine_step_impl)
+def _engine_step_impl(dec, shard, sample, check, w, tokens, prev, feed,
+                      slot_ids, positions, valid, tables, k_pools, v_pools):
+    """The one compiled serving program: ``_ragged_forward`` and the sample
+    of every row, so that a step's tokens can feed the next step without
+    the host reading them. A row whose token the host has not seen
+    (``feed`` >= 0) takes it from ``prev``, the output of the step before,
+    still on the device, at that row. Returns ONE int32 array — every row's
+    sample (``sample``, static: ``_argmax_rows``), what the decoder returns
+    beside its logits behind them, and ``check``'s verdict last where the
+    nan guard is armed (static: ``_all_finite``, or None) — and the pools.
+    Logits never leave the program. Pools are donated — each step reuses
+    the previous buffers. (The un-jitted body, so the AOT cache path can
+    close over the static arguments and export a program of array-only
+    inputs.)"""
+    tokens = jnp.where(feed >= 0, prev[jnp.clip(feed, 0)], tokens)
+    logits, beside, kp, vp = _ragged_forward(
+        dec, shard, w, tokens, slot_ids, positions, valid, tables, k_pools,
+        v_pools)
+    out = [sample(logits)]
+    if beside is not None:
+        out.append(beside.astype(jnp.int32))
+    if check is not None:
+        out.append(check(logits).astype(jnp.int32)[None])
+    out = jnp.concatenate(out) if len(out) > 1 else out[0]
+    if shard is not None:
+        out = shard.whole(out)
+    return out, kp, vp
+
+
+_engine_step = partial(jax.jit, static_argnums=(0, 1, 2, 3),
+                       donate_argnums=(12, 13))(_engine_step_impl)
+
+
+def _emitted() -> dict:
+    """What one engine call read back and handed out, summed over the
+    steps it read (``read``)."""
+    return {"read": 0, "tokens": 0, "finished": 0, "finished_rids": [],
+            "ttfts": [], "accepted": 0, "rollback_pages": 0}
+
+
+class _Launched:
+    """A launched step: its plan, the rows that sample (``(entry, row)``)
+    and its output array, on the device until the host reads it."""
+
+    __slots__ = ("plan", "sample_points", "out")
+
+    def __init__(self, plan, sample_points, out):
+        self.plan, self.sample_points, self.out = plan, sample_points, out
 
 
 class ServingEngine:
@@ -424,17 +468,29 @@ class ServingEngine:
         # reentrant; PADDLE_LOCKCHECK=1 arms LOCK_ORDER enforcement
         self._lock = OrderedLock("engine")
         self._work = threading.Event()
+        # resilience plane (serving/resilience.py); disarmed = None, and
+        # every armed-only seam below is behind one `is None` check
+        self.resilience = _res.resolve_resilience(cfg.resilience)
+        # the step program's static sampler and, where the nan guard is
+        # armed, its check of the logits (looked up here, once)
+        self._sample = _argmax_rows
+        self._check = _all_finite if (self.resilience is not None and
+                                      self.resilience.nan_guard) else None
         self._step_call = self._build_step_call()
+        # the last launched step's output (``prev`` of the next launch)
+        # and the step launched and not read back yet, if any
+        self._prev = self._first_prev()
+        self._ahead: Optional[_Launched] = None
         self.aot_warm_result = self._warm_start()
         self.steps = 0
         self.tokens_generated = 0
         self.attn_tiles = self.attn_tiles_ahead = 0
+        # steps launched while the one before was in flight, the decode
+        # rows planned, and those of them whose token came from the device
+        self.steps_ahead = self.decode_rows = self.device_fed_rows = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.spec_rollback_pages = 0
-        # resilience plane (serving/resilience.py); disarmed = None, and
-        # every armed-only seam below is behind one `is None` check
-        self.resilience = _res.resolve_resilience(cfg.resilience)
         self._draining = False
         self._admit_cv = threading.Condition()
         self.step_faults = 0
@@ -528,14 +584,14 @@ class ServingEngine:
         store = resolve_store(self.config.aot_cache)
         if store is None or self._state:     # a state rides the plain jit
             return self._plain_step_call()
-        dec = self.dec
-        shard = self._shard
+        dec, shard, sample, check = (self.dec, self._shard, self._sample,
+                                     self._check)
 
-        def serve_engine_step(w, tokens, slot_ids, positions, valid,
-                              tables, k_pools, v_pools):
-            return _engine_step_impl(dec, shard, w, tokens, slot_ids,
-                                     positions, valid, tables, k_pools,
-                                     v_pools)
+        def serve_engine_step(w, tokens, prev, feed, slot_ids, positions,
+                              valid, tables, k_pools, v_pools):
+            return _engine_step_impl(dec, shard, sample, check, w, tokens,
+                                     prev, feed, slot_ids, positions, valid,
+                                     tables, k_pools, v_pools)
 
         # _static_key() is what jax.jit's static-argnums dispatch keyed
         # the uncached path on: the decoder's baked-in trace constants
@@ -545,28 +601,41 @@ class ServingEngine:
         # MoE static key holds live function objects whose repr embeds
         # a per-process address (= a permanent spurious miss).
         from ..aot.fingerprint import stable_repr
-        jit_kwargs = {"donate_argnums": (6, 7)}
+        jit_kwargs = {"donate_argnums": (8, 9)}
         if self.mesh is not None:
             # warm() lowers from avals ALONE — without explicit
             # in_shardings the exported program would assume unsharded
             # inputs and silently gather the committed TP shards on
             # every real call. Pin the argument layouts the engine
             # actually feeds: per-leaf weight split, replicated host
-            # arrays, per-KV-head pools.
+            # arrays and previous output, per-KV-head pools.
             from jax.sharding import NamedSharding, PartitionSpec
             rep = NamedSharding(self.mesh, PartitionSpec())
             w_sh = {name: arr.sharding for name, arr in self._w.items()}
             pool = self._pool_sharding()
-            jit_kwargs["in_shardings"] = (w_sh, rep, rep, rep, rep, rep,
-                                          pool, pool)
+            jit_kwargs["in_shardings"] = (w_sh,) + (rep,) * 7 + (pool, pool)
         return cached_jit(
             serve_engine_step, name="serve_engine_step", cache=store,
             key_extras=(stable_repr(self.dec._static_key()),
                         self.config.quant,
                         getattr(self.dec, "min_capacity_override", None),
                         self.config.block_size, self.max_pages_per_seq,
-                        ("mesh", self._mesh_geometry())),
+                        ("mesh", self._mesh_geometry()),
+                        ("nan_guard", check is not None)),
             jit_kwargs=jit_kwargs)
+
+    def _first_prev(self):
+        """Zeros in the shape, type and placement of the step program's
+        output: ``prev`` of the first launch, which feeds no row from it.
+        (The decoder says how wide its part is: a trace to read it off
+        would cost set-up a second trace of the whole model.)"""
+        t_max = self.config.token_budget
+        n = t_max + self.dec.beside_width(t_max) + (self._check is not None)
+        sharding = None
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            sharding = NamedSharding(self.mesh, PartitionSpec())
+        return jnp.zeros((n,), jnp.int32, device=sharding)
 
     def _warm_start(self) -> Optional[str]:
         """Materialize the one engine program at construction: on a cache
@@ -580,10 +649,10 @@ class ServingEngine:
         i32 = jnp.int32
         w_avals = jax.tree_util.tree_map(
             lambda a: sds(jnp.shape(a), a.dtype), self._w)
+        rows = sds((t_max,), i32)
         return self._step_call.warm(
-            w_avals, sds((t_max,), i32), sds((t_max,), i32),
-            sds((t_max,), i32), sds((t_max,), jnp.bool_),
-            sds(self._tables.shape, i32),
+            w_avals, rows, sds(self._prev.shape, i32), rows, rows, rows,
+            sds((t_max,), jnp.bool_), sds(self._tables.shape, i32),
             sds(self._kp.shape, self._kp.dtype),
             sds(self._vp.shape, self._vp.dtype))
 
@@ -758,10 +827,17 @@ class ServingEngine:
 
     # -- engine side ----------------------------------------------------------
     def step(self) -> bool:
-        """Run one continuous-batching step: schedule, one device call,
-        sample, evict — and on a prefill-role engine, export finished
-        prefills' KV pages for hand-off to the decode pool. Returns
-        True while work remains.
+        """Run one continuous-batching step: schedule, launch the step
+        program, then read back and hand out the step launched before it
+        (sample, evict) — and on a prefill-role engine, export finished
+        prefills' KV pages for hand-off to the decode pool. Returns True
+        while work remains, a step in flight included.
+
+        At most one step is in flight: step n+1 is planned, packed and
+        launched while the chip still runs step n, and a row whose input
+        is step n's sample takes it on the device. Where the host must see
+        a step's tokens before it plans the next (``_reads_first``), the
+        step is read back in the same call.
 
         Always under the ``serve.step`` span, with its phases inside
         (``serve.schedule``, ``serve.run`` and its four children,
@@ -770,17 +846,29 @@ class ServingEngine:
         with RecordEvent("serve.step"):
             return self._step()
 
+    def _reads_first(self) -> bool:
+        """Must the host read a step's tokens before it plans the next?
+        Where drafts are made from them (speculation), where a role hands
+        requests off, and where the nan guard clears a step before any of
+        its tokens reaches a client."""
+        return (self.drafter is not None or self.role is not None
+                or self._check is not None)
+
+    def _has_work(self) -> bool:
+        return self.sched.has_work() or self._ahead is not None
+
     def _step(self) -> bool:
         t0 = time.monotonic()
         obs = self.obs
         armed = obs is not None and obs.armed
-        sampled = None
+        got = _emitted()
         with self._lock:
             q0 = self.pool.stats["prefix_queries"]
             h0 = self.pool.stats["prefix_hits"]
-            with RecordEvent("serve.schedule"):
-                plan = self.sched.schedule()
+            plan = self._schedule(got, armed)
             if not plan.entries:
+                # nothing to launch: the step in flight, if any, is read
+                self._settle(got, armed)
                 # prefill-complete requests can exist even on an empty
                 # plan (everything schedulable was already swept):
                 # export them so the hand-off never waits on new work
@@ -800,7 +888,8 @@ class ServingEngine:
                         "t_mono_s": round(t0, 6),
                         "dt_s": round(time.monotonic() - t0, 6),
                         "plan": plan.explain, "entries": [],
-                        "tokens": 0, "finished": [],
+                        "tokens": got["tokens"],
+                        "finished": got["finished_rids"],
                         "queue_depth": self.sched.queue_depth(),
                         "running": len(self.sched.running),
                         "pool": {"used": self.pool.used_blocks(),
@@ -809,14 +898,16 @@ class ServingEngine:
                                  "utilization":
                                      round(self.pool.utilization(), 4)},
                     })
-                if not self.sched.has_work():
+                if not self._has_work():
                     self._work.clear()
-                has_work = self.sched.has_work()
             else:
                 tiles = self._attn_tiles(plan)
                 ahead = max(tiles - 1, 0)
                 self.attn_tiles += tiles
                 self.attn_tiles_ahead += ahead
+                fed = self._device_fed(plan)
+                self.decode_rows += plan.decode_tokens
+                self.device_fed_rows += fed
                 try:
                     with RecordEvent(
                             "serve.run",
@@ -829,8 +920,9 @@ class ServingEngine:
                             * self.max_pages_per_seq,
                             attn_tiles=tiles, attn_tiles_ahead=ahead,
                             layer_visits=self.dec.cache_entries,
+                            device_fed_rows=fed,
                             **self._state_counts(plan)):
-                        sampled = self._run_plan(plan, armed)
+                        self._run_plan(plan, got, armed)
                 except Exception as exc:  # noqa: BLE001 — containment seam
                     if self.resilience is None:
                         # disarmed: the pre-resilience contract — the
@@ -840,43 +932,44 @@ class ServingEngine:
                         raise
                     self._contain_step_fault(plan, exc, armed, t0)
                     self._notify_admit()
-                    return self.sched.has_work()
+                    return self._has_work()
                 # export AFTER the device call landed: a raising step
                 # must leave every request somewhere a salvage/requeue
                 # can find it, never half-exported in a dropped outbox
                 outbox = self._collect_handoffs()
                 self.steps += 1
-                queue_depth = self.sched.queue_depth()
-                running = len(self.sched.running)
-                util = self.pool.utilization()
-                used_blocks = self.pool.used_blocks()
                 if self.memwatch is not None:
                     self.memwatch.snapshot(step=self.steps)
-                dq = self.pool.stats["prefix_queries"] - q0
-                dh = self.pool.stats["prefix_hits"] - h0
-                if armed:
-                    dt = time.monotonic() - t0
-                    obs.record_step({
-                        "step": self.steps,
-                        "t_mono_s": round(t0, 6),
-                        "dt_s": round(dt, 6),
-                        "plan": plan.explain,
-                        "entries": [{"rid": e.req.rid, "start": e.start,
-                                     "n": e.n, "draft": len(e.draft)}
-                                    for e in plan.entries],
-                        "tokens": sampled["tokens"],
-                        "finished": sampled["finished_rids"],
-                        "accepted": sampled["accepted"],
-                        "rollback_pages": sampled["rollback_pages"],
-                        "pool": {"used": self.pool.used_blocks(),
-                                 "cached": self.pool.cached_blocks(),
-                                 "free": self.pool.free_blocks(),
-                                 "utilization": round(util, 4)},
-                        "prefix": {"queries": dq, "hits": dh},
-                        "queue_depth": queue_depth,
-                        "running": running,
-                    })
-                has_work = self.sched.has_work()
+            queue_depth = self.sched.queue_depth()
+            running = len(self.sched.running)
+            util = self.pool.utilization()
+            used_blocks = self.pool.used_blocks()
+            dq = self.pool.stats["prefix_queries"] - q0
+            dh = self.pool.stats["prefix_hits"] - h0
+            if armed and plan.entries:
+                obs.record_step({
+                    "step": self.steps,
+                    "t_mono_s": round(t0, 6),
+                    "dt_s": round(time.monotonic() - t0, 6),
+                    "plan": plan.explain,
+                    "entries": [{"rid": e.req.rid, "start": e.start,
+                                 "n": e.n, "draft": len(e.draft)}
+                                for e in plan.entries],
+                    # what this call read back: the step before this one,
+                    # or this one where the host reads first
+                    "tokens": got["tokens"],
+                    "finished": got["finished_rids"],
+                    "accepted": got["accepted"],
+                    "rollback_pages": got["rollback_pages"],
+                    "pool": {"used": used_blocks,
+                             "cached": self.pool.cached_blocks(),
+                             "free": self.pool.free_blocks(),
+                             "utilization": round(util, 4)},
+                    "prefix": {"queries": dq, "hits": dh},
+                    "queue_depth": queue_depth,
+                    "running": running,
+                })
+            has_work = self._has_work()
         # -- outside the engine lock: hand-off dispatch, telemetry I/O,
         #    metrics (the sink takes the router lock, and lock order is
         #    always engine -> nothing while dispatching)
@@ -884,9 +977,9 @@ class ServingEngine:
             self._dispatch_handoffs(outbox)
             if self.step_hook is not None:
                 self.step_hook()
-            if sampled is None:
+            if not plan.entries and not got["read"]:
                 return has_work
-            if armed and obs.telemetry_path and \
+            if plan.entries and armed and obs.telemetry_path and \
                     self.steps % obs.config.telemetry_every == 0:
                 # telemetry file I/O happens OUTSIDE the engine lock —
                 # telemetry() takes it briefly for the snapshot, but the
@@ -894,19 +987,31 @@ class ServingEngine:
                 obs.write_telemetry(self.telemetry())
             dt = time.monotonic() - t0
             _instr.record_serve_step(
-                plan.admitted, sampled["finished"], plan.preempted,
-                queue_depth, running, util)
+                plan.admitted, got["finished"], plan.preempted,
+                queue_depth, running, util, launched=bool(plan.entries))
             _instr.record_serve_kv_pool_bytes(used_blocks * self.page_bytes)
             _instr.record_serve_prefix(dq, dh)
-            for lat in sampled["ttfts"]:
+            for lat in got["ttfts"]:
                 _instr.record_serve_ttft(lat)
-            _instr.record_serve_tokens(sampled["tokens"], dt)
+            _instr.record_serve_tokens(got["tokens"], dt)
             if plan.drafted:
                 _instr.record_serve_spec_tokens(plan.drafted,
-                                                sampled["accepted"])
-            _instr.record_serve_spec_rollback(sampled["rollback_pages"])
+                                                got["accepted"])
+            _instr.record_serve_spec_rollback(got["rollback_pages"])
             self._notify_admit()
             return has_work
+
+    def _schedule(self, got: dict, armed: bool):
+        """The next plan. One that would preempt a sequence whose next
+        token is still on the device waits for that step: it is read back
+        first, and the plan made again."""
+        try:
+            with RecordEvent("serve.schedule"):
+                return self.sched.schedule()
+        except InFlight:
+            self._settle(got, armed)
+        with RecordEvent("serve.schedule"):
+            return self.sched.schedule()
 
     def _notify_admit(self) -> None:
         """Wake submitters blocked on queue room (policy ``block``)."""
@@ -925,6 +1030,7 @@ class ServingEngine:
         done = self.sched.pop_prefill_done()
         if not done:
             return []
+        self._settle()
         out = []
         now = time.monotonic()
         bs = self.pool.block_size
@@ -1127,6 +1233,7 @@ class ServingEngine:
         ``commit_export``: either verdict leaves this pool clean, the
         two differ only in who owns the K/V afterwards."""
         with self._lock:
+            self._settle()
             pages = self._pending_exports.pop(rid, None)
             if pages is None:
                 return False
@@ -1144,6 +1251,12 @@ class ServingEngine:
         along), and FAIL requests past their retry budget with a clean
         terminal error. Runs under the engine lock."""
         res = self.resilience
+        # the step in flight, where one is left, is completed first: its
+        # tokens reach their clients and ride along in the requeue
+        try:
+            self._settle(None, armed)
+        except Exception:  # noqa: BLE001 — the fault took it down too
+            self._drop_ahead()
         if isinstance(exc, _res.StepFault):
             kind = exc.kind
         elif isinstance(exc, chaos.FaultInjected):
@@ -1213,48 +1326,102 @@ class ServingEngine:
         if self.sched.has_work():
             self._work.set()
 
-    def _run_plan(self, plan, armed: bool = False) -> dict:
+    def _run_plan(self, plan, got: dict, armed: bool = False) -> None:
         """One planned step, in the four phases its ``serve.*`` spans
-        name: pack the host arrays, launch the step program, wait for the
-        device (``serve.sync``), hand the sampled tokens out."""
+        name: pack the host arrays and launch the step program, then read
+        back and hand out the step launched before it (``serve.sync``,
+        ``serve.emit``), if one is in flight — or this step itself, where
+        the host reads first. ``got`` sums what was handed out."""
         # the step-fault drill seam: an injected error here is exactly a
         # device step blowing up with requests mid-flight (contained by
         # _contain_step_fault when the resilience plane is armed)
         chaos.site("serve.engine_step")
+        prior = self._ahead
         with RecordEvent("serve.pack"):
-            tokens, slots, positions, valid, sample_points = \
-                self._pack_plan(plan, armed)
+            arrays, sample_points = self._pack_plan(plan, armed)
         with RecordEvent("serve.launch"):
             # the page tables go as a COPY: the CPU backend's asarray
             # aliases a numpy array, and the next _pack_plan rewrites
-            # these rows while a step that sampled nothing (a prefill
-            # chunk) may still be running
-            logits, exits, self._kp, self._vp = self._step_call(
-                self._w, jnp.asarray(tokens), jnp.asarray(slots),
-                jnp.asarray(positions), jnp.asarray(valid),
-                jnp.array(self._tables), self._kp, self._vp)
+            # these rows while this step may still be running
+            tokens, feed, slots, positions, valid = arrays
+            out, self._kp, self._vp = self._step_call(
+                self._w, jnp.asarray(tokens), self._prev, jnp.asarray(feed),
+                jnp.asarray(slots), jnp.asarray(positions),
+                jnp.asarray(valid), jnp.array(self._tables), self._kp,
+                self._vp)
+        self._prev = out
+        self._ahead = launched = _Launched(plan, sample_points, out)
+        # the fed positions are confirmed at launch, and a sampling row's
+        # token is the next plan's to feed from the device
+        for e in plan.entries:
+            e.req.pos = e.start + e.n      # draft positions confirmed later
+        for e, i in sample_points:
+            e.req.unread = (launched, i)
+        if prior is not None:
+            self.steps_ahead += 1
+            try:
+                self._read(prior, got, armed)
+            except BaseException:
+                # this step was planned on tokens never handed out
+                self._forget(prior)
+                self._drop_ahead()
+                raise
+        if self._reads_first():
+            self._settle(got, armed)
+
+    def _settle(self, got: Optional[dict] = None, armed: bool = False
+                ) -> None:
+        """Read back and hand out the step in flight, if any. Every path
+        that reads or moves requests outside a step's own course calls it
+        first (under the engine lock)."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None:
+            return
+        try:
+            self._read(ahead, _emitted() if got is None else got, armed)
+        finally:
+            self._forget(ahead)
+
+    def _drop_ahead(self) -> None:
+        """Forget the step in flight without reading it (abort, or a fault
+        that took its read down)."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is not None:
+            self._forget(ahead)
+
+    @staticmethod
+    def _forget(step: _Launched) -> None:
+        """Clear the marks of tokens ``step`` sampled that were not read:
+        their sequences, requeued or failed, never wait on them."""
+        for e, _ in step.sample_points:
+            if e.req.unread is not None and e.req.unread[0] is step:
+                e.req.unread = None
+
+    def _read(self, step: _Launched, got: dict, armed: bool) -> None:
+        """Wait for one launched step (``serve.sync``: the host waits on
+        the device) and hand its tokens out (``serve.emit``)."""
+        t_max = self.config.token_budget
+        counts, all_tok = {}, None
         with RecordEvent("serve.sync"):
-            res = self.resilience
-            if res is not None and res.nan_guard and \
-                    not bool(_all_finite(logits)):
-                # garbage logits: fail the STEP before any token of it can
-                # reach a client (pools already swapped — consistent; the
-                # containment path requeues everything for recompute)
-                raise _res.StepFault(
-                    "nan_logits", f"step {self.steps + 1} produced non-finite "
-                    f"logits over {int(valid.sum())} packed tokens")
-            all_tok, counts = None, {}
-            if sample_points:
-                if exits is None:
-                    all_tok = np.asarray(_argmax_rows(logits))
-                else:
-                    got = np.asarray(
-                        _beside_exits(_argmax_rows(logits), exits))
-                    all_tok, beside = got[:len(valid)], got[len(valid):]
+            if step.sample_points or self._check is not None:
+                arr = np.asarray(step.out)
+                if self._check is not None and not arr[-1]:
+                    # garbage logits: fail the STEP before any token of it
+                    # can reach a client (pools already swapped —
+                    # consistent; the containment path requeues
+                    # everything for recompute)
+                    raise _res.StepFault(
+                        "nan_logits", f"step {self.steps + 1} produced "
+                        f"non-finite logits over "
+                        f"{step.plan.total_tokens} packed tokens")
+                all_tok = arr[:t_max]
+                beside = arr[t_max:len(arr) - (self._check is not None)]
+                if len(beside) and step.sample_points:
                     counts = self.dec.step_counts(
-                        beside, [i for _, i in sample_points])
+                        beside, [i for _, i in step.sample_points])
         with RecordEvent("serve.emit", **counts):
-            return self._emit_sampled(plan, sample_points, all_tok, armed)
+            self._emit_sampled(step, all_tok, armed, got)
+        got["read"] += 1
 
     # -- a state beside the pages (decoders with ``state_shapes``) -------------
     def _new_state(self) -> list:
@@ -1300,13 +1467,14 @@ class ServingEngine:
         """The jitted step program with decoder and annotator bound. With a
         state, a call takes and returns what a stateless one does and the
         state pools ride behind the page pools, kept on the engine."""
+        statics = (self.dec, self._shard, self._sample, self._check)
         if not self._state:
-            return partial(_engine_step, self.dec, self._shard)
+            return partial(_engine_step, *statics)
 
         def step_call(*args):
-            logits, exits, kp, vp, *self._state = _engine_step_state(
-                self.dec, self._shard, *args, *self._state)
-            return logits, exits, kp, vp
+            out, kp, vp, *self._state = _engine_step_state(
+                *statics, *args, *self._state)
+            return out, kp, vp
 
         return step_call
 
@@ -1349,13 +1517,20 @@ class ServingEngine:
         tq = min(TQ, self.config.token_budget)
         return sum(-(-(e.n + len(e.draft)) // tq) for e in plan.entries)
 
+    def _device_fed(self, plan) -> int:
+        """Rows whose input token is the sample of the step in flight,
+        which the program takes on the device (``serve.run`` carries it)."""
+        return sum(e.start + e.n > len(e.req.seq) for e in plan.entries)
+
     def _pack_plan(self, plan, armed: bool):
         """The numpy fill of the step program's inputs (and of the page
-        table rows of the scheduled slots). Returns (tokens, slots,
-        positions, valid, sample_points): sample_points are (entry, row of
-        its LAST seq token)."""
+        table rows of the scheduled slots), fresh each step. Returns
+        ((tokens, feed, slots, positions, valid), sample_points): ``feed``
+        is -1, or the row of the step in flight whose sample is the row's
+        token; sample_points are (entry, row of its LAST seq token)."""
         t_max = self.config.token_budget
         tokens = np.zeros(t_max, np.int32)
+        feed = np.full(t_max, -1, np.int32)
         slots = np.zeros(t_max, np.int32)
         positions = np.zeros(t_max, np.int32)
         valid = np.zeros(t_max, bool)
@@ -1363,7 +1538,10 @@ class ServingEngine:
         idx = 0
         for e in plan.entries:
             n, k = e.n, len(e.draft)
-            tokens[idx:idx + n] = e.req.seq[e.start:e.start + n]
+            known = e.req.seq[e.start:e.start + n]
+            tokens[idx:idx + len(known)] = known
+            if len(known) < n:
+                feed[idx + n - 1] = e.req.unread[1]
             if k:
                 # the verify chunk: drafted tokens ride the SAME packed
                 # batch at the positions they would occupy if accepted —
@@ -1380,23 +1558,27 @@ class ServingEngine:
             if armed and e.start + e.n < len(e.req.seq):
                 self.obs.on_prefill(e.req, e.start, e.n)
             idx += n + k
-        return tokens, slots, positions, valid, sample_points
+        return (tokens, feed, slots, positions, valid), sample_points
 
-    def _emit_sampled(self, plan, sample_points, all_tok, armed: bool) -> dict:
-        """Confirm the fed positions and hand out the sampled tokens
-        (``all_tok``: the step's argmax row per packed token, on the host):
-        verify drafts, emit, roll back rejected pages, evict the finished.
-        Returns the step's counts."""
-        out = {"tokens": 0, "finished": 0, "finished_rids": [],
-               "ttfts": [], "accepted": 0, "rollback_pages": 0}
-        for e in plan.entries:
-            e.req.pos = e.start + e.n    # draft positions confirmed below
+    def _emit_sampled(self, step: _Launched, all_tok, armed: bool,
+                      out: dict) -> None:
+        """Hand out one read-back step's sampled tokens (``all_tok``: its
+        argmax row per packed token, on the host): verify drafts, emit,
+        roll back rejected pages, evict the finished. A row of a sequence
+        that finished at an earlier read (an EOS found after this step was
+        launched) is dropped. Adds the step's counts to ``out``."""
+        plan, sample_points = step.plan, step.sample_points
         if not sample_points:
-            return out
+            return
         now = time.monotonic()
         finished = []
+        accepted = rollback = 0
         for e, i in sample_points:
             req = e.req
+            if req.state != RUNNING:
+                continue
+            if req.unread is not None and req.unread[0] is step:
+                req.unread = None
             k = len(e.draft)
             targets = [int(t) for t in all_tok[i:i + k + 1]]
             if k:
@@ -1440,10 +1622,11 @@ class ServingEngine:
             # used-1 drafts were confirmed correct (eos may cut the
             # emission short of the full accepted prefix)
             consumed = used - 1
-            out["accepted"] += consumed
-            req.pos = e.start + e.n + consumed
+            accepted += consumed
             if armed:
                 self.obs.on_decode(req, used, k, consumed)
+            if k:
+                req.pos = e.start + e.n + consumed
             if consumed < k:
                 # rejected drafts left garbage K/V past the accepted
                 # frontier: roll the page list back; copy-on-write if
@@ -1452,7 +1635,7 @@ class ServingEngine:
                 kept, released, cow = self.pool.truncate(req.pages,
                                                          req.pos)
                 req.pages = kept
-                out["rollback_pages"] += released
+                rollback += released
                 if cow is not None:
                     self._kp, self._vp = _copy_page(
                         self._kp, self._vp, cow[0], cow[1])
@@ -1468,12 +1651,13 @@ class ServingEngine:
                     req.handoff_at if req.handoff_at is not None
                     else req.arrival)
                 self._e2e_n += 1
-        out["finished"] = len(finished)
-        out["finished_rids"] = [r.rid for r in finished]
+        out["finished"] += len(finished)
+        out["finished_rids"] += [r.rid for r in finished]
+        out["accepted"] += accepted
+        out["rollback_pages"] += rollback
         self.spec_proposed += plan.drafted
-        self.spec_accepted += out["accepted"]
-        self.spec_rollback_pages += out["rollback_pages"]
-        return out
+        self.spec_accepted += accepted
+        self.spec_rollback_pages += rollback
 
     def run_until_idle(self, max_steps: Optional[int] = None) -> int:
         """Drive step() until no work remains; returns steps taken."""
@@ -1489,7 +1673,7 @@ class ServingEngine:
 
     def has_work(self) -> bool:
         with self._lock:
-            return self.sched.has_work()
+            return self._has_work()
 
     def generate_batch(self, prompts: Sequence[Sequence[int]],
                        max_new_tokens: int = 32,
@@ -1535,6 +1719,7 @@ class ServingEngine:
                 break
         drain_seconds = time.monotonic() - t0
         with self._lock:
+            self._settle()
             manifest = _res.build_manifest(self._live_requests(),
                                            drain_seconds)
             self.drains += 1
@@ -1572,6 +1757,9 @@ class ServingEngine:
         exception instead of a forever-parked Future. Returns how many
         requests were failed. Always available, armed or not."""
         with self._lock:
+            # the step in flight is dropped, not read: a request failed
+            # here gets no token after its terminal error
+            self._drop_ahead()
             live = self._live_requests()
             self._handoff_outbox = []
             # retained two-phase exports: a dead exporter's pending
@@ -1587,7 +1775,7 @@ class ServingEngine:
                 self.sched.fail_request(req, err, reason="error")
             self.requests_failed += len(live)
             self._tables[:] = -1
-            if not self.sched.has_work():
+            if not self._has_work():
                 self._work.clear()
         self._notify_admit()
         return len(live)
@@ -1641,7 +1829,7 @@ class ServingEngine:
         with self._lock:
             s = self.pool.stats
             base = {
-                "version": 4,
+                "version": 5,
                 "steps": self.steps,
                 "tokens_generated": self.tokens_generated,
                 "queue_depth": self.sched.queue_depth(),
@@ -1668,6 +1856,11 @@ class ServingEngine:
                 "attention_tiles": {"attn_tiles": self.attn_tiles,
                                     "attn_tiles_ahead":
                                         self.attn_tiles_ahead},
+                # steps launched while the step before was in flight, and
+                # the decode rows whose token the program took from it
+                "overlap": {"steps_ahead": self.steps_ahead,
+                            "decode_rows": self.decode_rows,
+                            "device_fed_rows": self.device_fed_rows},
                 # two numbers, equal unless the model runs its layers
                 # several times a token: the pools are cache_entries deep
                 # and what a cached token costs across them, K and V or
@@ -1810,20 +2003,22 @@ class _BesideState:
         return out
 
 
-def _engine_step_impl_state(dec, shard, w, tokens, slot_ids, positions,
-                            valid, tables, k_pools, v_pools, state):
+def _engine_step_impl_state(dec, shard, sample, check, w, tokens, prev, feed,
+                            slot_ids, positions, valid, tables, k_pools,
+                            v_pools, state):
     """``_engine_step_impl`` for a decoder that keeps a state beside the
     pages: the same program with ``state`` (the engine's pools ``[state
     layers, max_seqs, ...]``, donated like the page pools) threaded through
     the decoder's step and returned advanced. A decoder without a state
     never comes here, so its program is what it was."""
     bound = _BesideState(dec, state, slot_ids, valid)
-    return (*_engine_step_impl(bound, shard, w, tokens, slot_ids, positions,
-                               valid, tables, k_pools, v_pools), bound.state)
+    return (*_engine_step_impl(bound, shard, sample, check, w, tokens, prev,
+                               feed, slot_ids, positions, valid, tables,
+                               k_pools, v_pools), bound.state)
 
 
-_engine_step_state = partial(jax.jit, static_argnums=(0, 1),
-                             donate_argnums=(8, 9, 10))(
+_engine_step_state = partial(jax.jit, static_argnums=(0, 1, 2, 3),
+                             donate_argnums=(12, 13, 14))(
                                  _engine_step_impl_state)
 
 

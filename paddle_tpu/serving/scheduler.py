@@ -79,9 +79,11 @@ class Request:
     """One generation request inside the engine.
 
     ``seq`` is the token stream fed to the model: the prompt, then each
-    sampled token as it is accepted. ``pos`` counts how many of those are
-    already in the KV cache; the request is in its decode phase once
-    ``pos == len(seq) - 1`` (one pending token to feed). After a
+    sampled token as it is read back. ``pos`` counts how many of those a
+    launched step has fed to the KV cache; the request is in its decode
+    phase once ``pos == seq_len - 1`` (one pending token to feed). A
+    token sampled by the step in flight and not read yet counts in
+    ``seq_len`` and not in ``seq``: ``unread`` says where it is. After a
     preemption ``pos`` rolls back to the prefix-cached depth and the
     generated tokens ride along in ``seq`` for recompute."""
 
@@ -123,6 +125,10 @@ class Request:
         self.slot: Optional[int] = None
         self.pages: List[int] = []
         self.pos = 0                  # tokens already in the KV cache
+        # (step, row) of the launched step whose sample is this sequence's
+        # next token, until the engine reads it back (None otherwise): the
+        # next plan feeds that row's token on the device
+        self.unread = None
         self.n_prefix = 0             # of which reused from the prefix cache
         self.preemptions = 0
         self.step_retries = 0         # contained step-fault requeues
@@ -172,6 +178,12 @@ class Request:
     @property
     def done(self) -> bool:
         return self._done.is_set()
+
+    @property
+    def seq_len(self) -> int:
+        """``len(seq)``, and the token sampled on the device and not read
+        back yet where one is."""
+        return len(self.seq) + (self.unread is not None)
 
     # -- engine-side helpers --------------------------------------------------
     def emit(self, tok: int) -> None:
@@ -224,7 +236,7 @@ class StepEntry:
     def samples(self) -> bool:
         """Does this entry's last token produce a next-token sample? True
         exactly when it feeds the sequence's current last token."""
-        return self.start + self.n == len(self.req.seq)
+        return self.start + self.n == self.req.seq_len
 
 
 class StepPlan:
@@ -257,6 +269,11 @@ class StepPlan:
     @property
     def total_tokens(self) -> int:
         return sum(e.n + len(e.draft) for e in self.entries)
+
+
+class InFlight(Exception):
+    """A plan would preempt a sequence whose next token is still on the
+    device: the engine reads that step back first and plans again."""
 
 
 class Scheduler:
@@ -378,6 +395,7 @@ class Scheduler:
                 "kind": kind, "need_pages": need})
 
     def _release(self, req: Request, cache_prefix: bool) -> None:
+        req.unread = None             # a row of a step in flight is dropped
         if cache_prefix and req.pos >= len(req.prompt):
             # the prompt's full pages are valid reusable prefix content
             self.pool.register_prefix(req.prompt, req.pages)
@@ -403,6 +421,8 @@ class Scheduler:
         request back to the waiting front for recompute."""
         if not self.running:
             return None
+        if self.running[-1].unread is not None:
+            raise InFlight(self.running[-1].rid)
         victim = self.running.pop()
         self._release(victim, cache_prefix=False)
         victim.state = WAITING
@@ -495,15 +515,19 @@ class Scheduler:
         #    hands the request across the pool boundary this same step.
         if self.role == "prefill":
             for req in [r for r in self.running
-                        if r.pos >= len(r.seq) - 1]:
+                        if r.pos >= r.seq_len - 1]:
                 self.running.remove(req)
                 self._prefill_complete(req)
 
         # 1) one decode token per running sequence in its decode phase —
         #    grown pages first; exhaustion preempts the youngest (possibly
-        #    the grower itself) and retries once.
+        #    the grower itself) and retries once. A sequence whose last
+        #    token is in flight is known finished by count: not planned.
         for req in list(self.running):
-            if req.pos != len(req.seq) - 1 or budget <= 0:
+            if req.pos != req.seq_len - 1 or budget <= 0:
+                continue
+            if req.unread is not None and \
+                    len(req.output) + 1 >= req.max_new_tokens:
                 continue
             while not self._grow_pages(req, req.pos):
                 victim = self._preempt_youngest(to_grow=req)
@@ -526,7 +550,7 @@ class Scheduler:
         for req in self.running:
             if budget <= 0:
                 break
-            if req.pos >= len(req.seq) - 1:
+            if req.pos >= req.seq_len - 1:
                 continue                      # decode-phase: handled above
             chunk = min(self._prefill_cap(req), budget)
             chunk = self._fit_chunk(req, chunk)
@@ -709,7 +733,7 @@ class Scheduler:
         token yields the logits the sample comes from), but NEVER the
         final token on a prefill-role engine — that feed would sample,
         and sampling is the decode pool's half of the split."""
-        cap = len(req.seq) - req.pos
+        cap = req.seq_len - req.pos
         if self.role == "prefill":
             cap -= 1
         return cap
@@ -730,6 +754,6 @@ class Scheduler:
         return chunk
 
 
-__all__ = ["Request", "Scheduler", "StepPlan", "StepEntry",
+__all__ = ["Request", "Scheduler", "StepPlan", "StepEntry", "InFlight",
            "WAITING", "RUNNING", "FINISHED", "HANDOFF",
            "REQUEST_TRANSITIONS"]

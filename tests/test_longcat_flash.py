@@ -22,6 +22,8 @@ from paddle_tpu.kernels import ragged_pallas
 from paddle_tpu.models import longcat_flash as lf
 from paddle_tpu.serving import EngineConfig, ServingEngine, ragged
 
+import engine_record
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from bench.reference import longcat_flash_block as ref   # noqa: E402
 from bench.tools.longcat_faults import FAULTS, faulty    # noqa: E402
@@ -156,22 +158,7 @@ def _engine(model, **kw):
     return ServingEngine(model, EngineConfig(**cfg))
 
 
-def _record(eng):
-    steps = []
-    call, emit = eng._step_call, eng._emit_sampled
-
-    def step_call(*args):
-        out = call(*args)
-        steps.append([np.asarray(out[0]), np.asarray(out[1]), []])
-        return out
-
-    def emit_sampled(plan, sample_points, all_tok, armed):
-        steps[-1][2] = [(e.req, e.start + e.n - 1, i)
-                        for e, i in sample_points]
-        return emit(plan, sample_points, all_tok, armed)
-
-    eng._step_call, eng._emit_sampled = step_call, emit_sampled
-    return steps
+_record = engine_record.record
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["gather", "kernel"])

@@ -23,6 +23,8 @@ from paddle_tpu.kernels import ssm_pallas as ssm
 from paddle_tpu.models import nemotron_h as nh
 from paddle_tpu.serving import EngineConfig, ServingEngine
 
+import engine_record
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from bench.archs import nemotron_h as arch                 # noqa: E402
 from bench.reference import nemotron_h_block as ref        # noqa: E402
@@ -107,23 +109,7 @@ def _engine(model, **kw):
     return ServingEngine(model, EngineConfig(**cfg))
 
 
-def _record(eng):
-    """Every step's (logits, counters, [(request, position, row)])."""
-    steps = []
-    call, emit = eng._step_call, eng._emit_sampled
-
-    def step_call(*args):
-        out = call(*args)
-        steps.append([np.asarray(out[0]), np.asarray(out[1]), []])
-        return out
-
-    def emit_sampled(plan, sample_points, all_tok, armed):
-        steps[-1][2] = [(e.req, e.start + e.n - 1, i)
-                        for e, i in sample_points]
-        return emit(plan, sample_points, all_tok, armed)
-
-    eng._step_call, eng._emit_sampled = step_call, emit_sampled
-    return steps
+_record = engine_record.record
 
 
 # -- (a) forward and the engine against the reference ---------------------------
@@ -350,6 +336,7 @@ def test_a_state_not_carried_or_from_another_slot_moves_the_logits(fault):
     eng = _engine(model)
     other = eng.submit(_ids(32, 9).tolist(), max_new_tokens=40)   # a neighbour
     eng.step()
+    steps = _record(eng)
     call = eng._step_call
 
     def tampered(*args):
@@ -360,7 +347,6 @@ def test_a_state_not_carried_or_from_another_slot_moves_the_logits(fault):
         return out
 
     eng._step_call = tampered
-    steps = _record(eng)
     req = eng.submit(prompt, max_new_tokens=6)
     for _ in range(12):
         eng.step()

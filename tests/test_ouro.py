@@ -28,6 +28,8 @@ from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.serving import EngineConfig, ServingEngine
 from paddle_tpu.serving import engine as engine_mod
 
+import engine_record
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
@@ -172,24 +174,7 @@ def test_dense_cache_logits_match_the_reference(threshold):
 
 
 # -- (c) the serving engine: chunks and decode through the paged pools --------
-def _record(eng):
-    """Every step's logits and exit passes, with which request and position
-    each sampled row belongs to."""
-    steps = []
-    call, emit = eng._step_call, eng._emit_sampled
-
-    def step_call(*args):
-        out = call(*args)
-        steps.append([np.asarray(out[0]), np.asarray(out[1]), []])
-        return out
-
-    def emit_sampled(plan, sample_points, all_tok, armed):
-        steps[-1][2] = [(e.req, e.start + e.n - 1, i)
-                        for e, i in sample_points]
-        return emit(plan, sample_points, all_tok, armed)
-
-    eng._step_call, eng._emit_sampled = step_call, emit_sampled
-    return steps
+_record = engine_record.record
 
 
 def _check(model, steps, reqs):
@@ -399,9 +384,10 @@ def _step_equations(passes, layers=48):
     i32 = jnp.zeros((t,), jnp.int32)
     pool = jnp.zeros((dec.cache_entries, pages, 4, 8, 16), jnp.float32)
     jaxpr = jax.make_jaxpr(
-        lambda *a: engine_mod._engine_step_impl(dec, None, *a))(
-        w, i32, i32, i32, jnp.zeros((t,), bool),
-        jnp.zeros((slots, 12), jnp.int32), pool, pool)
+        lambda *a: engine_mod._engine_step_impl(
+            dec, None, engine_mod._argmax_rows, None, *a))(
+        w, i32, jnp.zeros((2 * t,), jnp.int32), i32, i32, i32,
+        jnp.zeros((t,), bool), jnp.zeros((slots, 12), jnp.int32), pool, pool)
 
     def count(j):
         n = 0

@@ -118,8 +118,8 @@ def test_a_mixed_step_writes_what_a_row_by_row_write_does(kind):
     want_v = rng.standard_normal(shape).astype(np.float32)
     first_k = want_k.copy()
     kp, vp = jnp.asarray(want_k), jnp.asarray(want_v)
-    step = jax.jit(lambda *args: engine_mod._engine_step_impl(dec, None, w,
-                                                              *args))
+    step = jax.jit(lambda *args: engine_mod._ragged_forward(dec, None, w,
+                                                            *args))
     steps = [
         # every sequence's first chunk
         _rows((0, range(5), a[:5], True), (1, range(6), b[:6], True),
@@ -172,9 +172,9 @@ def test_the_step_program_holds_no_pool_sized_copy(kind):
     i32 = jnp.zeros((t,), jnp.int32)
     pool = jnp.zeros((4, pages, dec.n_kv, BS, dec.hd), jnp.float32)
     text = jax.jit(lambda *a: engine_mod._engine_step_impl(
-        dec, None, *a)).lower(w, i32, i32, i32, jnp.zeros((t,), bool),
-                              jnp.zeros((SLOTS, TABLE), jnp.int32),
-                              pool, pool).as_text()
+        dec, None, engine_mod._argmax_rows, None, *a)).lower(
+            w, i32, i32, i32, i32, i32, jnp.zeros((t,), bool),
+            jnp.zeros((SLOTS, TABLE), jnp.int32), pool, pool).as_text()
     one_layer = re.compile(rf"tensor<(1x)?{pages}x{tail}")
     assert not one_layer.search(text), one_layer.search(text).group(0)
     whole = re.compile(rf"-> tensor<(4x{pages}|{4 * pages})x{tail}")

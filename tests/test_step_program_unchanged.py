@@ -1,10 +1,12 @@
 """The step program of a decoder WITHOUT a recurrent state is what it was
 before the engine learnt to keep one (PR 37): the lowered text of
 ``_engine_step`` for a GPT-2-shaped and a LongCat-Flash decoder at a tiny
-size, location metadata aside, hashes to what the parent commit's lowered
-(recorded below from a checkout of e759ec5 by this file's ``program_hash``).
-A later PR that means to change those programs records new hashes and says
-so; one that does not, finds out here."""
+size, location metadata aside, hashes to what was recorded below by this
+file's ``program_hash``. A later PR that means to change those programs
+records new hashes and says so; one that does not, finds out here. PR 38
+changed them on purpose: the program samples every row and takes the rows
+whose token is still on the device from the step before (``prev``,
+``feed``), and hands back one int32 array instead of the logits."""
 import hashlib
 
 import jax
@@ -15,11 +17,11 @@ import paddle_tpu as paddle
 from paddle_tpu.generation import _decoder_for
 from paddle_tpu.serving import engine as E
 
-PARENT = {
+PARENT = {                               # recorded in PR 38
     "gpt":
-        "90c66c8f668e0c2c9318536bef5b5c76f6fff20aea47762c790f055429ca80e3",
+        "3d33c416def4085d721bc13457ca1c14e2b442edd6b0a43e4b753fe4ecfa1a60",
     "longcat":
-        "d764b5a0c88b7c6ad1fc3a13b74af1d0989136fce1951d4eb7edd9a7c621b225",
+        "4469b5e0ba898e67a0dfae10a41c662da4cc6a10b44d94b0df04ac415dad2b3a",
 }
 
 
@@ -44,8 +46,10 @@ def program_hash(model, rows=16, slots=4, pages=24, table=6, bs=8):
     sds, i32 = jax.ShapeDtypeStruct, jnp.int32
     kp = sds((dec.cache_entries, pages, dec.n_kv, bs, dec.hd), jnp.float32)
     vp = sds(kp.shape[:-1] + (dec.v_dim,), jnp.float32)
+    row = sds((rows,), i32)
+    prev = sds((rows + dec.beside_width(rows),), i32)
     text = E._engine_step.lower(
-        dec, None, w, sds((rows,), i32), sds((rows,), i32), sds((rows,), i32),
+        dec, None, E._argmax_rows, None, w, row, prev, row, row, row,
         sds((rows,), jnp.bool_), sds((slots, table), i32), kp, vp).as_text()
     assert "loc(" not in text
     return hashlib.sha256(text.encode()).hexdigest()
@@ -65,4 +69,4 @@ def test_a_stateless_step_takes_no_argument_for_a_state():
                                             block_size=4, num_blocks=16))
     assert eng._state == []
     assert eng._step_call.func is E._engine_step      # the parent's jit
-    assert eng._step_call.args == (eng.dec, None)
+    assert eng._step_call.args == (eng.dec, None, E._argmax_rows, None)

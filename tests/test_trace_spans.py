@@ -93,6 +93,11 @@ SERVE_PHASES = ["serve.schedule", "serve.run", "serve.pack", "serve.launch",
 
 def test_engine_emits_each_phase_once_a_step_with_the_plans_counts(
         tmp_path, monkeypatch):
+    """A step launches its plan and then reads back the step before it:
+    ``serve.pack``/``serve.launch`` are this step's, ``serve.sync``/
+    ``serve.emit`` the one before's, all four inside ``serve.run``. The
+    first step has nothing in flight to read; after the last plan one call
+    only reads (no ``serve.run``), and one more finds no work."""
     eng = ServingEngine(_llama(), EngineConfig(
         max_seqs=4, token_budget=16, block_size=4, num_blocks=64))
     rng = np.random.default_rng(0)
@@ -101,9 +106,9 @@ def test_engine_emits_each_phase_once_a_step_with_the_plans_counts(
     plans = []
     run_plan = ServingEngine._run_plan
 
-    def spy(self, plan, armed=False):
+    def spy(self, plan, got, armed=False):
         plans.append(plan.total_tokens)
-        return run_plan(self, plan, armed)
+        return run_plan(self, plan, got, armed)
 
     monkeypatch.setattr(ServingEngine, "_run_plan", spy)
     jax.profiler.start_trace(str(tmp_path))
@@ -118,22 +123,29 @@ def test_engine_emits_each_phase_once_a_step_with_the_plans_counts(
     assert all(r.done and r.error is None for r in reqs)
     spans = _host_spans(tmp_path, "serve.")
     steps = [s for s in spans if s["name"] == "serve.step"]
-    assert len(steps) == len(plans) + 1
-    idle = steps[-1]
-    assert [s["name"] for s in spans if s["ts"] > idle["ts"]] == [
+    assert len(steps) == len(plans) + 2
+
+    def inside(step):
+        lo, hi = step["ts"], step["ts"] + step["dur"]
+        return [s for s in spans if s is not step and lo <= s["ts"] <= hi]
+
+    assert [s["name"] for s in inside(steps[-2])] == [
+        "serve.schedule", "serve.sync", "serve.emit", "serve.post"]
+    assert [s["name"] for s in inside(steps[-1])] == [
         "serve.schedule", "serve.post"]
     assert sum(s["name"] == "serve.submit" for s in spans) == 3
-    for step, tokens in zip(steps, plans):
-        lo, hi = step["ts"], step["ts"] + step["dur"]
-        inside = [s for s in spans if s is not step and lo <= s["ts"] <= hi]
-        assert sorted(s["name"] for s in inside) == sorted(SERVE_PHASES)
-        by = {s["name"]: s for s in inside}
+    for i, (step, tokens) in enumerate(zip(steps, plans)):
+        got = inside(step)
+        names = [n for n in SERVE_PHASES
+                 if i or n not in ("serve.sync", "serve.emit")]
+        assert sorted(s["name"] for s in got) == sorted(names)
+        by = {s["name"]: s for s in got}
         run = by["serve.run"]
-        for child in ("serve.pack", "serve.launch", "serve.sync", "serve.emit"):
+        for child in names[2:-1]:
             assert run["ts"] <= by[child]["ts"]
             assert by[child]["ts"] + by[child]["dur"] \
                 <= run["ts"] + run["dur"] + 1e-3
-        order = [by[n]["ts"] for n in SERVE_PHASES]
+        order = [by[n]["ts"] for n in names]
         assert order == sorted(order)
         a = run["args"]
         assert int(a["prefill_tokens"]) + int(a["decode_tokens"]) == tokens
@@ -141,9 +153,10 @@ def test_engine_emits_each_phase_once_a_step_with_the_plans_counts(
         assert set(a) == {"prefill_tokens", "decode_tokens",
                           "first_scheduled", "first_wait_s",
                           "pages_walked", "pages_tabled", "attn_tiles",
-                          "attn_tiles_ahead", "layer_visits"}
+                          "attn_tiles_ahead", "layer_visits",
+                          "device_fed_rows"}
         assert int(a["layer_visits"]) == 2     # one visit a layer
-        assert not any(s.get("args") for s in inside if s is not run)
+        assert not any(s.get("args") for s in got if s is not run)
     runs = [s["args"] for s in spans if s["name"] == "serve.run"]
     # each request is planned for the first time exactly once
     assert sum(int(a["first_scheduled"]) for a in runs) == 3
@@ -153,6 +166,8 @@ def test_engine_emits_each_phase_once_a_step_with_the_plans_counts(
         == pytest.approx(waited, rel=1e-4, abs=1e-6)
     assert sum(int(a["prefill_tokens"]) for a in runs) == 21 + 6 + 11
     assert sum(int(a["decode_tokens"]) for a in runs) == 3 * 3
+    # every decode row took its token from the step before, on the device
+    assert sum(int(a["device_fed_rows"]) for a in runs) == 3 * 3
 
 
 def test_trainer_emits_step_and_block_spans(tmp_path):
@@ -197,8 +212,9 @@ def test_serving_step_program_names_its_scopes(family):
     t = eng.config.token_budget
     i32 = jnp.zeros(t, jnp.int32)
     text = engine_mod._engine_step.lower(
-        eng.dec, None, eng._w, i32, i32, i32, jnp.zeros(t, bool),
-        jnp.asarray(eng._tables), eng._kp, eng._vp).as_text(debug_info=True)
+        eng.dec, None, eng._sample, None, eng._w, i32, eng._prev, i32, i32,
+        i32, jnp.zeros(t, bool), jnp.asarray(eng._tables), eng._kp,
+        eng._vp).as_text(debug_info=True)
     assert {"embed", "attn_proj", "kv_write", "paged_attention", "mlp",
             "head"} <= _scope_names(text)
 

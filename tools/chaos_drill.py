@@ -1210,7 +1210,7 @@ def run_elastic_drill(seed: int = 1234, verbose: bool = True):
         tag += 5
     # post-swing steady tail: traffic settles back to the base rate, so
     # the drain-out retire fires while the victim still carries work
-    for p in range(22, 80, 2):
+    for p in range(22, 100, 2):
         schedule[p] = [tag]
         tag += 1
     prompts = [rng.integers(1, 61, (int(rng.integers(8, 13)),)).tolist()
@@ -1242,7 +1242,7 @@ def run_elastic_drill(seed: int = 1234, verbose: bool = True):
         handles = {}
         try:
             p = 0
-            while p < 80 or router.has_work():
+            while p < 100 or router.has_work():
                 for t in schedule.get(p, ()):
                     handles[t] = router.submit(prompts[t],
                                                max_new_tokens=max_new,
