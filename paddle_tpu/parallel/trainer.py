@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..autograd.tape import no_grad
 from ..utils.jax_compat import shard_map
 from ..framework.random import key_context, next_key
-from ..profiler import RecordEvent
+from ..profiler import RecordEvent, host_time
 from ..optimizer import (ClipGradByGlobalNorm, ClipGradByNorm, ClipGradByValue,
                          Optimizer)
 from ..tensor import Tensor
@@ -627,7 +627,7 @@ class SpmdTrainer:
         """One compiled fwd+bwd+update step. batch: Tensors or arrays."""
         batch_arrays = tuple(b._data if isinstance(b, Tensor) else jnp.asarray(b)
                              for b in batch)
-        with RecordEvent("train.step"):
+        with RecordEvent("train.step"), host_time.Realm("train"):
             # validated per call: jit retraces on new shapes, and a
             # non-divisible batch must fail with THIS message, not a reshape
             # error deep inside the trace
@@ -672,7 +672,7 @@ class SpmdTrainer:
         parameters, so the loss alone would leave the last update in
         flight)."""
         if self._last_loss is not None:
-            with RecordEvent("train.block"):
+            with RecordEvent("train.block"), host_time.Realm("train"):
                 jax.block_until_ready(
                     (self._last_loss,
                      [self._params[n]._data for n in self._param_list]))

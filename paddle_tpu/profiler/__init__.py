@@ -12,9 +12,11 @@ the platform's CUPTI equivalent). While a ``Profiler`` records, spans are
 also kept in ONE shared, lock-guarded buffer (they may begin/end on any
 thread — dataloader worker spans are collected too) for ``summary()`` and
 the chrome export. The serving engine and the trainer carry fixed spans
-(``serve.*``, ``train.*``; README "Observability"); the spans per dispatched
-op and per collective entry point, thousands a step, stay behind a single
-boolean so disabled runs pay one check.
+(``serve.*``, ``train.*``; README "Observability"; ``host_time`` counts where
+each engine step's host time went and puts every Python collection on the
+trace as a span); the spans per dispatched op and per collective entry
+point, thousands a step, stay behind a single boolean so disabled runs pay
+one check.
 
 Exports: chrome-trace JSON with rank-qualified pids, process/thread-name
 metadata and a wall-clock anchor (``tools/trace_merge.py`` merges N ranks
@@ -38,7 +40,7 @@ from typing import Callable, List, Optional
 
 from jax.profiler import TraceAnnotation
 
-from . import evidence, instrument, memwatch, metrics  # noqa: F401
+from . import evidence, host_time, instrument, memwatch, metrics  # noqa: F401
 from . import runlog  # noqa: F401 (re-export)
 from .memwatch import (MemoryWatcher, MemWatchConfig,  # noqa: F401
                        resolve_watcher)

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..profiler import RecordEvent
+from ..profiler import RecordEvent, host_time as _host
 from ..profiler import instrument as _instr
 from ..resilience import chaos
 from . import ragged as _ragged
@@ -341,13 +341,14 @@ def _emitted() -> dict:
 
 
 class _Launched:
-    """A launched step: its plan, the rows that sample (``(entry, row)``)
-    and its output array, on the device until the host reads it."""
+    """A launched step: its plan, the rows that sample (``(entry, row)``),
+    its output array (on the device until read) and its step number."""
 
-    __slots__ = ("plan", "sample_points", "out")
+    __slots__ = ("plan", "sample_points", "out", "n")
 
-    def __init__(self, plan, sample_points, out):
+    def __init__(self, plan, sample_points, out, n):
         self.plan, self.sample_points, self.out = plan, sample_points, out
+        self.n = n
 
 
 class ServingEngine:
@@ -488,14 +489,12 @@ class ServingEngine:
         # steps launched while the one before was in flight, the decode
         # rows planned, and those of them whose token came from the device
         self.steps_ahead = self.decode_rows = self.device_fed_rows = 0
-        self.spec_proposed = 0
-        self.spec_accepted = 0
-        self.spec_rollback_pages = 0
+        # where each launched step's host time went (serve.post's arguments)
+        self.host_log = _host.HostLog()
+        self.spec_proposed = self.spec_accepted = self.spec_rollback_pages = 0
         self._draining = False
         self._admit_cv = threading.Condition()
-        self.step_faults = 0
-        self.request_retries = 0
-        self.requests_failed = 0
+        self.step_faults = self.request_retries = self.requests_failed = 0
         self.shed_total = 0
         self.drains = 0
         # running mean of finished-request e2e seconds: the evidence the
@@ -686,6 +685,7 @@ class ServingEngine:
                           on_token=on_token, stream=stream,
                           ttft_deadline=ttft_deadline,
                           tpot_deadline=tpot_deadline, tag=tag)
+            req.launch_mark = getattr(self._ahead, "n", self.steps + 1)
             total = len(req.prompt) + req.max_new_tokens
             if total > self.max_model_len:
                 raise ValueError(
@@ -843,7 +843,7 @@ class ServingEngine:
         (``serve.schedule``, ``serve.run`` and its four children,
         ``serve.post``): whatever ``jax.profiler`` trace runs sees where
         the host was while the device stood idle."""
-        with RecordEvent("serve.step"):
+        with RecordEvent("serve.step"), _host.Realm("serve"):
             return self._step()
 
     def _reads_first(self) -> bool:
@@ -858,11 +858,12 @@ class ServingEngine:
         return self.sched.has_work() or self._ahead is not None
 
     def _step(self) -> bool:
-        t0 = time.monotonic()
         obs = self.obs
         armed = obs is not None and obs.armed
-        got = _emitted()
+        got, record = _emitted(), None
+        acct = _host.Interval()        # where the host's time goes: serve.post
         with self._lock:
+            acct.locked()
             q0 = self.pool.stats["prefix_queries"]
             h0 = self.pool.stats["prefix_hits"]
             plan = self._schedule(got, armed)
@@ -883,10 +884,9 @@ class ServingEngine:
                                and (plan.explain["exhaustion"]
                                     or plan.explain["chaos"]))
                               or obs.has_pending()):
-                    obs.record_step({
+                    record = {
                         "step": self.steps, "empty": True,
-                        "t_mono_s": round(t0, 6),
-                        "dt_s": round(time.monotonic() - t0, 6),
+                        "t_mono_s": round(acct.t0 / 1e9, 6),
                         "plan": plan.explain, "entries": [],
                         "tokens": got["tokens"],
                         "finished": got["finished_rids"],
@@ -897,7 +897,7 @@ class ServingEngine:
                                  "free": self.pool.free_blocks(),
                                  "utilization":
                                      round(self.pool.utilization(), 4)},
-                    })
+                    }
                 if not self._has_work():
                     self._work.clear()
             else:
@@ -930,7 +930,7 @@ class ServingEngine:
                         # in scheduler state, so a router's salvage
                         # manifest still sees them
                         raise
-                    self._contain_step_fault(plan, exc, armed, t0)
+                    self._contain_step_fault(plan, exc, armed, acct)
                     self._notify_admit()
                     return self._has_work()
                 # export AFTER the device call landed: a raising step
@@ -947,10 +947,8 @@ class ServingEngine:
             dq = self.pool.stats["prefix_queries"] - q0
             dh = self.pool.stats["prefix_hits"] - h0
             if armed and plan.entries:
-                obs.record_step({
-                    "step": self.steps,
-                    "t_mono_s": round(t0, 6),
-                    "dt_s": round(time.monotonic() - t0, 6),
+                record = {
+                    "step": self.steps, "t_mono_s": round(acct.t0 / 1e9, 6),
                     "plan": plan.explain,
                     "entries": [{"rid": e.req.rid, "start": e.start,
                                  "n": e.n, "draft": len(e.draft)}
@@ -966,14 +964,18 @@ class ServingEngine:
                              "free": self.pool.free_blocks(),
                              "utilization": round(util, 4)},
                     "prefix": {"queries": dq, "hits": dh},
-                    "queue_depth": queue_depth,
-                    "running": running,
-                })
+                    "queue_depth": queue_depth, "running": running,
+                }
             has_work = self._has_work()
+            host = acct.read() if plan.entries or record else None
+            if plan.entries:
+                self.host_log.add(self.steps, host)
+            if armed and record is not None:
+                obs.record_step(record, host)
         # -- outside the engine lock: hand-off dispatch, telemetry I/O,
         #    metrics (the sink takes the router lock, and lock order is
         #    always engine -> nothing while dispatching)
-        with RecordEvent("serve.post"):
+        with RecordEvent("serve.post", **(host if plan.entries else {})):
             self._dispatch_handoffs(outbox)
             if self.step_hook is not None:
                 self.step_hook()
@@ -985,7 +987,7 @@ class ServingEngine:
                 # telemetry() takes it briefly for the snapshot, but the
                 # write must not stall concurrent submit() callers
                 obs.write_telemetry(self.telemetry())
-            dt = time.monotonic() - t0
+            dt = time.monotonic() - acct.t0 / 1e9
             _instr.record_serve_step(
                 plan.admitted, got["finished"], plan.preempted,
                 queue_depth, running, util, launched=bool(plan.entries))
@@ -1242,7 +1244,7 @@ class ServingEngine:
 
     # -- step-fault containment (serving/resilience.py) -----------------------
     def _contain_step_fault(self, plan, exc: BaseException, armed: bool,
-                            t0: float) -> None:
+                            acct) -> None:
         """A raising step never escapes an armed engine. Reset to a
         consistent state: re-zero the device pools if the fault
         invalidated the donated buffers, drop prefix-cache content that
@@ -1308,8 +1310,7 @@ class ServingEngine:
                     "requeued": [r.rid for r in requeued
                                  if r not in failed],
                     "failed": [r.rid for r in failed]},
-                "t_mono_s": round(t0, 6),
-                "dt_s": round(time.monotonic() - t0, 6),
+                "t_mono_s": round(acct.t0 / 1e9, 6),
                 "plan": plan.explain,
                 "entries": [{"rid": e.req.rid, "start": e.start,
                              "n": e.n, "draft": len(e.draft)}
@@ -1320,9 +1321,8 @@ class ServingEngine:
                 "pool": {"used": self.pool.used_blocks(),
                          "cached": self.pool.cached_blocks(),
                          "free": self.pool.free_blocks(),
-                         "utilization":
-                             round(self.pool.utilization(), 4)},
-            })
+                         "utilization": round(self.pool.utilization(), 4)},
+            }, acct.read())
         if self.sched.has_work():
             self._work.set()
 
@@ -1350,7 +1350,8 @@ class ServingEngine:
                 jnp.asarray(valid), jnp.array(self._tables), self._kp,
                 self._vp)
         self._prev = out
-        self._ahead = launched = _Launched(plan, sample_points, out)
+        self._ahead = launched = _Launched(plan, sample_points, out,
+                                           self.steps + 1)
         # the fed positions are confirmed at launch, and a sampling row's
         # token is the next plan's to feed from the device
         for e in plan.entries:
@@ -1369,8 +1370,7 @@ class ServingEngine:
         if self._reads_first():
             self._settle(got, armed)
 
-    def _settle(self, got: Optional[dict] = None, armed: bool = False
-                ) -> None:
+    def _settle(self, got: Optional[dict] = None, armed: bool = False) -> None:
         """Read back and hand out the step in flight, if any. Every path
         that reads or moves requests outside a step's own course calls it
         first (under the engine lock)."""
@@ -1400,9 +1400,8 @@ class ServingEngine:
     def _read(self, step: _Launched, got: dict, armed: bool) -> None:
         """Wait for one launched step (``serve.sync``: the host waits on
         the device) and hand its tokens out (``serve.emit``)."""
-        t_max = self.config.token_budget
-        counts, all_tok = {}, None
-        with RecordEvent("serve.sync"):
+        t_max, counts, all_tok = self.config.token_budget, {}, None
+        with RecordEvent("serve.sync"), _host.DeviceWait():
             if step.sample_points or self._check is not None:
                 arr = np.asarray(step.out)
                 if self._check is not None and not arr[-1]:
@@ -1419,8 +1418,9 @@ class ServingEngine:
                 if len(beside) and step.sample_points:
                     counts = self.dec.step_counts(
                         beside, [i for _, i in step.sample_points])
-        with RecordEvent("serve.emit", **counts):
-            self._emit_sampled(step, all_tok, armed, got)
+        first, now = _first_tokens(step)
+        with RecordEvent("serve.emit", **counts, **first):
+            self._emit_sampled(step, all_tok, armed, got, now)
         got["read"] += 1
 
     # -- a state beside the pages (decoders with ``state_shapes``) -------------
@@ -1561,16 +1561,15 @@ class ServingEngine:
         return (tokens, feed, slots, positions, valid), sample_points
 
     def _emit_sampled(self, step: _Launched, all_tok, armed: bool,
-                      out: dict) -> None:
+                      out: dict, now: float) -> None:
         """Hand out one read-back step's sampled tokens (``all_tok``: its
-        argmax row per packed token, on the host): verify drafts, emit,
-        roll back rejected pages, evict the finished. A row of a sequence
-        that finished at an earlier read (an EOS found after this step was
-        launched) is dropped. Adds the step's counts to ``out``."""
+        argmax row per packed token, on the host) at ``now``: verify drafts,
+        emit, roll back rejected pages, evict the finished. A row of a
+        sequence that finished at an earlier read (an EOS found after this
+        step was launched) is dropped. Adds the step's counts to ``out``."""
         plan, sample_points = step.plan, step.sample_points
         if not sample_points:
             return
-        now = time.monotonic()
         finished = []
         accepted = rollback = 0
         for e, i in sample_points:
@@ -1829,7 +1828,7 @@ class ServingEngine:
         with self._lock:
             s = self.pool.stats
             base = {
-                "version": 5,
+                "version": 6,
                 "steps": self.steps,
                 "tokens_generated": self.tokens_generated,
                 "queue_depth": self.sched.queue_depth(),
@@ -1861,6 +1860,7 @@ class ServingEngine:
                 "overlap": {"steps_ahead": self.steps_ahead,
                             "decode_rows": self.decode_rows,
                             "device_fed_rows": self.device_fed_rows},
+                "host": self.host_log.snapshot(),      # serve.post's, summed
                 # two numbers, equal unless the model runs its layers
                 # several times a token: the pools are cache_entries deep
                 # and what a cached token costs across them, K and V or
@@ -2086,5 +2086,31 @@ def engine_from_config(model, config=None, **overrides) -> ServingEngine:
     return ServingEngine(model, EngineConfig(**kw))
 
 
+# below the step program's frames: their line numbers are in its location
+# metadata, which the compile cache keys on (keep those where they stand)
+def _first_tokens(step: _Launched):
+    """``serve.emit``'s arguments, from ``step``'s sample points as it is
+    read back, and the hand-out's time: the requests whose first token it
+    hands out, the sum of their submit -> hand-out on the engine's clock
+    (what ``ttfts`` gets) and of the steps each waited on, from the one in
+    flight at its submit (or the next) to ``step``; none where it sampled
+    nothing."""
+    now = time.monotonic()
+    if not step.sample_points:
+        return {}, now
+    n = steps = 0
+    waited = 0.0
+    for e, _ in step.sample_points:
+        req = e.req
+        if req.state == RUNNING and req.first_token_at is None:
+            n += 1
+            waited += now - req.arrival
+            mark = req.launch_mark
+            steps += 1 if mark is None else step.n - mark + 1
+    return {"first_tokens": n, "first_token_s": waited,
+            "first_token_steps": steps}, now
+
+
 __all__ = ["EngineConfig", "ServingEngine", "EnginePredictor",
            "engine_from_config"]
+

@@ -429,11 +429,17 @@ class ServingObserver:
                 return
             self._pending.append((reason, detail))
 
-    def record_step(self, rec: Dict[str, Any]) -> None:
+    def record_step(self, rec: Dict[str, Any],
+                    host: Optional[Dict[str, Any]] = None) -> None:
         """Append one engine step's plan record to the flight ring, run
-        the stall watchdog, and flush any pending anomaly into a dump."""
+        the stall watchdog, and flush any pending anomaly into a dump.
+        ``host`` is where the step's host time went (``profiler.host_time``
+        ``Interval.read()``): the record's step clock (``dt_s``) and its
+        breakdown, so a stall dump says what the thread was doing."""
         if not self.armed:
             return
+        if host is not None:
+            rec["dt_s"], rec["host"] = host["host_wall_us"] / 1e6, host
         with self._lock:
             self._steps.append(rec)
             if rec.get("dt_s", 0.0) > self.config.stall_threshold_s:

@@ -142,6 +142,9 @@ class Request:
         # when the scheduler first gave this request an entry of a plan
         # (kept across preemption: the queue wait is counted once)
         self.first_planned_at: Optional[float] = None
+        # the engine's launch its first token waits on first (set by
+        # ``ServingEngine.submit``): ``serve.emit`` counts from it
+        self.launch_mark: Optional[int] = None
         self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
         self.finish_reason: Optional[str] = None

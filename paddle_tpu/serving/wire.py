@@ -338,8 +338,8 @@ WIRE_SCHEMAS = {
     "telemetry_line": {
         "family": "telemetry_line",
         # 2: "attention" (PR 27); 3: "model"; 4: "attention_tiles" (PR 35);
-        # 5: "overlap" (PR 38)
-        "version": 5,
+        # 5: "overlap" (PR 38); 6: "host" (PR 39)
+        "version": 6,
         "version_key": "version",
         "required": {
             "version": "int",
@@ -364,13 +364,14 @@ WIRE_SCHEMAS = {
             "attention": "str",
             "attention_tiles": "dict",
             "overlap": "dict",
+            "host": "dict",
             "model": "dict",
         },
         "item_key": None,
         "item_required": {},
         "item_optional": {},
         "key_hashes": {1: "f2b55577", 2: "a5410fb5", 3: "3a9c8544",
-                       4: "4b58fb5c", 5: "f2e21e83"},
+                       4: "4b58fb5c", 5: "f2e21e83", 6: "9e293f84"},
         "byte_stable": False,
         "builders": ("serving/engine.py::telemetry",),
         "consumers": (),

@@ -29,14 +29,14 @@ def record(eng):
     emit = eng._emit_sampled
     t_max = eng.config.token_budget
 
-    def emit_sampled(step, all_tok, armed, out):
+    def emit_sampled(step, all_tok, armed, out, now):
         k = next((k for k, o in enumerate(launched) if o is step.out), None)
         if k is not None:
             beside = np.asarray(step.out)[t_max:]      # the step has run
             steps.append([logits[k], beside,
                           [(e.req, e.start + e.n - 1, i)
                            for e, i in step.sample_points]])
-        return emit(step, all_tok, armed, out)
+        return emit(step, all_tok, armed, out, now)
 
     eng._step_call, eng._emit_sampled = step_call, emit_sampled
     return steps

@@ -22,9 +22,10 @@ from paddle_tpu.serving import EngineConfig, ServingEngine
 from paddle_tpu.serving import engine as engine_mod
 
 
-def _host_spans(trace_dir, prefix):
+def _host_spans(trace_dir, prefix, collections=False):
     """The host plane's complete events whose name starts with ``prefix``,
-    from the trace ``jax.profiler`` wrote under ``trace_dir``."""
+    from the trace ``jax.profiler`` wrote under ``trace_dir``; a Python
+    collection's span (``<prefix>gc``, whenever one runs) only if asked."""
     (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.trace.json.gz")
     with gzip.open(path) as f:
         events = json.load(f)["traceEvents"]
@@ -32,7 +33,8 @@ def _host_spans(trace_dir, prefix):
             and e["name"] == "process_name"
             and e["args"]["name"].startswith("/host:")}
     got = [e for e in events if e.get("ph") == "X" and e["pid"] in host
-           and e["name"].startswith(prefix)]
+           and e["name"].startswith(prefix)
+           and (collections or not e["name"].endswith(".gc"))]
     return sorted(got, key=lambda e: e["ts"])
 
 
@@ -89,6 +91,9 @@ def test_inactive_record_event_costs_under_5_us():
 
 SERVE_PHASES = ["serve.schedule", "serve.run", "serve.pack", "serve.launch",
                 "serve.sync", "serve.emit", "serve.post"]
+HOST = {"host_wall_us", "host_sync_us", "host_cpu_us", "host_offcpu_us",
+        "host_lock_us", "gc_us", "gc_collections", "compile_us", "compiles"}
+FIRST = {"first_tokens", "first_token_s", "first_token_steps"}
 
 
 def test_engine_emits_each_phase_once_a_step_with_the_plans_counts(
@@ -156,7 +161,14 @@ def test_engine_emits_each_phase_once_a_step_with_the_plans_counts(
                           "attn_tiles_ahead", "layer_visits",
                           "device_fed_rows"}
         assert int(a["layer_visits"]) == 2     # one visit a layer
-        assert not any(s.get("args") for s in got if s is not run)
+        # and those of the step's first tokens on serve.emit, where the host's
+        # time went on serve.post
+        assert set(by["serve.post"]["args"]) >= HOST
+        if "serve.emit" in by:             # where the step read sampled
+            assert set(by["serve.emit"].get("args", {})) in (set(), FIRST)
+        assert not any(s.get("args") for s in got
+                       if s["name"] not in ("serve.run", "serve.emit",
+                                            "serve.post"))
     runs = [s["args"] for s in spans if s["name"] == "serve.run"]
     # each request is planned for the first time exactly once
     assert sum(int(a["first_scheduled"]) for a in runs) == 3
