@@ -10,13 +10,13 @@ clock, as a span ``<realm>.gc`` with its ``generation`` and ``collected``:
 ``serve`` inside ``ServingEngine.step``, ``train`` inside
 ``SpmdTrainer.train_step`` and ``block`` (``Realm``), ``host`` elsewhere.
 
-``Interval`` reads, from its start to ``read()``, the thread's wall and CPU
-clocks, those sums, the thread's time waiting on the device (inside
-``DeviceWait``) and, where the OS has it, ``getrusage(RUSAGE_THREAD)``.
-``HostLog`` keeps running sums of the readings and the slowest of the last
-ones. An interval costs two reads of each clock and two ``getrusage`` calls
-(30 us on the TPU host, whose thread CPU clock costs 5 us a read; 6 us in a
-plain VM); no switch.
+``Interval`` reads, from its start to ``read()``, the thread's wall clock,
+those sums, the thread's time waiting on the device and the CPU time it
+spent there (inside ``DeviceWait``), and ``getrusage(RUSAGE_THREAD)``: the
+thread's CPU time and, where the OS counts them, its involuntary switches
+and major faults. ``HostLog`` keeps running sums of the readings and the
+slowest of the last ones. A step costs five reads of the wall clock and
+four ``getrusage`` calls, two of them around its device wait; no switch.
 """
 from __future__ import annotations
 
@@ -37,7 +37,7 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _sums = [0, 0, 0, 0]
 _compiles_lock = threading.Lock()   # compiles may end on several threads
 _gc_open = [None, 0]          # the collection running: its span, its start
-_local = threading.local()    # realm, device wait (ns) and its CPU (ns)
+_local = threading.local()    # realm, device wait (ns)
 _RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
 
 
@@ -87,32 +87,34 @@ gc.callbacks.append(_on_gc)
 monitoring.register_event_duration_secs_listener(_on_event)
 
 
+def _thread():
+    """This thread's CPU time (us), involuntary switches and major faults
+    (None where the OS has no per-thread ``getrusage``: the CPU clock)."""
+    if _RUSAGE_THREAD is None:
+        return time.thread_time_ns() // 1000, None, None
+    r = resource.getrusage(_RUSAGE_THREAD)
+    return int((r.ru_utime + r.ru_stime) * 1e6), r.ru_nivcsw, r.ru_majflt
+
+
 def _waits():
-    return getattr(_local, "wait_ns", 0), getattr(_local, "wait_cpu_ns", 0)
+    return getattr(_local, "wait_ns", 0), getattr(_local, "wait_cpu_us", 0)
 
 
 class DeviceWait:
     """Around a wait on the device: its time, and the CPU time the thread
-    spent in it, count as this thread's device wait."""
+    spent in it (a wait that spins), count as this thread's device wait."""
 
     __slots__ = ("_t0", "_cpu0")
 
     def __enter__(self):
-        self._t0, self._cpu0 = time.monotonic_ns(), time.thread_time_ns()
+        self._t0, self._cpu0 = time.monotonic_ns(), _thread()[0]
         return self
 
     def __exit__(self, *exc):
         wait, cpu = _waits()
         _local.wait_ns = wait + time.monotonic_ns() - self._t0
-        _local.wait_cpu_ns = cpu + time.thread_time_ns() - self._cpu0
+        _local.wait_cpu_us = cpu + _thread()[0] - self._cpu0
         return False
-
-
-def _rusage():
-    if _RUSAGE_THREAD is None:
-        return None
-    r = resource.getrusage(_RUSAGE_THREAD)
-    return r.ru_nivcsw, r.ru_majflt
 
 
 class Interval:
@@ -120,14 +122,13 @@ class Interval:
     stretch that starts by taking a lock calls ``locked()`` once it holds
     it."""
 
-    __slots__ = ("t0", "lock_ns", "_cpu0", "_sums0", "_waits0", "_ru0")
+    __slots__ = ("t0", "lock_ns", "_sums0", "_waits0", "_thread0")
 
     def __init__(self):
         self.t0 = time.monotonic_ns()
-        self._cpu0 = time.thread_time_ns()
         self._sums0 = tuple(_sums)
         self._waits0 = _waits()
-        self._ru0 = _rusage()
+        self._thread0 = _thread()
         self.lock_ns = 0
 
     def locked(self) -> None:
@@ -138,29 +139,29 @@ class Interval:
         """In microseconds: ``host_wall_us`` the whole stretch,
         ``host_sync_us`` the device waits in it, ``host_cpu_us`` the
         thread's CPU time, ``host_offcpu_us`` the rest (neither running nor
-        waiting on the device: descheduled, faulting or blocked; a device
-        wait that spins on the CPU is left out of the CPU time here),
+        waiting on the device: descheduled, faulting or blocked; the CPU
+        time of a device wait that spins is counted in the wait only),
         ``host_lock_us``, ``gc_us`` / ``gc_collections`` and ``compile_us``
         / ``compiles`` that ran meanwhile, process-wide; ``invol_switches``
         and ``major_faults`` of the thread where the OS counts them.
         Where the OS counts a thread's CPU time in ticks (the TPU host's:
-        10 ms) one stretch's CPU time is 0 or a tick, and ``host_offcpu_us``
+        10 ms) one stretch's CPU time is 0 or a tick and ``host_offcpu_us``
         may read below zero: it is not clipped, so that its sum over many
-        stretches, which is what a metric reads, is not biased."""
+        stretches, which is what a metric reads, is not biased upward."""
         wall = time.monotonic_ns() - self.t0
-        cpu = time.thread_time_ns() - self._cpu0
         wait, wait_cpu = (a - b for a, b in zip(_waits(), self._waits0))
+        now = _thread()
+        cpu = now[0] - self._thread0[0]
         sums = [a - b for a, b in zip(_sums, self._sums0)]
         got = {"host_wall_us": wall // 1000, "host_sync_us": wait // 1000,
-               "host_cpu_us": cpu // 1000,
-               "host_offcpu_us": (wall - wait - cpu + wait_cpu) // 1000,
+               "host_cpu_us": cpu,
+               "host_offcpu_us": (wall - wait) // 1000 - cpu + wait_cpu,
                "host_lock_us": self.lock_ns // 1000,
                "gc_us": sums[0] // 1000, "gc_collections": sums[1],
                "compile_us": sums[2] // 1000, "compiles": sums[3]}
-        if self._ru0 is not None:
-            ru = _rusage()
-            got["invol_switches"] = ru[0] - self._ru0[0]
-            got["major_faults"] = ru[1] - self._ru0[1]
+        if now[1] is not None:
+            got["invol_switches"] = now[1] - self._thread0[1]
+            got["major_faults"] = now[2] - self._thread0[2]
         return got
 
 

@@ -351,6 +351,29 @@ class _Launched:
         self.n = n
 
 
+def _first_tokens(step: _Launched):
+    """``serve.emit``'s arguments, from ``step``'s sample points as it is
+    read back, and the hand-out's time: the requests whose first token it
+    hands out, the sum of their submit -> hand-out on the engine's clock
+    (what ``ttfts`` gets) and of the steps each waited on, from the one in
+    flight at its submit (or the next) to ``step``; none where it sampled
+    nothing."""
+    now = time.monotonic()
+    if not step.sample_points:
+        return {}, now
+    n = steps = 0
+    waited = 0.0
+    for e, _ in step.sample_points:
+        req = e.req
+        if req.state == RUNNING and req.first_token_at is None:
+            n += 1
+            waited += now - req.arrival
+            mark = req.launch_mark
+            steps += 1 if mark is None else step.n - mark + 1
+    return {"first_tokens": n, "first_token_s": waited,
+            "first_token_steps": steps}, now
+
+
 class ServingEngine:
     """Continuous-batching LLM serving over one model.
 
@@ -491,10 +514,14 @@ class ServingEngine:
         self.steps_ahead = self.decode_rows = self.device_fed_rows = 0
         # where each launched step's host time went (serve.post's arguments)
         self.host_log = _host.HostLog()
-        self.spec_proposed = self.spec_accepted = self.spec_rollback_pages = 0
+        self.spec_proposed = 0
+        self.spec_accepted = 0
+        self.spec_rollback_pages = 0
         self._draining = False
         self._admit_cv = threading.Condition()
-        self.step_faults = self.request_retries = self.requests_failed = 0
+        self.step_faults = 0
+        self.request_retries = 0
+        self.requests_failed = 0
         self.shed_total = 0
         self.drains = 0
         # running mean of finished-request e2e seconds: the evidence the
@@ -685,7 +712,6 @@ class ServingEngine:
                           on_token=on_token, stream=stream,
                           ttft_deadline=ttft_deadline,
                           tpot_deadline=tpot_deadline, tag=tag)
-            req.launch_mark = getattr(self._ahead, "n", self.steps + 1)
             total = len(req.prompt) + req.max_new_tokens
             if total > self.max_model_len:
                 raise ValueError(
@@ -724,9 +750,7 @@ class ServingEngine:
             # deadlock a block policy (nobody steps during replay) or
             # silently drop accepted work on reject/shed
             with self._lock:
-                self.sched.submit(req)
-                if self.obs is not None:
-                    self.obs.on_submit(req)
+                self._enqueue(req)
             return
         deadline = None
         if res is not None and res.backpressure == "block" and \
@@ -737,9 +761,7 @@ class ServingEngine:
                 verdict, reason, retry_after, predicted = \
                     self._admission_verdict(req)
                 if verdict == "admit":
-                    self.sched.submit(req)
-                    if self.obs is not None:
-                        self.obs.on_submit(req)
+                    self._enqueue(req)
                     return
                 if verdict == "reject":
                     depth = self.sched.queue_depth()
@@ -775,6 +797,15 @@ class ServingEngine:
                         raise err
             with self._admit_cv:
                 self._admit_cv.wait(timeout=timeout)
+
+    def _enqueue(self, req: Request) -> None:
+        """Under the engine lock: put ``req`` on the waiting queue, marked
+        with the launch its first token waits on first (the step in
+        flight, else the next one)."""
+        req.launch_mark = getattr(self._ahead, "n", self.steps + 1)
+        self.sched.submit(req)
+        if self.obs is not None:
+            self.obs.on_submit(req)
 
     def _admission_verdict(self, req: Request):
         """(verdict, reason, retry_after_s, predicted_wait_s) for one
@@ -1370,7 +1401,8 @@ class ServingEngine:
         if self._reads_first():
             self._settle(got, armed)
 
-    def _settle(self, got: Optional[dict] = None, armed: bool = False) -> None:
+    def _settle(self, got: Optional[dict] = None, armed: bool = False
+                ) -> None:
         """Read back and hand out the step in flight, if any. Every path
         that reads or moves requests outside a step's own course calls it
         first (under the engine lock)."""
@@ -1400,7 +1432,8 @@ class ServingEngine:
     def _read(self, step: _Launched, got: dict, armed: bool) -> None:
         """Wait for one launched step (``serve.sync``: the host waits on
         the device) and hand its tokens out (``serve.emit``)."""
-        t_max, counts, all_tok = self.config.token_budget, {}, None
+        t_max = self.config.token_budget
+        counts, all_tok = {}, None
         with RecordEvent("serve.sync"), _host.DeviceWait():
             if step.sample_points or self._check is not None:
                 arr = np.asarray(step.out)
@@ -2086,31 +2119,5 @@ def engine_from_config(model, config=None, **overrides) -> ServingEngine:
     return ServingEngine(model, EngineConfig(**kw))
 
 
-# below the step program's frames: their line numbers are in its location
-# metadata, which the compile cache keys on (keep those where they stand)
-def _first_tokens(step: _Launched):
-    """``serve.emit``'s arguments, from ``step``'s sample points as it is
-    read back, and the hand-out's time: the requests whose first token it
-    hands out, the sum of their submit -> hand-out on the engine's clock
-    (what ``ttfts`` gets) and of the steps each waited on, from the one in
-    flight at its submit (or the next) to ``step``; none where it sampled
-    nothing."""
-    now = time.monotonic()
-    if not step.sample_points:
-        return {}, now
-    n = steps = 0
-    waited = 0.0
-    for e, _ in step.sample_points:
-        req = e.req
-        if req.state == RUNNING and req.first_token_at is None:
-            n += 1
-            waited += now - req.arrival
-            mark = req.launch_mark
-            steps += 1 if mark is None else step.n - mark + 1
-    return {"first_tokens": n, "first_token_s": waited,
-            "first_token_steps": steps}, now
-
-
 __all__ = ["EngineConfig", "ServingEngine", "EnginePredictor",
            "engine_from_config"]
-

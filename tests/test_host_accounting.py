@@ -174,6 +174,21 @@ def test_first_tokens_on_emit_are_the_engines_ttfts(tmp_path, monkeypatch):
         [r.first_token_at - r.arrival for r in (a, b)], rel=1e-9)
 
 
+def test_a_requests_launch_mark_is_taken_under_the_engine_lock():
+    """A submit that waits for the engine lock while a step launches marks
+    the launch as it stands once it holds the lock, not before."""
+    eng = _engine()
+    got = []
+    with eng._lock:
+        t = threading.Thread(
+            target=lambda: got.append(eng.submit(_prompt(5), 2)))
+        t.start()
+        time.sleep(0.05)                  # submit now waits for the lock
+        eng.steps = 7                     # a step launched meanwhile
+    t.join(10)
+    assert got[0].launch_mark == 8
+
+
 class _Late:
     """A step's output that takes 5 ms to come back to the host: the CPU
     backend has finished a step by the time its launch returns."""
