@@ -180,37 +180,64 @@ def _join_entry_pages(pools):
     return pools.reshape((pools.shape[0] * pools.shape[1],) + pools.shape[2:])
 
 
+def page_windows(rows, seqs, bs):
+    """The most distinct pages one step's writes can land in: ``rows``
+    packed rows (the token budget) of at most ``seqs`` sequences (page-table
+    rows), pages of ``bs`` slots; what a step program writes a visit. The
+    engine packs a sequence's rows at consecutive positions, so r rows span
+    at most ``1 + (r + bs - 2) // bs`` pages (starting in a page's last
+    slot), and never more than r. Over k sequences and T rows that sums to
+    at most ``k + (T + k (bs - 2)) // bs``, which grows with k: ``2 S + (T -
+    2 S) // bs`` at k = S, reached by S - 1 two-row chunks across a page
+    boundary and one chunk of the rest from a page's last slot; and at most
+    T, reached by T / 2 such two-row chunks where S allows them."""
+    return min(rows, 2 * seqs + (rows - 2 * seqs) // bs)
+
+
 @jax.named_scope("kv_write")
-def _page_plan(scatter, bs):
-    """For a step that writes whole pages: which of the step's rows lands in
-    each slot of each row's page. scatter = (pages [T], offs [T]). Returns
-    (hit [T, bs] bool, src [T, bs] int32): slot ``o`` of row ``r``'s page
-    takes row ``src[r, o]``'s K/V where ``hit[r, o]``. Rows of one page get
-    equal plans, so writing the page once a row writes one content."""
-    pages, offs = scatter
-    lands = (pages[:, None, None] == pages[None, :, None]) \
-        & (offs[None, :, None] == jnp.arange(bs)[None, None, :])   # [T, T', bs]
-    return lands.any(1), jnp.argmax(lands, axis=1).astype(jnp.int32)
+def _page_plan(pages, offs, windows, bs, dropped):
+    """For a step that writes whole pages: which pages it writes and which
+    of its rows lands in each slot of each. pages, offs: [T] within an
+    entry, ``dropped`` a row that writes nothing; windows: the pages the
+    step writes, ``page_windows``' bound (static). Returns (targets [W],
+    hit [W, bs] bool, src [W, bs] int32): slot ``o`` of page ``targets[w]``
+    takes row ``src[w, o]``'s K/V where ``hit[w, o]``. Where the bound
+    reaches T a row's own page is its window (W = T; a page of several rows
+    is written once a row, with one content); below it each distinct page
+    is one window, in the order of its first row, and the windows past them
+    are ``dropped``."""
+    targets = pages
+    if windows < pages.shape[0]:
+        earlier = jnp.tril(pages[:, None] == pages[None, :], -1).any(1)
+        first = (pages < dropped) & ~earlier          # a page's first row
+        opens = first[None, :] & (jnp.cumsum(first)[None, :] == jnp.arange(
+            1, windows + 1)[:, None])                 # [W, T]
+        targets = jnp.where(opens.any(1), pages[jnp.argmax(opens, axis=1)],
+                            dropped)
+    lands = (targets[:, None, None] == pages[None, :, None]) \
+        & (offs[None, :, None] == jnp.arange(bs)[None, None, :])   # [W, T, bs]
+    return targets, lands.any(1), jnp.argmax(lands, axis=1).astype(jnp.int32)
 
 
 def _entry_seams(pool_shape, scatter, attend):
     """What a layer needs to work on ONE cache entry of the joined pools.
     pool_shape: the stacked pools' [E, P, kvh, bs, D]; scatter: the step's
-    (pages [T], offs [T]) within an entry, page ``P`` a dropped row; attend:
-    ``ragged.make_attend``'s callable. Returns ``seam(e)`` -> (the scatter
-    ``_kv_write_pages`` takes for entry ``e``, the ``attend(q, kf, vf)``
-    that reads entry ``e``); ``e`` may be traced. The page plan is computed
-    here, once a step."""
+    (pages [T], offs [T]) within an entry, page ``P`` a dropped row, and
+    its ``page_windows`` (static); attend: ``ragged.make_attend``'s
+    callable. Returns ``seam(e)`` -> (the scatter ``_kv_write_pages`` takes
+    for entry ``e``, the ``attend(q, kf, vf)`` that reads entry ``e``);
+    ``e`` may be traced. The page plan is computed here, once a step."""
     entry_pages, all_pages = pool_shape[1], pool_shape[0] * pool_shape[1]
-    pages, _ = scatter
-    plan = _page_plan(scatter, pool_shape[3])
+    pages, offs, windows = scatter
+    targets, *plan = _page_plan(pages, offs, windows, pool_shape[3],
+                                entry_pages)
 
     def seam(e):
         first_page = e * entry_pages
         with jax.named_scope("kv_write"):
-            # a dropped row (one past the entry) stays dropped
-            at = jnp.where(pages >= entry_pages, all_pages,
-                           pages + first_page)
+            # a dropped window (one past the entry) stays dropped
+            at = jnp.where(targets >= entry_pages, all_pages,
+                           targets + first_page)
         return (at, *plan), partial(attend, first_page=first_page)
 
     return seam
@@ -219,17 +246,18 @@ def _entry_seams(pool_shape, scatter, attend):
 @jax.named_scope("kv_write")
 def _kv_write_pages(kf, vf, k, v, scatter):
     """Put this step's K/V rows ([T, 1, kvh, D]) into the joined pools [N,
-    kvh, bs, D], the one write of every decoder: each row's page is read,
-    the step's rows are put into its slots and the page is written back
-    whole, a page a row and nothing else of the pools moved, so the
-    scatter updates the step's donated argument (or a loop's carry) in
-    place. A scatter of single rows makes the compiler keep the pools in
-    the layout that scatter likes, slots before heads, and copy the whole
-    of them into the attention kernel's layout at every call; a page is the
-    unit both agree on. scatter = (pages [T] in the joined pools, ``N`` = a
-    dropped row; ``_page_plan``'s hit and src). Scope ``kv_write``: with
-    ``_join_entries``, ``_page_plan`` and ``_entry_seams``' targets the
-    whole cost of keeping the pools, whatever the attention reads."""
+    kvh, bs, D], the one write of every decoder: each page the step writes
+    is read, the step's rows are put into its slots and the page is written
+    back whole, in one window of ``page_windows`` a page and nothing else
+    of the pools moved, so the scatter updates the step's donated argument
+    (or a loop's carry) in place. A scatter of single rows makes the
+    compiler keep the pools in the layout that scatter likes, slots before
+    heads, and copy the whole of them into the attention kernel's layout
+    at every call; a page is the unit both agree on. scatter = (pages [W]
+    in the joined pools, ``N`` = a dropped window; ``_page_plan``'s hit and
+    src). Scope ``kv_write``: with ``_join_entries``, ``_page_plan`` and
+    ``_entry_seams``' targets the whole cost of keeping the pools, whatever
+    the attention reads."""
     return _write_pages(kf, k, scatter), _write_pages(vf, v, scatter)
 
 
@@ -237,7 +265,7 @@ def _write_pages(pool, new, scatter):
     """One pool's half of ``_kv_write_pages`` (a latent pool is one)."""
     pages, hit, src = scatter
     at = jnp.minimum(pages, pool.shape[0] - 1)
-    rows = new[:, 0][src].transpose(0, 2, 1, 3)          # [T, kvh, bs, D]
+    rows = new[:, 0][src].transpose(0, 2, 1, 3)          # [W, kvh, bs, D]
     page = jnp.where(hit[:, None, :, None], rows.astype(pool.dtype),
                      pool[at])
     return pool.at[pages].set(page, mode="drop")
@@ -436,10 +464,11 @@ class _LlamaDecoder:
         absolute position); k_pools/v_pools: [E, P, kvh, bs, D] shared
         block pools, one per cache entry (here one a layer); scatter:
         (pages [T], offs [T]) per-token write targets within an entry
-        (page index P == dropped row); attend: ``ragged.make_attend``'s
-        callable; shard as in _layer_ragged. The pools are joined into one
-        run of pages, threaded through the layers and written in place a
-        page a row: no entry is copied out and nothing is stacked again.
+        (page index P == dropped row) and the step's ``page_windows``
+        (static); attend: ``ragged.make_attend``'s callable; shard as in
+        _layer_ragged. The pools are joined into one run of pages, threaded
+        through the layers and written in place a page a window: no entry
+        is copied out and nothing is stacked again.
         Returns (logits [T, V], exits, k_pools', v_pools'): ``exits`` is
         the pass each row's logits were taken after, [T] int32, where the
         decoder runs its layers several times a token, and None here."""

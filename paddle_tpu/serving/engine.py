@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import generation as _G
 from ..profiler import RecordEvent, host_time as _host
 from ..profiler import instrument as _instr
 from ..resilience import chaos
@@ -273,13 +274,15 @@ def _ragged_forward(dec, shard, w, tokens, slot_ids, positions, valid,
     v_pools'), ``beside`` being what the decoder returns beside its logits
     (the pass each row's logits were taken after, where it runs its layers
     several times a token; the routing counts of an expert layer) or None.
-    ``step_ragged`` writes this step's rows into the pools in place (a page
-    a row; nothing of a pool's size is copied). ``shard`` (static, None on
-    a single chip) is the tensor-parallel annotator pinning the TP layout
-    through the ragged path."""
+    ``step_ragged`` writes this step's rows into the pools in place (each
+    page they land in once, in ``generation.page_windows`` windows of the
+    rows, sequences and page size; nothing of a pool's size is copied).
+    ``shard`` (static, None on a single chip) is the tensor-parallel
+    annotator pinning the TP layout through the ragged path."""
     bs = k_pools.shape[3]
     p_total = k_pools.shape[1]
     mp = tables.shape[1]
+    windows = _G.page_windows(positions.shape[0], tables.shape[0], bs)
     col = positions // bs
     page = jnp.take_along_axis(tables[slot_ids],
                                jnp.clip(col, 0, mp - 1)[:, None], 1)[:, 0]
@@ -291,8 +294,8 @@ def _ragged_forward(dec, shard, w, tokens, slot_ids, positions, valid,
                                  dec.n_heads // dec.n_kv, shard=shard,
                                  scale=dec.attn_scale, latent=dec.latent_dim)
     logits, beside, kp, vp = dec.step_ragged(w, tokens, positions, k_pools,
-                                             v_pools, (pages, offs), attend,
-                                             shard=shard)
+                                             v_pools, (pages, offs, windows),
+                                             attend, shard=shard)
     if shard is not None:
         # pin the donated outputs to the per-KV-head layout the next
         # step's inputs commit to (no silent gather between steps)
@@ -509,6 +512,12 @@ class ServingEngine:
         self.steps = 0
         self.tokens_generated = 0
         self.attn_tiles = self.attn_tiles_ahead = 0
+        # the pages the step program writes a visit (its plan's windows),
+        # and the sums over launched steps of those and of the distinct
+        # pages the steps' rows landed in
+        self._page_windows = _G.page_windows(cfg.token_budget, cfg.max_seqs,
+                                             bs)
+        self.kv_pages_written = self.kv_page_windows = 0
         # steps launched while the one before was in flight, the decode
         # rows planned, and those of them whose token came from the device
         self.steps_ahead = self.decode_rows = self.device_fed_rows = 0
@@ -939,6 +948,9 @@ class ServingEngine:
                 fed = self._device_fed(plan)
                 self.decode_rows += plan.decode_tokens
                 self.device_fed_rows += fed
+                written = self._pages_written(plan)
+                self.kv_pages_written += written
+                self.kv_page_windows += self._page_windows
                 try:
                     with RecordEvent(
                             "serve.run",
@@ -952,6 +964,8 @@ class ServingEngine:
                             attn_tiles=tiles, attn_tiles_ahead=ahead,
                             layer_visits=self.dec.cache_entries,
                             device_fed_rows=fed,
+                            pages_written=written,
+                            page_windows=self._page_windows,
                             **self._state_counts(plan)):
                         self._run_plan(plan, got, armed)
                 except Exception as exc:  # noqa: BLE001 — containment seam
@@ -1540,6 +1554,23 @@ class ServingEngine:
         return sum(-(-(e.start + e.n + len(e.draft)) // bs)
                    for e in plan.entries)
 
+    def _pages_written(self, plan) -> int:
+        """Distinct pages the step's rows land in, drafts included:
+        ``serve.run`` carries it beside ``page_windows``, the pages the
+        step program writes a visit whatever the plan. More than those
+        would lose writes, and ``generation.page_windows`` bounds them from
+        the packing alone (a sequence's rows at consecutive positions), so
+        a plan that breaks it is a fault of the engine."""
+        bs = self.config.block_size
+        written = len({p for e in plan.entries for p in e.req.pages[
+            e.start // bs:-(-(e.start + e.n + len(e.draft)) // bs)]})
+        if written > self._page_windows:
+            raise RuntimeError(
+                f"a step writes {written} pages but its program writes "
+                f"{self._page_windows} a visit: generation.page_windows' "
+                f"bound does not hold for this plan")
+        return written
+
     def _attn_tiles(self, plan) -> int:
         """Query tiles the step's attention kernel walks: each scheduled
         sequence's rows (drafts included) in tiles of ``ragged_pallas.TQ``.
@@ -1861,7 +1892,7 @@ class ServingEngine:
         with self._lock:
             s = self.pool.stats
             base = {
-                "version": 6,
+                "version": 7,
                 "steps": self.steps,
                 "tokens_generated": self.tokens_generated,
                 "queue_depth": self.sched.queue_depth(),
@@ -1893,6 +1924,10 @@ class ServingEngine:
                 "overlap": {"steps_ahead": self.steps_ahead,
                             "decode_rows": self.decode_rows,
                             "device_fed_rows": self.device_fed_rows},
+                # distinct pages the launched steps' rows landed in, and the
+                # pages their programs wrote (serve.run's, summed)
+                "kv_write": {"pages_written": self.kv_pages_written,
+                             "page_windows": self.kv_page_windows},
                 "host": self.host_log.snapshot(),      # serve.post's, summed
                 # two numbers, equal unless the model runs its layers
                 # several times a token: the pools are cache_entries deep

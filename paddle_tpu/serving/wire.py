@@ -339,7 +339,8 @@ WIRE_SCHEMAS = {
         "family": "telemetry_line",
         # 2: "attention" (PR 27); 3: "model"; 4: "attention_tiles" (PR 35);
         # 5: "overlap" (PR 38); 6: "host" (PR 39)
-        "version": 6,
+        # 7: "kv_write"
+        "version": 7,
         "version_key": "version",
         "required": {
             "version": "int",
@@ -365,13 +366,15 @@ WIRE_SCHEMAS = {
             "attention_tiles": "dict",
             "overlap": "dict",
             "host": "dict",
+            "kv_write": "dict",
             "model": "dict",
         },
         "item_key": None,
         "item_required": {},
         "item_optional": {},
         "key_hashes": {1: "f2b55577", 2: "a5410fb5", 3: "3a9c8544",
-                       4: "4b58fb5c", 5: "f2e21e83", 6: "9e293f84"},
+                       4: "4b58fb5c", 5: "f2e21e83", 6: "9e293f84",
+                       7: "20247409"},
         "byte_stable": False,
         "builders": ("serving/engine.py::telemetry",),
         "consumers": (),

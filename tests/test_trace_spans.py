@@ -108,6 +108,7 @@ def test_engine_emits_each_phase_once_a_step_with_the_plans_counts(
     rng = np.random.default_rng(0)
     eng.submit(rng.integers(1, 61, 9).tolist(), max_new_tokens=3)
     eng.run_until_idle()                       # compiled before the trace
+    kv_before = eng.telemetry()["kv_write"]
     plans = []
     run_plan = ServingEngine._run_plan
 
@@ -159,8 +160,11 @@ def test_engine_emits_each_phase_once_a_step_with_the_plans_counts(
                           "first_scheduled", "first_wait_s",
                           "pages_walked", "pages_tabled", "attn_tiles",
                           "attn_tiles_ahead", "layer_visits",
-                          "device_fed_rows"}
+                          "device_fed_rows", "pages_written",
+                          "page_windows"}
         assert int(a["layer_visits"]) == 2     # one visit a layer
+        # 4 sequences of 16 rows on pages of 4 slots: 2 x 4 + 8 // 4 windows
+        assert 0 < int(a["pages_written"]) <= int(a["page_windows"]) == 10
         # and those of the step's first tokens on serve.emit, where the host's
         # time went on serve.post
         assert set(by["serve.post"]["args"]) >= HOST
@@ -180,6 +184,10 @@ def test_engine_emits_each_phase_once_a_step_with_the_plans_counts(
     assert sum(int(a["decode_tokens"]) for a in runs) == 3 * 3
     # every decode row took its token from the step before, on the device
     assert sum(int(a["device_fed_rows"]) for a in runs) == 3 * 3
+    # the pool's telemetry sums what the spans carried
+    kv = eng.telemetry()["kv_write"]
+    for name in ("pages_written", "page_windows"):
+        assert kv[name] - kv_before[name] == sum(int(a[name]) for a in runs)
 
 
 def test_trainer_emits_step_and_block_spans(tmp_path):
