@@ -21,6 +21,12 @@ import jax  # noqa: E402
 if not os.environ.get("PADDLE_TPU_TEST_FULL_OPT"):
     jax.config.update("jax_disable_most_optimizations", True)
 
+# Dispatch inline on the CPU. The Pallas TPU interpreter's callbacks dispatch
+# JAX ops while their kernel is in flight; under async dispatch the test's
+# next eager op and such a callback's op wait on each other forever. JAX reads
+# the flag once, when it makes the CPU client, so it is set before any test.
+jax.config.update("jax_cpu_enable_async_dispatch", False)
+
 # No persistent compilation cache is configured here: CPU cache entries
 # abort on reload in this sandbox ("Fatal Python error: Aborted" while
 # EXECUTING an entry a previous green run wrote; cpu_aot_loader logs a
@@ -29,6 +35,16 @@ if not os.environ.get("PADDLE_TPU_TEST_FULL_OPT"):
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+import deadline  # noqa: E402
+
+DEADLINE = 300      # seconds for a test's setup, call and teardown: 7x the
+                    # slowest test's
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item):
+    return (yield from deadline.armed(item, DEADLINE))
 
 
 def pytest_collection_modifyitems(config, items):
