@@ -196,20 +196,21 @@ def page_windows(rows, seqs, bs):
 
 @jax.named_scope("kv_write")
 def _page_plan(pages, offs, windows, bs, dropped):
-    """For a step that writes whole pages: which pages it writes and which
-    of its rows lands in each slot of each. pages, offs: [T] within an
-    entry, ``dropped`` a row that writes nothing; windows: the pages the
+    """For a step that XLA writes by whole pages: which pages it writes and
+    which of its rows lands in each slot of each. pages, offs: [T] within
+    an entry, ``dropped`` a row that writes nothing; windows: the pages the
     step writes, ``page_windows``' bound (static). Returns (targets [W],
     hit [W, bs] bool, src [W, bs] int32): slot ``o`` of page ``targets[w]``
     takes row ``src[w, o]``'s K/V where ``hit[w, o]``. Where the bound
     reaches T a row's own page is its window (W = T; a page of several rows
     is written once a row, with one content); below it each distinct page
-    is one window, in the order of its first row, and the windows past them
-    are ``dropped``."""
+    is one window, in the order of its first row (the page kernel's order,
+    ``kv_write_pallas.first_rows``), and the windows past them are
+    ``dropped``."""
+    from .kernels import kv_write_pallas as _kw
     targets = pages
     if windows < pages.shape[0]:
-        earlier = jnp.tril(pages[:, None] == pages[None, :], -1).any(1)
-        first = (pages < dropped) & ~earlier          # a page's first row
+        first = _kw.first_rows(pages, dropped)
         opens = first[None, :] & (jnp.cumsum(first)[None, :] == jnp.arange(
             1, windows + 1)[:, None])                 # [W, T]
         targets = jnp.where(opens.any(1), pages[jnp.argmax(opens, axis=1)],
@@ -222,23 +223,37 @@ def _page_plan(pages, offs, windows, bs, dropped):
 def _entry_seams(pool_shape, scatter, attend):
     """What a layer needs to work on ONE cache entry of the joined pools.
     pool_shape: the stacked pools' [E, P, kvh, bs, D]; scatter: the step's
-    (pages [T], offs [T]) within an entry, page ``P`` a dropped row, and
-    its ``page_windows`` (static); attend: ``ragged.make_attend``'s
+    (pages [T], offs [T]) within an entry, page ``P`` a dropped row, its
+    ``page_windows`` (static) and the write that runs it (static: the
+    engine's ``ragged.write_path``); attend: ``ragged.make_attend``'s
     callable. Returns ``seam(e)`` -> (the scatter ``_kv_write_pages`` takes
     for entry ``e``, the ``attend(q, kf, vf)`` that reads entry ``e``);
-    ``e`` may be traced. The page plan is computed here, once a step."""
+    ``e`` may be traced. The page plan is computed here, once a step: by
+    distinct page for the page kernel (``kv_write_pallas.plan``), in
+    ``page_windows`` windows for XLA's write (``_page_plan``)."""
+    from .kernels import kv_write_pallas as _kw
     entry_pages, all_pages = pool_shape[1], pool_shape[0] * pool_shape[1]
-    pages, offs, windows = scatter
-    targets, *plan = _page_plan(pages, offs, windows, pool_shape[3],
-                                entry_pages)
+    pages, offs, windows, path = scatter
+    if path == "page_kernel":
+        with jax.named_scope("kv_write"):
+            plan = _kw.plan(pages, offs, entry_pages)
+
+        def at(first_page):
+            return _kw.at_entry(plan, first_page)
+    else:
+        targets, *plan = _page_plan(pages, offs, windows, pool_shape[3],
+                                    entry_pages)
+
+        def at(first_page):
+            # a dropped window (one past the entry) stays dropped
+            return (jnp.where(targets >= entry_pages, all_pages,
+                              targets + first_page), *plan)
 
     def seam(e):
         first_page = e * entry_pages
         with jax.named_scope("kv_write"):
-            # a dropped window (one past the entry) stays dropped
-            at = jnp.where(targets >= entry_pages, all_pages,
-                           targets + first_page)
-        return (at, *plan), partial(attend, first_page=first_page)
+            scatter_e = at(first_page)
+        return scatter_e, partial(attend, first_page=first_page)
 
     return seam
 
@@ -246,23 +261,36 @@ def _entry_seams(pool_shape, scatter, attend):
 @jax.named_scope("kv_write")
 def _kv_write_pages(kf, vf, k, v, scatter):
     """Put this step's K/V rows ([T, 1, kvh, D]) into the joined pools [N,
-    kvh, bs, D], the one write of every decoder: each page the step writes
-    is read, the step's rows are put into its slots and the page is written
-    back whole, in one window of ``page_windows`` a page and nothing else
-    of the pools moved, so the scatter updates the step's donated argument
-    (or a loop's carry) in place. A scatter of single rows makes the
-    compiler keep the pools in the layout that scatter likes, slots before
-    heads, and copy the whole of them into the attention kernel's layout
-    at every call; a page is the unit both agree on. scatter = (pages [W]
-    in the joined pools, ``N`` = a dropped window; ``_page_plan``'s hit and
-    src). Scope ``kv_write``: with ``_join_entries``, ``_page_plan`` and
-    ``_entry_seams``' targets the whole cost of keeping the pools, whatever
-    the attention reads."""
-    return _write_pages(kf, k, scatter), _write_pages(vf, v, scatter)
+    kvh, bs, D], the one write of every decoder (a latent pool is one: ``vf``
+    and ``v`` None): each page the step writes is read, the step's rows are
+    put into its slots and the page is written back whole, and nothing else
+    of the pools moves, so the write updates the step's donated argument (or
+    a loop's carry) in place. A scatter of single rows makes the compiler
+    keep the pools in the layout that scatter likes, slots before heads, and
+    copy the whole of them into the attention kernel's layout at every call;
+    a page is the unit both agree on. ``scatter`` is what ``_entry_seams``'
+    seam gives: a ``kv_write_pallas.WritePlan``, and the page kernel writes
+    each distinct page once, K and V in one call; or (pages [W] in the
+    joined pools, ``N`` = a dropped window; ``_page_plan``'s hit and src),
+    and XLA writes one window of ``page_windows`` a page (``_write_pages``).
+    Scope ``kv_write``: with ``_join_entries``, the plan and ``_entry_seams``'
+    targets the whole cost of keeping the pools, whatever the attention
+    reads."""
+    from .kernels import kv_write_pallas as _kw
+    pools = (kf,) if vf is None else (kf, vf)
+    news = (k,) if vf is None else (k, v)
+    if isinstance(scatter, _kw.WritePlan):
+        out = _kw.write_pages(pools, [n[:, 0].astype(p.dtype)
+                                      for n, p in zip(news, pools)], scatter)
+    else:
+        out = [_write_pages(p, n, scatter) for p, n in zip(pools, news)]
+    return out[0], (None if vf is None else out[1])
 
 
 def _write_pages(pool, new, scatter):
-    """One pool's half of ``_kv_write_pages`` (a latent pool is one)."""
+    """One pool's half of ``_kv_write_pages`` in XLA: ``page_windows``
+    pages gathered, the step's rows put into their slots, the pages
+    scattered back (the mesh's and the CPU's write, and the oracle)."""
     pages, hit, src = scatter
     at = jnp.minimum(pages, pool.shape[0] - 1)
     rows = new[:, 0][src].transpose(0, 2, 1, 3)          # [W, kvh, bs, D]
@@ -464,11 +492,12 @@ class _LlamaDecoder:
         absolute position); k_pools/v_pools: [E, P, kvh, bs, D] shared
         block pools, one per cache entry (here one a layer); scatter:
         (pages [T], offs [T]) per-token write targets within an entry
-        (page index P == dropped row) and the step's ``page_windows``
-        (static); attend: ``ragged.make_attend``'s callable; shard as in
-        _layer_ragged. The pools are joined into one run of pages, threaded
-        through the layers and written in place a page a window: no entry
-        is copied out and nothing is stacked again.
+        (page index P == dropped row), the step's ``page_windows`` and
+        the write that runs (both static: ``_entry_seams``); attend:
+        ``ragged.make_attend``'s callable; shard as in _layer_ragged. The
+        pools are joined into one run of pages, threaded through the layers
+        and written in place a page at a time: no entry is copied out and
+        nothing is stacked again.
         Returns (logits [T, V], exits, k_pools', v_pools'): ``exits`` is
         the pass each row's logits were taken after, [T] int32, where the
         decoder runs its layers several times a token, and None here."""
@@ -832,8 +861,7 @@ class _LongcatDecoder(_LlamaDecoder):
                 with jax.named_scope("attn_proj"):
                     q, row = self._mla_rows(w, i, j, x, cos, sin)
                 sc, att = seam(2 * i + j)
-                with jax.named_scope("kv_write"):
-                    kf = _write_pages(kf, row[:, :, None], sc)
+                kf, _ = _kv_write_pages(kf, None, row[:, :, None], None, sc)
                 o_lat = att(q[:, 0], kf, None)[:, None]
                 with jax.named_scope("attn_proj"):
                     return self._mla_out(w, i, j, o_lat)

@@ -275,14 +275,17 @@ def _ragged_forward(dec, shard, w, tokens, slot_ids, positions, valid,
     (the pass each row's logits were taken after, where it runs its layers
     several times a token; the routing counts of an expert layer) or None.
     ``step_ragged`` writes this step's rows into the pools in place (each
-    page they land in once, in ``generation.page_windows`` windows of the
-    rows, sequences and page size; nothing of a pool's size is copied).
+    page they land in once: by the page kernel where ``ragged.write_path``
+    picks it, else in ``generation.page_windows`` windows of the rows,
+    sequences and page size; nothing of a pool's size is copied).
     ``shard`` (static, None on a single chip) is the tensor-parallel
     annotator pinning the TP layout through the ragged path."""
     bs = k_pools.shape[3]
     p_total = k_pools.shape[1]
     mp = tables.shape[1]
     windows = _G.page_windows(positions.shape[0], tables.shape[0], bs)
+    path = _ragged.write_path(shard, k_pools.shape, k_pools.dtype,
+                              positions.shape[0])
     col = positions // bs
     page = jnp.take_along_axis(tables[slot_ids],
                                jnp.clip(col, 0, mp - 1)[:, None], 1)[:, 0]
@@ -293,9 +296,9 @@ def _ragged_forward(dec, shard, w, tokens, slot_ids, positions, valid,
     attend = _ragged.make_attend(tables, slot_ids, positions, valid,
                                  dec.n_heads // dec.n_kv, shard=shard,
                                  scale=dec.attn_scale, latent=dec.latent_dim)
-    logits, beside, kp, vp = dec.step_ragged(w, tokens, positions, k_pools,
-                                             v_pools, (pages, offs, windows),
-                                             attend, shard=shard)
+    logits, beside, kp, vp = dec.step_ragged(
+        w, tokens, positions, k_pools, v_pools, (pages, offs, windows, path),
+        attend, shard=shard)
     if shard is not None:
         # pin the donated outputs to the per-KV-head layout the next
         # step's inputs commit to (no silent gather between steps)
@@ -512,11 +515,16 @@ class ServingEngine:
         self.steps = 0
         self.tokens_generated = 0
         self.attn_tiles = self.attn_tiles_ahead = 0
-        # the pages the step program writes a visit (its plan's windows),
-        # and the sums over launched steps of those and of the distinct
-        # pages the steps' rows landed in
-        self._page_windows = _G.page_windows(cfg.token_budget, cfg.max_seqs,
-                                             bs)
+        # what writes the steps' K/V (``_ragged_forward`` asks the same
+        # rule); the pages XLA's write moves a visit, its plan's windows
+        # (None where the page kernel moves just the pages the rows land
+        # in); and the sums over launched steps of the pages moved a visit
+        # and of the distinct pages the steps' rows landed in
+        self._write_path = _ragged.write_path(
+            self._shard, self._pool_shape, self._pool_dtype, cfg.token_budget)
+        self._page_windows = (
+            _G.page_windows(cfg.token_budget, cfg.max_seqs, bs)
+            if self._write_path == "xla" else None)
         self.kv_pages_written = self.kv_page_windows = 0
         # steps launched while the one before was in flight, the decode
         # rows planned, and those of them whose token came from the device
@@ -949,8 +957,10 @@ class ServingEngine:
                 self.decode_rows += plan.decode_tokens
                 self.device_fed_rows += fed
                 written = self._pages_written(plan)
+                windows = (written if self._page_windows is None
+                           else self._page_windows)
                 self.kv_pages_written += written
-                self.kv_page_windows += self._page_windows
+                self.kv_page_windows += windows
                 try:
                     with RecordEvent(
                             "serve.run",
@@ -965,7 +975,7 @@ class ServingEngine:
                             layer_visits=self.dec.cache_entries,
                             device_fed_rows=fed,
                             pages_written=written,
-                            page_windows=self._page_windows,
+                            page_windows=windows,
                             **self._state_counts(plan)):
                         self._run_plan(plan, got, armed)
                 except Exception as exc:  # noqa: BLE001 — containment seam
@@ -1557,14 +1567,16 @@ class ServingEngine:
     def _pages_written(self, plan) -> int:
         """Distinct pages the step's rows land in, drafts included:
         ``serve.run`` carries it beside ``page_windows``, the pages the
-        step program writes a visit whatever the plan. More than those
-        would lose writes, and ``generation.page_windows`` bounds them from
-        the packing alone (a sequence's rows at consecutive positions), so
-        a plan that breaks it is a fault of the engine."""
+        step program writes a visit (the same pages on the page kernel; on
+        XLA's write its plan's windows, whatever the plan). More windows
+        than those would lose writes, and ``generation.page_windows``
+        bounds them from the packing alone (a sequence's rows at
+        consecutive positions), so a plan that breaks it is a fault of the
+        engine."""
         bs = self.config.block_size
         written = len({p for e in plan.entries for p in e.req.pages[
             e.start // bs:-(-(e.start + e.n + len(e.draft)) // bs)]})
-        if written > self._page_windows:
+        if self._page_windows is not None and written > self._page_windows:
             raise RuntimeError(
                 f"a step writes {written} pages but its program writes "
                 f"{self._page_windows} a visit: generation.page_windows' "
@@ -1924,9 +1936,12 @@ class ServingEngine:
                 "overlap": {"steps_ahead": self.steps_ahead,
                             "decode_rows": self.decode_rows,
                             "device_fed_rows": self.device_fed_rows},
-                # distinct pages the launched steps' rows landed in, and the
-                # pages their programs wrote (serve.run's, summed)
-                "kv_write": {"pages_written": self.kv_pages_written,
+                # what writes the step's K/V (the page kernel moves each
+                # distinct page once, XLA ``page_windows`` windows a visit),
+                # the distinct pages the launched steps' rows landed in, and
+                # the pages their programs moved a visit (serve.run's, summed)
+                "kv_write": {"path": self._write_path,
+                             "pages_written": self.kv_pages_written,
                              "page_windows": self.kv_page_windows},
                 "host": self.host_log.snapshot(),      # serve.post's, summed
                 # two numbers, equal unless the model runs its layers

@@ -102,6 +102,20 @@ def attention_path(shard, pool_shape, dtype) -> str:
     return "reference"
 
 
+def write_path(shard, pool_shape, dtype, rows) -> str:
+    """Which implementation writes a step's ``rows`` new K/V rows into the
+    pools: ``"page_kernel"`` (``kernels/kv_write_pallas.py``) wherever
+    ``attention_path`` runs the attention kernel and the kernel's VMEM
+    holds the step's rows (``kv_write_pallas.fits``), else ``"xla"``
+    (``generation._write_pages``). ``telemetry()["kv_write"]["path"]``
+    reports it."""
+    from ..kernels import kv_write_pallas as _kw
+    if (attention_path(shard, pool_shape, dtype) == "paged_kernel"
+            and _kw.fits(rows, pool_shape, dtype)):
+        return "page_kernel"
+    return "xla"
+
+
 def make_attend(page_tables, slot_ids, positions, valid, rep, shard=None,
                 scale=None, latent=None):
     """Bind the ragged metadata into the ``attend(q, kp, vp)`` callable
@@ -142,4 +156,5 @@ def make_attend(page_tables, slot_ids, positions, valid, rep, shard=None,
     return attend
 
 
-__all__ = ["ragged_paged_attention", "attention_path", "make_attend"]
+__all__ = ["ragged_paged_attention", "attention_path", "write_path",
+           "make_attend"]

@@ -40,3 +40,36 @@ def record(eng):
 
     eng._step_call, eng._emit_sampled = step_call, emit_sampled
     return steps
+
+
+def write_paths(monkeypatch, engine, prompts, max_new_tokens):
+    """Serve ``prompts`` on two fresh engines (``engine()`` makes one) with
+    the serving kernels interpreted (``ragged_pallas._INTERPRET``): the
+    first writes its K/V with the page kernel, the rule's choice there;
+    the second with XLA's write, the rule steered here. Returns {path:
+    (``telemetry()["kv_write"]["path"]``, the tokens, the K and V pools as
+    numpy arrays)}."""
+    from paddle_tpu.kernels import ragged_pallas
+    from paddle_tpu.serving import ragged
+    monkeypatch.setattr(ragged_pallas, "_INTERPRET", True)
+    runs = {}
+    for path in ("page_kernel", "xla"):
+        if path == "xla":
+            monkeypatch.setattr(ragged, "write_path", lambda *a: "xla")
+        # each run traces and compiles its programs anew: a program cached
+        # by an earlier test is not the one the other run compiles
+        jax.clear_caches()
+        eng = engine()
+        tokens = eng.generate_batch(prompts, max_new_tokens=max_new_tokens)
+        runs[path] = (eng.telemetry()["kv_write"]["path"], tokens,
+                      [np.asarray(p) for p in (eng._kp, eng._vp)])
+    return runs
+
+
+def assert_same_bits(a, b):
+    """Two arrays hold the same bits (NaN too)."""
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.shape == b.shape and a.dtype == b.dtype
+    if a.size:
+        width = {2: np.uint16, 4: np.uint32}[a.dtype.itemsize]
+        np.testing.assert_array_equal(a.view(width), b.view(width))

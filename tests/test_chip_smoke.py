@@ -3,6 +3,7 @@ two rules it shares with every chip script (platform check, compile-cache
 placement) hold. The real run is on the chip: `python chip_smoke.py`."""
 import json
 import os
+import re
 import subprocess
 import sys
 import types
@@ -199,6 +200,129 @@ def test_paged_attention_compiles_at_the_serving_cells_geometries(
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "paged_attention" in text
     assert compiled.memory_analysis().output_size_in_bytes == rows * kvh * rep * 256
+
+
+def _page_writes(text):
+    """Each ``kv_write`` kernel call of a lowered program: (the operand
+    types, the result types, {result: the operand it aliases})."""
+    calls = []
+    for line in text.splitlines():
+        if 'kernel_name = "kv_write"' not in line:
+            continue
+        ins, outs = re.search(r"\} : \((.*)\) -> \(?(.*?)\)?$",
+                              line).groups()
+        # a single result is no tuple: its index list is empty
+        aliases = {int(o or 0): int(i) for o, i in re.findall(
+            r"output_tuple_indices = \[(\d*)\], operand_index = (\d+)",
+            line)}
+        calls.append((re.findall(r"tensor<[^>]*>", ins),
+                      re.findall(r"tensor<[^>]*>", outs), aliases))
+    return calls
+
+
+def test_the_step_programs_page_write_aliases_both_pools(one_chip,
+                                                         monkeypatch):
+    """A Llama step program lowered for one v5e: the page write is one
+    kernel call a layer visit (one function, called a layer), K and V
+    both handed back aliased, and the compiled program holds no second
+    pool (its temporaries are under one pool's bytes)."""
+    import jax.numpy as jnp
+    import paddle_tpu as paddle
+    from paddle_tpu import generation as G
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.serving import engine as E
+    monkeypatch.setattr(paddle.kernels, "on_tpu", lambda: True)
+    paddle.seed(0)
+    cfg = LlamaConfig.tiny(vocab_size=61, hidden_size=256, layers=2,
+                           heads=2, kv_heads=2, seq=64)
+    cfg.use_flash_attention = False
+    dec = G._decoder_for(LlamaForCausalLM(cfg))
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    w = {k: shape(v.shape, v.dtype) for k, v in
+         dec.weights(LlamaForCausalLM(cfg)).items()}
+    t, i32 = 16, jnp.int32
+    pool = shape((2, 2048, 2, 16, 128), jnp.bfloat16)
+    lowered = E._engine_step.lower(
+        dec, None, E._argmax_rows, None, w, shape((t,), i32),
+        shape((t + dec.beside_width(t),), i32), shape((t,), i32),
+        shape((t,), i32), shape((t,), i32), shape((t,), jnp.bool_),
+        shape((4, 8), i32), pool, pool)
+    (ins, outs, aliases), = _page_writes(lowered.as_text())
+    joined = "tensor<4096x2x16x128xbf16>"
+    assert outs == [joined, joined]
+    assert [ins[aliases[0]], ins[aliases[1]]] == [joined, joined]
+    assert aliases[0] != aliases[1]
+    temp = lowered.compile().memory_analysis().temp_size_in_bytes
+    assert temp < 4096 * 2 * 16 * 128 * 2, temp
+
+
+def test_the_latent_page_write_aliases_its_one_pool(one_chip):
+    """The write of a latent pool (no V) is the same kernel with one pool,
+    handed back aliased."""
+    import jax.numpy as jnp
+    from paddle_tpu import generation as G
+    from paddle_tpu.kernels import kv_write_pallas as kw
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    t, pages = 320, 8 * 1024
+    pool = shape((pages, 1, 16, 640), jnp.bfloat16)
+
+    def write(kf, rows, at, offs):
+        plan = kw.at_entry(kw.plan(at, offs, 1024), 7 * 1024)
+        return G._kv_write_pages(kf, None, rows, None, plan)[0]
+
+    lowered = jax.jit(write, donate_argnums=0).lower(
+        pool, shape((t, 1, 1, 640), jnp.bfloat16), shape((t,), jnp.int32),
+        shape((t,), jnp.int32))
+    (ins, outs, aliases), = _page_writes(lowered.as_text())
+    assert outs == ["tensor<8192x1x16x640xbf16>"] == [ins[aliases[0]]]
+    assert lowered.compile().memory_analysis().temp_size_in_bytes \
+        < pages * 16 * 640 * 2
+
+
+@pytest.mark.parametrize("rows,kvh,d,pools,path", [
+    (2048, 32, 128, 2, "page_kernel"),  # cgpt67's heads at a 2,048 budget
+    (2048, 1, 640, 1, "page_kernel"),   # a latent pool at the same budget
+    (6016, 32, 128, 2, "page_kernel"),  # just under ``VMEM_MOST``
+    (6144, 32, 128, 2, "xla"),          # just over: XLA's write
+], ids=["decode-2048", "latent-2048", "most", "over"])
+def test_the_page_write_runs_where_its_vmem_holds_the_steps_rows(
+        one_chip, monkeypatch, rows, kvh, d, pools, path):
+    """The page kernel holds a step's rows in VMEM, so its VMEM grows with
+    the budget: ``write_path`` takes it where ``vmem_bytes`` fits under
+    ``VMEM_MOST``, and there Mosaic compiles it for one v5e at the limit the
+    call asks for; past that the write stays XLA's."""
+    import jax.numpy as jnp
+    import paddle_tpu as paddle
+    from paddle_tpu import generation as G
+    from paddle_tpu.kernels import kv_write_pallas as kw
+    from paddle_tpu.serving import ragged
+    monkeypatch.setattr(paddle.kernels, "on_tpu", lambda: True)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    entry = (512, kvh, 16, d)
+    assert ragged.write_path(None, (2,) + entry, jnp.bfloat16, rows) == path
+    if path == "xla":
+        return
+    pool = shape((2 * entry[0],) + entry[1:], jnp.bfloat16)
+    new = shape((rows, 1, kvh, d), jnp.bfloat16)
+
+    def write(kf, vf, k, v, at, offs):
+        plan = kw.at_entry(kw.plan(at, offs, entry[0]), entry[0])
+        two = pools == 2
+        return G._kv_write_pages(kf, vf if two else None, k,
+                                 v if two else None, plan)
+
+    jax.jit(write, donate_argnums=(0, 1)).lower(
+        pool, pool, new, new, shape((rows,), jnp.int32),
+        shape((rows,), jnp.int32)).compile()
 
 
 @pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv"])

@@ -236,6 +236,23 @@ def test_the_steps_counters_ride_on_the_emit_span(tmp_path):
         assert float(a["moe_held_mean_tokens"]) == int(counters[1]) / 4
 
 
+
+def test_the_page_kernel_writes_the_latent_pages_xla_does(monkeypatch):
+    """The latent pool, one pool and no V, through the page kernel
+    (interpreted): the same tokens and the same pages, bit for bit, as
+    XLA's write."""
+    model = _model()
+    prompts = [_ids(s, n).tolist() for s, n in ((5, 37), (6, 5), (7, 21))]
+    runs = engine_record.write_paths(monkeypatch, lambda: _engine(model),
+                                     prompts, 5)
+    (path, tokens, pools), (x_path, x_tokens, x_pools) = \
+        runs["page_kernel"], runs["xla"]
+    assert (path, x_path) == ("page_kernel", "xla")
+    assert tokens == x_tokens
+    assert pools[1].size == 0                       # no V pool
+    engine_record.assert_same_bits(pools[0], x_pools[0])
+
+
 # -- (b) absorbed against unabsorbed attention ----------------------------------
 def test_absorbed_attention_equals_unabsorbed():
     model = _model()

@@ -244,6 +244,24 @@ def test_a_decoder_without_a_state_adds_nothing_to_a_span_or_a_step():
     assert "state_bytes" not in eng.telemetry()["model"]
 
 
+
+def test_the_page_kernel_writes_the_attention_layers_pages_xla_does(
+        monkeypatch):
+    """The one attention layer's pages beside the recurrent state, through
+    the page kernel (interpreted): the same tokens and pages, bit for bit,
+    as XLA's write."""
+    model = _model()
+    prompts = [_ids(s, n).tolist() for s, n in ((5, 37), (6, 5), (7, 21))]
+    runs = engine_record.write_paths(monkeypatch, lambda: _engine(model),
+                                     prompts, 5)
+    (path, tokens, pools), (x_path, x_tokens, x_pools) = \
+        runs["page_kernel"], runs["xla"]
+    assert (path, x_path) == ("page_kernel", "xla")
+    assert tokens == x_tokens
+    for got, want in zip(pools, x_pools):
+        engine_record.assert_same_bits(got, want)
+
+
 # -- (b) the share test ------------------------------------------------------------
 def test_the_shares_add_up_to_the_uncut_expert_layer():
     """The routed parts the four shares of 4 experts give, each projected

@@ -264,6 +264,25 @@ def test_engine_on_the_paged_kernel_matches_the_reference(monkeypatch):
     assert rows == 6
 
 
+
+def test_the_page_kernel_writes_what_xla_does_through_the_pass_loop(
+        monkeypatch):
+    """The page write inside the pass ``scan``, interpreted: the same
+    tokens and, bit for bit, the same 2 x 4 entries of pages as XLA's
+    write leaves."""
+    model = _model(0.6)
+    prompts = [_ids(s, n).tolist() for s, n in ((8, 19), (9, 6))]
+    runs = engine_record.write_paths(
+        monkeypatch, lambda: _engine(model, max_seqs=2, num_blocks=16),
+        prompts, 3)
+    (path, tokens, pools), (x_path, x_tokens, x_pools) = \
+        runs["page_kernel"], runs["xla"]
+    assert (path, x_path) == ("page_kernel", "xla")
+    assert tokens == x_tokens
+    for got, want in zip(pools, x_pools):
+        engine_record.assert_same_bits(got, want)
+
+
 # -- (d) pages move whole: preemption, copy on write, handoff -----------------
 def test_preempted_and_resumed_requests_keep_their_logits():
     model = _model(0.6)

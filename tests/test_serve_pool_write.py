@@ -248,7 +248,8 @@ def test_the_page_write_is_a_row_by_row_write_digit_for_digit(slots):
 
     @jax.jit
     def write(kp, vp, at, off, k, v):
-        seam = G._entry_seams(shape, (at, off, windows), lambda *a, **k: 0)
+        seam = G._entry_seams(shape, (at, off, windows, "xla"),
+                              lambda *a, **k: 0)
         kf, vf = G._join_entries(kp, vp)
         for e in range(entries):
             kf, vf = G._kv_write_pages(kf, vf, k[e], v[e], seam(e)[0])
@@ -274,6 +275,106 @@ def test_the_page_write_is_a_row_by_row_write_digit_for_digit(slots):
                 vp[e, at[r], :, off[r]] = v[e, r, 0]
         np.testing.assert_array_equal(np.asarray(got_k), kp)
         np.testing.assert_array_equal(np.asarray(got_v), vp)
+
+
+
+# -- (a') the page kernel writes what the XLA write does, bit for bit ----------
+KBS, KPAGES, KROWS = 16, 6, 16
+
+
+def _chunk(page_of, start, n):
+    """(pages, offs) of ``n`` rows at positions ``start ..`` of a sequence
+    whose table is ``page_of`` (position // KBS -> page)."""
+    return ([page_of[p // KBS] for p in range(start, start + n)],
+            [p % KBS for p in range(start, start + n)])
+
+
+def _kernel_plan(name):
+    """(pages [KROWS], offs [KROWS], page-table rows) of one step within an
+    entry, page ``KPAGES`` a row that writes nothing."""
+    rows = {
+        # five sequences decode, one row each, the rest of the budget padding
+        "decode": ([0, 3, 5, 1, 4], [0, 15, 7, 3, 9], 5),
+        # a 7-row chunk from position 12 over pages 2 and 4 beside three
+        # decodes; an invalid row aimed at the chunk's first slot
+        "mixed": tuple(a + b for a, b in zip(
+            _chunk({0: 2, 1: 4}, 12, 7), ([1, 3, 0, KPAGES], [5, 0, 15, 12]))
+        ) + (4,),
+        # a whole budget of prompt from a page's last slot: 1 + 15 rows
+        "crossing": _chunk({0: 5, 1: 0}, 15, 16) + (2,),
+        # two real rows: the XLA plan's other windows are dropped ones
+        "dropped": ([3, 1], [2, 11], 4),
+        # eight page-table rows for 16 rows: the windows reach the rows (the
+        # row plan), four sequences of four rows on one page each
+        "row_plan": tuple(sum(c, []) for c in zip(*(
+            _chunk({0: p}, 4 * k, 4) for k, p in enumerate([2, 0, 5, 3])))
+        ) + (8,),
+        # rows in any order, two of them on one slot: the first one wins
+        "collide": ([4, 1, 4, 1, 2, 4], [6, 6, 7, 6, 0, 6], 4),
+    }[name]
+    pages, offs, seqs = rows
+    pad = KROWS - len(pages)
+    return (np.asarray(pages + [KPAGES] * pad, np.int32),
+            np.asarray(offs + [0] * pad, np.int32), seqs)
+
+
+KERNEL_CASES = [(plan, 2, "float32") for plan in
+                ("decode", "mixed", "crossing", "dropped", "row_plan",
+                 "collide")] + [
+    ("mixed", 2, "bfloat16"), ("row_plan", 2, "bfloat16"),
+    *(("mixed", kvh, dt) for kvh in (1, 8, 16, 32)
+      for dt in ("float32", "bfloat16"))]
+
+
+@pytest.mark.parametrize("plan,kvh,dtype", KERNEL_CASES,
+                         ids=[f"{p}-kvh{k}-{d}" for p, k, d in KERNEL_CASES])
+def test_the_page_kernel_writes_what_the_xla_write_does_bit_for_bit(
+        plan, kvh, dtype, monkeypatch):
+    """One step into both cache entries of two joined pools, through
+    ``_entry_seams`` and ``_kv_write_pages`` as a decoder calls them: the
+    XLA write (the CPU's path) and the page kernel (the path ``ragged.
+    write_path`` picks when the serving kernels run, here interpreted).
+    ``kvh`` 1 is a latent pool: one pool, no V."""
+    from paddle_tpu.kernels import kv_write_pallas as kw
+    from paddle_tpu.kernels import ragged_pallas
+    from paddle_tpu.serving import ragged
+    import engine_record
+    pages, offs, seqs = _kernel_plan(plan)
+    windows = G.page_windows(KROWS, seqs, KBS)
+    assert (windows == KROWS) == (plan == "row_plan")
+    entries, d, latent = 2, 128, kvh == 1
+    shape = (entries, KPAGES, kvh, KBS, d)
+    rng = np.random.default_rng(kvh)
+    pools = [jnp.asarray(rng.standard_normal((entries * KPAGES,) + shape[2:]),
+                         dtype) for _ in range(1 if latent else 2)]
+    news = [[jnp.asarray(rng.standard_normal((KROWS, 1, kvh, d)), dtype)
+             for _ in pools] for _ in range(entries)]
+
+    def write():
+        path = ragged.write_path(None, shape, dtype, KROWS)
+        seam = G._entry_seams(shape, (jnp.asarray(pages), jnp.asarray(offs),
+                                      windows, path), lambda *a, **k: 0)
+        kf, vf = pools[0], None if latent else pools[1]
+        for e in range(entries):
+            kf, vf = G._kv_write_pages(kf, vf, news[e][0],
+                                       None if latent else news[e][1],
+                                       seam(e)[0])
+        return [kf] if latent else [kf, vf]
+
+    assert ragged.write_path(None, shape, dtype, KROWS) == "xla"
+    want = write()
+    monkeypatch.setattr(ragged_pallas, "_INTERPRET", True)
+    assert ragged.write_path(None, shape, dtype, KROWS) == "page_kernel"
+    got = write()
+    for g, w, p in zip(got, want, pools):
+        engine_record.assert_same_bits(g, w)
+        assert not np.array_equal(np.asarray(g), np.asarray(p))
+    # the kernel moves each distinct page the rows land in once: what the
+    # engine counts as ``pages_written``
+    step = kw.plan(jnp.asarray(pages), jnp.asarray(offs), KPAGES)
+    real = set(pages[pages < KPAGES].tolist())
+    assert int(step.count[0]) == len(real)
+    assert sorted(np.asarray(step.pages)[:len(real)].tolist()) == sorted(real)
 
 
 # -- (b) nothing the size of a pool is copied, stacked or sliced ----------------
@@ -393,7 +494,30 @@ def test_an_engines_steps_land_in_no_more_pages_than_the_windows(opts):
     assert landed == counted and max(counted) <= windows
     assert max(counted) > 4                 # steps that wrote many pages
     assert eng.telemetry()["kv_write"] == {
-        "pages_written": sum(counted), "page_windows": windows * len(counted)}
+        "path": "xla", "pages_written": sum(counted),
+        "page_windows": windows * len(counted)}
+
+
+
+@pytest.mark.parametrize("kind", DECODERS)
+def test_an_engine_on_the_page_kernel_serves_and_keeps_what_xla_does(
+        kind, monkeypatch):
+    """Chunked prompts beside decode rows, the serving kernels interpreted:
+    the page kernel's engine serves the tokens and leaves the pools, bit
+    for bit, that an engine writing with XLA does, and each says which
+    write it ran."""
+    import engine_record
+    model = _model(kind, layers=2)
+    rng = np.random.default_rng(12)
+    prompts = [rng.integers(1, VOCAB, n).tolist() for n in (13, 5, 21)]
+    runs = engine_record.write_paths(monkeypatch, lambda: _engine(model),
+                                     prompts, 6)
+    (path, tokens, pools), (x_path, x_tokens, x_pools) = \
+        runs["page_kernel"], runs["xla"]
+    assert (path, x_path) == ("page_kernel", "xla")
+    assert tokens == x_tokens
+    for got, want in zip(pools, x_pools):
+        engine_record.assert_same_bits(got, want)
 
 
 # -- (c) pages that move whole still serve the same tokens ----------------------

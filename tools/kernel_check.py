@@ -437,6 +437,118 @@ def case_ssm_scan():
              "unscheduled_slots_bit_for_bit": kept})
 
 
+
+def _as_bits(a):
+    return jax.lax.bitcast_convert_type(
+        a, {2: jnp.uint16, 4: jnp.uint32}[a.dtype.itemsize])
+
+
+def _bits(a):
+    """A wrapping sum of the elements' bits: equal before and after for
+    the pages a write must leave alone."""
+    return _as_bits(a).astype(jnp.uint32).sum()
+
+
+def kv_write_step(cell):
+    """One step of a cell's rows as the page write takes them: (the cell's
+    whole pools as ``paged_step`` draws them, K and V or a latent pool
+    alone; the step's new rows [T, 1, kvh, D] of each; the rows' pages [T]
+    within the LAST cache entry, ``pages`` a row that writes nothing, and
+    slots [T]; entries; pages an entry; ``page_windows`` of the step)."""
+    from paddle_tpu import generation as G
+    if cell == "latent":
+        entries, pages, _, d, _, rows, slots, table, plan = LATENT_GEOMETRY
+        kvh, pools = 1, 1
+    else:
+        entries, pages, kvh, _, rows, slots, table, plan = \
+            PAGED_GEOMETRIES[cell]
+        d, pools = 128, 2
+    bs, first = 16, (entries - 1) * pages
+    tables, slot, pos, valid = packed_plan(entries, pages, rows, slots,
+                                           table, plan)
+    col = pos // bs
+    page = jnp.take_along_axis(tables[slot], jnp.clip(col, 0, table - 1)
+                               [:, None], 1)[:, 0] - first
+    at = jnp.where(~valid | (col >= table) | (page < 0), pages, page)
+    news = [rand(10 + k, (rows, 1, kvh, d)) for k in range(pools)]
+    _, kp, vp, _ = paged_step(entries, pages, kvh, 1, rows, slots, table,
+                              plan, d=d, pools=pools)
+    return ([p for p in (kp, vp) if p is not None], news, at, pos % bs,
+            entries, pages, G.page_windows(rows, slots, bs))
+
+
+def kv_write(pools, news, how):
+    """``generation._kv_write_pages`` on a list of one or two pools: ``how``
+    a ``kv_write_pallas.WritePlan`` (the page kernel) or ``xla_scatter``'s
+    (the XLA write)."""
+    from paddle_tpu import generation as G
+    two = len(pools) > 1
+    kf, vf = G._kv_write_pages(pools[0], pools[1] if two else None, news[0],
+                               news[-1] if two else None, how)
+    return [kf, vf] if two else [kf]
+
+
+def xla_scatter(at, offs, windows, entries, pages, e):
+    """The XLA write's scatter into entry ``e`` (``_entry_seams``')."""
+    from paddle_tpu import generation as G
+    targets, hit, src = G._page_plan(at, offs, windows, 16, pages)
+    return (jnp.where(targets >= pages, entries * pages,
+                      targets + e * pages), hit, src)
+
+
+def case_kv_write(cell):
+    """The step's page write (``kernels/kv_write_pallas.py``) against XLA's
+    (``generation._write_pages``), bit for bit: one layer visit's rows of a
+    cell's step into the LAST cache entry of the cell's whole pools, written
+    in place (donated); XLA's write runs on a copy of that entry alone, and
+    every other page must keep its bits. Then the microseconds a visit of
+    each, a visit an entry in one program as a step's layers make them."""
+    from paddle_tpu.kernels import kv_write_pallas as kw
+    old, news, at, offs, entries, pages, windows = kv_write_step(cell)
+    first = (entries - 1) * pages
+
+    def write(pools_, e, kernel):
+        return kv_write(pools_, news, kw.at_entry(
+            kw.plan(at, offs, pages), e * pages) if kernel else
+            xla_scatter(at, offs, windows, entries, pages, e))
+
+    entry = jax.jit(lambda ps: [p[first:first + pages] for p in ps])
+    mine = entry(old)
+    rest = [(_bits(p) - _bits(m)) for p, m in zip(old, mine)]
+    want = jax.jit(lambda ps: write(ps, 0, False))(mine)
+    got_all = jax.jit(lambda ps: write(ps, entries - 1, True),
+                      donate_argnums=0)(old)
+    got = entry(got_all)
+    kept = all(int(_bits(p) - _bits(m)) == int(r)
+               for p, m, r in zip(got_all, got, rest))
+    same = all(bool(jnp.array_equal(_as_bits(g), _as_bits(w)))
+               for g, w in zip(got, want))
+    if not (same and kept):
+        raise AssertionError(f"bits equal {same}, other pages kept {kept}")
+
+    def visits(kernel):
+        loop = jax.jit(lambda ps: jax.lax.fori_loop(
+            0, entries, lambda e, ps: write(ps, e, kernel), ps),
+            donate_argnums=0)
+        ps = loop(got_all)
+        jax.block_until_ready(ps)
+        t0 = time.perf_counter()
+        ps = loop(ps)
+        jax.block_until_ready(ps)
+        return ps, round((time.perf_counter() - t0) / entries * 1e6, 2)
+
+    got_all, kernel_us = visits(True)
+    got_all, xla_us = visits(False)
+    names = ("k", "v")[:len(old)]
+    d = news[0].shape[-1]
+    return ((entries * pages, news[0].shape[2], 16, d, news[0].shape[0],
+             int(windows)),
+            {n: (g, w) for n, g, w in zip(names, got, want)},
+            {"pages_written": len(set(np.asarray(at)[np.asarray(at)
+                                                     < pages].tolist())),
+             "us_a_visit": {"page_kernel": kernel_us, "xla": xla_us}})
+
+
 CASES = (
     ("flash_attention", "F.scaled_dot_product_attention on a TPU",
      case_flash_attention, REL_L2),
@@ -460,6 +572,9 @@ CASES = (
      "in a latent width", case_grouped_experts_two_banks, REL_L2),
     ("ssm_scan", "ServingEngine on one chip, a recurrent state beside the "
      "pages", case_ssm_scan, REL_L2_F32),
+    *((f"kv_write/{cell}", "ServingEngine on one chip, the K/V write",
+       functools.partial(case_kv_write, cell), 0.0)
+      for cell in ("decode", "looped", "latent")),
 )
 
 
