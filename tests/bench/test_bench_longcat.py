@@ -13,6 +13,7 @@ import pytest
 
 import bench_tiny as tiny
 import bench_contract as contract
+import bench_recorded
 import longcat_tiny
 from bench import run as R
 from bench.archs import longcat_flash as arch
@@ -24,6 +25,9 @@ from bench.tools.longcat_faults import FAULTS, faulty, in_the_programs_place
 SEED = 3400000123            # past 2**31, as the driver's are
 CFG = longcat_tiny.LONGCAT
 CELL = "longcat560-serve-batch"
+# the batch cells' share of the host-time counters
+TWINS = ("device_fed_share.batch.serve", "host_offcpu_share.batch.serve",
+         "host_gc_share.batch.serve", "host_compile_share.batch.serve")
 
 
 @pytest.fixture(scope="module")
@@ -201,16 +205,26 @@ CHAT = os.path.join(tiny.DATA, "chat_two_steps.trace.json.gz")      # PR 26
 
 
 def test_the_new_readers_read_two_recorded_steps(monkeypatch):
-    cell = real_cell()
+    read_two_recorded_steps(monkeypatch, tiny.REPO)
+
+
+def read_two_recorded_steps(monkeypatch, root):
+    """The cell's per-layer metrics in ``root`` over the recorded steps: what
+    reads nothing is what the kind samples on the host, and what counts
+    arguments the recording's program did not write yet (``bench_recorded``):
+    the tiles fetched ahead and the host-time twins among them."""
+    cell = spec.Cell(CELL, root)
     # the run these steps were cut from: a gap of 34.2 ms untraced
     quiet = {"itl_mean_s": 0.0342, "itl_p95_s": 0.0357, "engine_step_s": 0.0337}
     ctx = tiny.recorded_context(monkeypatch, TWO_STEPS, cell, **quiet)
     assert len(ctx["measured"]["steps"]) == 2
     got = {m["name"]: cell.reader(m["name"])(ctx, m["name"])
            for m in cell.per_layer()}
-    # sampled a step by the kind, or a counter the recording (PR 34) lacks
-    host = {"batch_pool_live_share", "attn_tiles_ahead_share.batch.serve"}
-    assert {n for n, v in got.items() if v is None} == host
+    host = {"batch_pool_live_share"}          # sampled a step by the kind
+    uncounted = bench_recorded.uncounted(cell, TWO_STEPS)
+    assert {"attn_tiles_ahead_share.batch.serve"} | set(TWINS) <= uncounted
+    silent = host | uncounted
+    assert {n for n, v in got.items() if v is None} == silent
     assert (got["batch_gap_mean_s"], got["batch_gap_p95_s"],
             got["batch_step_s"]) == tuple(quiet.values())
     # busy time a step from the trace over the UNTRACED period: the slice's
@@ -221,10 +235,10 @@ def test_the_new_readers_read_two_recorded_steps(monkeypatch):
     assert cell.reader("device_idle_share.batch.serve")(
         without, "device_idle_share.batch.serve") is None
     for name, value in got.items():
-        if name not in host:
+        if name not in silent:
             assert value >= 0, name
-        if name not in host and (name.endswith("_share.serve")
-                                 or "roofline" in name or "mfu" in name):
+        if name not in silent and (name.endswith("_share.serve")
+                                   or "roofline" in name or "mfu" in name):
             assert value <= 100.0, (name, value)
     assert 40 < got["moe_experts_roofline.serve"] <= 100
     assert 2 < got["latent_attn_roofline.serve"] <= 100
@@ -247,6 +261,39 @@ def test_the_new_readers_read_two_recorded_steps(monkeypatch):
     (emit, _) = S.named(t, "serve.emit")
     assert {"moe_pairs", "moe_pairs_held", "moe_pairs_zero", "moe_peak_tokens",
             "moe_experts_touched", "moe_held_mean_tokens"} <= set(emit[3])
+
+
+# two steps of a traced run of the cell on a v5e (seed 4400000111, steps 20
+# and 21 of the slice), cut by ``bench/tools/cut_trace.py``: a program that
+# writes the host-time counters on ``serve.run`` and ``serve.post``
+HOST_STEPS = os.path.join(tiny.DATA, "longcat_host_two_steps.trace.json.gz")
+
+
+def test_the_host_time_twins_read_two_recorded_steps_that_carry_them(
+        monkeypatch):
+    """Each twin reads a number: the shares of rows fed on the device, of
+    collections and of compiles from 0 to 100 %, the off-CPU share as read
+    (a host that counts CPU time in 10 ms ticks can put two steps' sum under
+    zero). What reads nothing is again what the kind samples on the host and
+    what the rule names."""
+    cell = real_cell()
+    # the run these steps were cut from: a gap of 31.3 ms untraced
+    quiet = {"itl_mean_s": 0.0313, "itl_p95_s": 0.0322, "engine_step_s": 0.0311}
+    ctx = tiny.recorded_context(monkeypatch, HOST_STEPS, cell, **quiet)
+    got = {m["name"]: cell.reader(m["name"])(ctx, m["name"])
+           for m in cell.per_layer()}
+    uncounted = bench_recorded.uncounted(cell, HOST_STEPS)
+    assert not uncounted & set(TWINS)
+    assert {n for n, v in got.items() if v is None} \
+        == {"batch_pool_live_share"} | uncounted
+    for name in TWINS:
+        assert got[name] is not None and got[name] <= 100.0, name
+    for name in set(TWINS) - {"host_offcpu_share.batch.serve"}:
+        assert got[name] >= 0, name
+    assert got["device_fed_share.batch.serve"] == 100.0   # nothing preempted
+    (post, _) = S.named(S.load(HOST_STEPS), "serve.post")
+    assert {"host_wall_us", "host_offcpu_us", "gc_us", "compile_us"} \
+        <= set(post[3])
 
 
 def test_the_new_readers_read_nothing_from_a_program_without_them(monkeypatch):
